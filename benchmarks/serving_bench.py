@@ -602,16 +602,15 @@ def run_dp_mode(dp, cfg, plan, mesh, params, sz):
 
 def rows(smoke: bool = False):
     import jax
-    from repro import compat
     from repro.configs import get_config, reduced
     from repro.core import model
     from repro.core.partition import ShardingPlan
+    from repro.launch.mesh import make_mesh
 
     sz = SIZES["smoke" if smoke else "full"]
     cfg = reduced(get_config("tinyllama-42m"), dtype="float32")
     plan = ShardingPlan(tp=1, kv_cache_dtype="float32")
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            devices=jax.devices()[:1])
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     params = model.init_params(cfg, plan)
     out = [run_mode(m, cfg, plan, mesh, params, sz)
            for m in ("contiguous", "paged", "prefix")]

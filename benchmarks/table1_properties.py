@@ -45,9 +45,9 @@ def rows():
         # structure per block, fewer blocks)
         rcfg = reduced(cfg)
         rplan = ShardingPlan(tp=1)
-        from repro import compat
-        mesh = compat.make_mesh((1, 1), ("data", "model"),
-                                devices=jax.devices()[:1])
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"),
+                         devices=jax.devices()[:1])
         shape = ShapeConfig("t", "train", 32, 2)
         cc.LEDGER.start()
         ts, _ = steps.make_train_step(rcfg, rplan, mesh, shape=shape)

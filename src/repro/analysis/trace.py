@@ -320,19 +320,18 @@ def run_recompile_harness(max_ticks: int = 200, verbose=print) -> list:
     -> list of Finding (empty = clean)."""
     import numpy as np
 
-    from repro import compat
     from repro.analysis.core import Finding
     from repro.configs import get_config, reduced
     from repro.core import model
     from repro.core.partition import ShardingPlan
+    from repro.launch.mesh import make_mesh
     from repro.serving import Request, ServingEngine
 
     import jax
 
     cfg = reduced(get_config("tinyllama-42m"), dtype="float32")
     plan = ShardingPlan(tp=1, kv_cache_dtype="float32")
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            devices=jax.devices()[:1])
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     params = model.init_params(cfg, plan)
     eng = ServingEngine.build_paged(cfg, plan, mesh, 2, 32, params,
                                     page_size=8, prefill_chunk=8)

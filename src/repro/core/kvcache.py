@@ -53,6 +53,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ATTN_WINDOW, ModelConfig
@@ -143,12 +144,15 @@ def cache_pspecs(tmpl):
     return _map_tmpl(tmpl, lambda trip: trip[2])
 
 
-def zero_cache(tmpl):
+def zero_cache(tmpl, mesh):
+    """Empty cache, each leaf created directly in its shards on ``mesh``:
+    no device ever holds a whole array."""
     def mk(trip):
-        shape, dtype, _ = trip
+        shape, dtype, spec = trip
+        dev = NamedSharding(mesh, spec)
         if dtype == jnp.int32:       # pos slots start empty
-            return jnp.full(shape, -1, dtype)
-        return jnp.zeros(shape, dtype)
+            return jnp.full(shape, -1, dtype, device=dev)
+        return jnp.zeros(shape, dtype, device=dev)
     return _map_tmpl(tmpl, mk)
 
 
@@ -334,8 +338,9 @@ def unfold_replica_pools(cache, n_replicas_loc: int):
             + pool.shape[2:]), cache)
 
 
-def zero_paged_cache(tmpl):
-    return _map_tmpl(tmpl, lambda trip: jnp.zeros(trip[0], trip[1]))
+def zero_paged_cache(tmpl, mesh):
+    return _map_tmpl(tmpl, lambda trip: jnp.zeros(
+        trip[0], trip[1], device=NamedSharding(mesh, trip[2])))
 
 
 class PageAllocator:

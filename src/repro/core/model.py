@@ -24,6 +24,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import (FFN_DENSE, FFN_MOE, FFN_NONE,
@@ -363,43 +364,57 @@ def abstract_params(cfg, plan, dtype=None):
     return _map_template(_with_reps(cfg, model_template(cfg)), mk)
 
 
+def _param_pspec(spec, reps, cfg, plan, lay):
+    if spec.kind == "replicated":
+        base = P(*([None] * len(spec.full)))
+    else:
+        tpax = plan.tp_axis if plan.tp > 1 else None
+        ndim = len(sharded_shape(spec, cfg, plan, lay))
+        base = P(*([tpax] + [None] * (ndim - 1)))
+    if reps:
+        base = P(*((None,) + tuple(base)))
+    return base
+
+
 def param_pspecs(cfg, plan):
     lay = model_layout(cfg, plan)
-
-    tpax = plan.tp_axis if plan.tp > 1 else None
-
-    def mk(spec, reps):
-        if spec.kind == "replicated":
-            ndim = len(spec.full)
-            base = P(*([None] * ndim))
-        else:
-            ndim = len(sharded_shape(spec, cfg, plan, lay))
-            base = P(*([tpax] + [None] * (ndim - 1)))
-        if reps:
-            base = P(*((None,) + tuple(base)))
-        return base
-
-    return _map_template(_with_reps(cfg, model_template(cfg)), mk)
+    return _map_template(_with_reps(cfg, model_template(cfg)),
+                         lambda spec, reps: _param_pspec(spec, reps, cfg,
+                                                         plan, lay))
 
 
-def init_params(cfg, plan, seed=0, dtype=None):
+def init_params(cfg, plan, seed=0, dtype=None, mesh=None):
     """Deterministic init: canonical full tensors (independent of plan),
-    then scatter.  Heavy for full-size configs — use on reduced/paper models."""
+    then scatter.  Without ``mesh`` the leaves are built eagerly on the
+    default device (fine for reduced/paper models).  With ``mesh`` each
+    leaf is computed under ``jit`` straight into its ``NamedSharding``
+    (``param_pspecs``), so a multi-chip mesh never stages the whole model
+    on one chip; the values are the same either way."""
     lay = model_layout(cfg, plan)
     dt = jnp.dtype(dtype or cfg.dtype)
     counter = [0]
 
     def mk(spec, reps):
-        leaves = []
+        keys = []
         for _ in range(max(reps, 1)):
             counter[0] += 1
-            key = jax.random.fold_in(jax.random.PRNGKey(seed), counter[0])
-            full = _init_full(spec, key)
-            sh = shard_full(spec, full, cfg, plan, lay)
-            sh = _mask_invalid_heads(spec, sh, cfg, plan, lay)
-            keep_f32 = spec.init in ("a_log", "dt_bias")
-            leaves.append(sh.astype(jnp.float32 if keep_f32 else dt))
-        return jnp.stack(leaves) if reps else leaves[0]
+            keys.append(jax.random.fold_in(jax.random.PRNGKey(seed),
+                                           counter[0]))
+        keep_f32 = spec.init in ("a_log", "dt_bias")
+
+        def build(keys):
+            leaves = []
+            for key in keys:
+                sh = shard_full(spec, _init_full(spec, key), cfg, plan, lay)
+                sh = _mask_invalid_heads(spec, sh, cfg, plan, lay)
+                leaves.append(sh.astype(jnp.float32 if keep_f32 else dt))
+            return jnp.stack(leaves) if reps else leaves[0]
+
+        if mesh is None:
+            return build(keys)
+        sharding = NamedSharding(mesh, _param_pspec(spec, reps, cfg, plan,
+                                                    lay))
+        return jax.jit(build, out_shardings=sharding)(keys)
 
     return _map_template(_with_reps(cfg, model_template(cfg)), mk)
 

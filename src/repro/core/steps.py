@@ -42,8 +42,8 @@ def n_dp(mesh, plan):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    from repro.compat import shard_map
-    return shard_map(f, mesh, in_specs, out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -378,13 +378,19 @@ def train_state_pspecs(cfg, plan):
 # Serving steps
 # ---------------------------------------------------------------------------
 
+def _batch_sharded(plan, batch, mesh):
+    """Whether a serving batch shards over the dp axes; tiny batches and
+    sequence-sharded caches replicate it."""
+    return batch % n_dp(mesh, plan) == 0 and not plan.seq_shard_kv
+
+
 def serve_templates(cfg, plan, shape: ShapeConfig, mesh):
     """Abstract inputs + specs for prefill/decode lowering of one cell."""
     prepare_ledger(mesh)
     lay = model_layout(cfg, plan)
     B, S = shape.global_batch, shape.seq_len
-    batch_ok = (B % n_dp(mesh, plan) == 0) and not plan.seq_shard_kv
-    bt = batch_axes(plan) if batch_ok else None  # replicate tiny batches
+    batch_ok = _batch_sharded(plan, B, mesh)
+    bt = batch_axes(plan) if batch_ok else None
     tmpl = kvcache.cache_template(cfg, plan, lay, B, S,
                                   batch_sharded=batch_ok)
     cache = kvcache.abstract_cache(tmpl)
@@ -418,9 +424,8 @@ def serve_templates(cfg, plan, shape: ShapeConfig, mesh):
 
 
 def _serve_bt(plan, shape, mesh):
-    batch_ok = (shape.global_batch % n_dp(mesh, plan) == 0) and \
-        not plan.seq_shard_kv
-    return batch_axes(plan) if batch_ok else None
+    return batch_axes(plan) \
+        if _batch_sharded(plan, shape.global_batch, mesh) else None
 
 
 def make_decode_step(cfg, plan, mesh, shape: ShapeConfig):
@@ -472,9 +477,13 @@ def make_prefill_step(cfg, plan, mesh, shape: ShapeConfig):
 
 
 def zero_cache_for(cfg, plan, mesh, batch, budget):
+    """Empty contiguous cache, placed on ``mesh`` with the specs that
+    ``serve_templates`` gives the decode/prefill steps."""
     lay = model_layout(cfg, plan)
-    tmpl = kvcache.cache_template(cfg, plan, lay, batch, budget)
-    return kvcache.zero_cache(tmpl)
+    tmpl = kvcache.cache_template(
+        cfg, plan, lay, batch, budget,
+        batch_sharded=_batch_sharded(plan, batch, mesh))
+    return kvcache.zero_cache(tmpl, mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -925,7 +934,7 @@ def zero_paged_cache_for(cfg, plan, mesh, n_pages, page_size,
     lay = model_layout(cfg, plan)
     tmpl = kvcache.paged_cache_template(cfg, plan, lay, n_pages, page_size,
                                         n_replicas, n_slabs)
-    return kvcache.zero_paged_cache(tmpl)
+    return kvcache.zero_paged_cache(tmpl, mesh)
 
 
 _STEP_SET_MEMO: dict = {}

@@ -91,163 +91,17 @@ def decode_attention(q, k, v, length, *, scale=None, bkv=512,
 
 
 # ---------------------------------------------------------------------------
-# Paged decode: K/V pages streamed through a scalar-prefetched block table
+# Paged decode / verify: K/V pages streamed through a scalar-prefetched
+# block table.  Decode is the one-query case of verify (query 0 sees
+# positions < length), so both share one kernel.
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, psz, n_max):
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = len_ref[b]
-
-    @pl.when(ki * psz < length)
-    def _compute():
-        s = jax.lax.dot_general(
-            q_ref[0, 0][None], k_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # (1, psz)
-        kpos = ki * psz + jax.lax.broadcasted_iota(jnp.int32, (1, psz), 1)
-        mask = kpos < length
-        s = jnp.where(mask, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        p = jnp.exp(s - m_new) * mask
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[0]
-        m_ref[...] = m_new
-
-    @pl.when(ki == n_max - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
-
-
-def _paged_decode_kernel_i8(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                            vs_ref, o_ref, acc_ref, m_ref, l_ref, *, scale,
-                            psz, n_max):
-    """int8 page variant: dequantize k/v in-register through the page's
-    per-row scales ((psz,) each) — the pool still streams off-chip at one
-    byte per element, the scales add 4 bytes per row."""
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = len_ref[b]
-
-    @pl.when(ki * psz < length)
-    def _compute():
-        kf = k_ref[0, 0].astype(jnp.float32) * ks_ref[0][:, None]  # (psz, D)
-        vf = v_ref[0, 0].astype(jnp.float32) * vs_ref[0][:, None]
-        s = jax.lax.dot_general(
-            q_ref[0, 0].astype(jnp.float32)[None], kf,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale            # (1, psz)
-        kpos = ki * psz + jax.lax.broadcasted_iota(jnp.int32, (1, psz), 1)
-        mask = kpos < length
-        s = jnp.where(mask, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s))
-        p = jnp.exp(s - m_new) * mask
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[0]
-        m_ref[...] = m_new
-
-    @pl.when(ki == n_max - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-20)).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode_attention(q, k_pages, v_pages, block_table, length, *,
-                           scale=None, interpret=False,
-                           k_scale=None, v_scale=None):
-    """Decode attention over a paged KV pool.
-
-    q: (B, H, D); k_pages/v_pages: (n_pages, H, psz, D);
-    block_table: (B, n_max) int32 page ids; length: (B,) -> (B, H, D).
-    ``k_scale``/``v_scale`` ((n_pages, psz) float32) select the int8
-    dequant-on-read kernel variant.
-
-    ``length`` counts valid tokens (positions < length attend), matching the
-    contiguous kernel above — NOT the inclusive current-position convention
-    of ``core.attention`` decode paths.  When driving this from the engine's
-    ``pos`` array (position of the just-written token), pass ``pos + 1``.
-
-    The sequential grid axis walks each sequence's block table; the page id
-    is scalar-prefetched so the next page's DMA is issued with the gathered
-    address — no materialized contiguous copy of the cache (the same
-    minimal-off-chip-traffic discipline as the paper's L3-resident GEMV,
-    with the pool standing in for on-chip K/V).
-    """
-    B, H, D = q.shape
-    n_pages, Hk, psz, _ = k_pages.shape
-    assert Hk == H, (Hk, H)
-    n_max = block_table.shape[1]
-    scale = scale if scale is not None else D ** -0.5
-    grid = (B, H, n_max)
-    in_specs = [
-        pl.BlockSpec((1, 1, D), lambda b, h, j, bt, ln: (b, h, 0)),
-        pl.BlockSpec((1, 1, psz, D),
-                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, psz, D),
-                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-    ]
-    inputs = (block_table, length, q, k_pages, v_pages)
-    if k_scale is not None:
-        assert k_pages.dtype == jnp.int8, k_pages.dtype
-        in_specs += [
-            pl.BlockSpec((1, psz), lambda b, h, j, bt, ln: (bt[b, j], 0)),
-            pl.BlockSpec((1, psz), lambda b, h, j, bt, ln: (bt[b, j], 0)),
-        ]
-        inputs += (k_scale, v_scale)
-        kernel = functools.partial(_paged_decode_kernel_i8, scale=scale,
-                                   psz=psz, n_max=n_max)
+def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale, psz,
+                  n_max, nq, quant):
+    if quant:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
-        kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                                   psz=psz, n_max=n_max)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, j, bt, ln: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((D,), jnp.float32),
-            pltpu.VMEM((), jnp.float32),
-            pltpu.VMEM((), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=interpret,
-    )(*inputs)
-
-
-# ---------------------------------------------------------------------------
-# Paged verify: a small query block per slot (speculative decoding)
-# ---------------------------------------------------------------------------
-
-def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, psz, n_max, nq):
+        o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
     ki = pl.program_id(2)
 
@@ -262,9 +116,16 @@ def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # query i sees positions < length + i; the deepest query gates the page
     @pl.when(ki * psz < length + nq - 1)
     def _compute():
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        if quant:
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
         s = jax.lax.dot_general(
-            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (nq, psz)
+        if quant:
+            # int8 rows dequantize through the page's per-row scales,
+            # applied to the (nq, psz) scores rather than the (psz, D) block
+            s = s * ks_ref[0]
         kpos = ki * psz + jax.lax.broadcasted_iota(jnp.int32, (nq, psz), 1)
         qpos = jax.lax.broadcasted_iota(jnp.int32, (nq, psz), 0)
         mask = kpos < length + qpos
@@ -274,8 +135,9 @@ def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new[:, None]) * mask
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
+        pv = p * vs_ref[0] if quant else p.astype(v.dtype)
         acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -286,47 +148,79 @@ def _paged_verify_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                        ).astype(o_ref.dtype)
 
 
-def _paged_verify_kernel_i8(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                            vs_ref, o_ref, acc_ref, m_ref, l_ref, *, scale,
-                            psz, n_max, nq):
-    """int8 page variant of the verify kernel (see decode's i8 twin)."""
-    b = pl.program_id(0)
-    ki = pl.program_id(2)
+def _paged_attention(q, k_pages, v_pages, block_table, length, scale,
+                     interpret, k_scale, v_scale):
+    """q: (B, H, Q, D) -> (B, H, Q, D); see ``paged_verify_attention``."""
+    B, H, nq, D = q.shape
+    n_pages, Hk, psz, _ = k_pages.shape
+    assert Hk == H, (Hk, H)
+    n_max = block_table.shape[1]
+    scale = scale if scale is not None else D ** -0.5
+    quant = k_scale is not None
+    # every block's two minor dims equal the array's own (Q, D) / (psz, D)
+    # / (1, psz): the TPU tiling accepts such blocks at any extent
+    in_specs = [
+        pl.BlockSpec((1, 1, nq, D), lambda b, h, j, bt, ln: (b, h, 0, 0)),
+        pl.BlockSpec((1, 1, psz, D),
+                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
+        pl.BlockSpec((1, 1, psz, D),
+                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
+    ]
+    inputs = (block_table, length, q, k_pages, v_pages)
+    if quant:
+        assert k_pages.dtype == jnp.int8, k_pages.dtype
+        in_specs += [
+            pl.BlockSpec((1, 1, psz), lambda b, h, j, bt, ln: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, 1, psz), lambda b, h, j, bt, ln: (bt[b, j], 0, 0)),
+        ]
+        inputs += (k_scale.reshape(n_pages, 1, psz),
+                   v_scale.reshape(n_pages, 1, psz))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, H, n_max),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, nq, D),
+                               lambda b, h, j, bt, ln: (b, h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((nq, D), jnp.float32),
+            pltpu.VMEM((nq,), jnp.float32),
+            pltpu.VMEM((nq,), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, scale=scale, psz=psz, n_max=n_max,
+                          nq=nq, quant=quant),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, nq, D), q.dtype),
+        interpret=interpret,
+    )(*inputs)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[b]
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode_attention(q, k_pages, v_pages, block_table, length, *,
+                           scale=None, interpret=False,
+                           k_scale=None, v_scale=None):
+    """Decode attention over a paged KV pool.
 
-    @pl.when(ki * psz < length + nq - 1)
-    def _compute():
-        kf = k_ref[0, 0].astype(jnp.float32) * ks_ref[0][:, None]  # (psz, D)
-        vf = v_ref[0, 0].astype(jnp.float32) * vs_ref[0][:, None]
-        s = jax.lax.dot_general(
-            q_ref[0, 0].astype(jnp.float32), kf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (nq, psz)
-        kpos = ki * psz + jax.lax.broadcasted_iota(jnp.int32, (nq, psz), 1)
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (nq, psz), 0)
-        mask = kpos < length + qpos
-        s = jnp.where(mask, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None]) * mask
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    q: (B, H, D); k_pages/v_pages: (n_pages, H, psz, D);
+    block_table: (B, n_max) int32 page ids; length: (B,) -> (B, H, D).
+    ``k_scale``/``v_scale`` ((n_pages, psz) float32) select the int8
+    dequant-on-read variant.
 
-    @pl.when(ki == n_max - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-20)[:, None]
-                       ).astype(o_ref.dtype)
+    ``length`` counts valid tokens (positions < length attend), matching the
+    contiguous kernel above — NOT the inclusive current-position convention
+    of ``core.attention`` decode paths.  When driving this from the engine's
+    ``pos`` array (position of the just-written token), pass ``pos + 1``.
+
+    The sequential grid axis walks each sequence's block table; the page id
+    is scalar-prefetched so the next page's DMA is issued with the gathered
+    address — no materialized contiguous copy of the cache (the same
+    minimal-off-chip-traffic discipline as the paper's L3-resident GEMV,
+    with the pool standing in for on-chip K/V).
+    """
+    return _paged_attention(q[:, :, None], k_pages, v_pages, block_table,
+                            length, scale, interpret, k_scale,
+                            v_scale)[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -348,47 +242,5 @@ def paged_verify_attention(q, k_pages, v_pages, block_table, length, *,
     off-chip once per step, now amortized over Q scored positions: the
     bandwidth-bound speculation argument.
     """
-    B, H, nq, D = q.shape
-    n_pages, Hk, psz, _ = k_pages.shape
-    assert Hk == H, (Hk, H)
-    n_max = block_table.shape[1]
-    scale = scale if scale is not None else D ** -0.5
-    grid = (B, H, n_max)
-    in_specs = [
-        pl.BlockSpec((1, 1, nq, D), lambda b, h, j, bt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, psz, D),
-                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, psz, D),
-                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-    ]
-    inputs = (block_table, length, q, k_pages, v_pages)
-    if k_scale is not None:
-        assert k_pages.dtype == jnp.int8, k_pages.dtype
-        in_specs += [
-            pl.BlockSpec((1, psz), lambda b, h, j, bt, ln: (bt[b, j], 0)),
-            pl.BlockSpec((1, psz), lambda b, h, j, bt, ln: (bt[b, j], 0)),
-        ]
-        inputs += (k_scale, v_scale)
-        kernel = functools.partial(_paged_verify_kernel_i8, scale=scale,
-                                   psz=psz, n_max=n_max, nq=nq)
-    else:
-        kernel = functools.partial(_paged_verify_kernel, scale=scale,
-                                   psz=psz, n_max=n_max, nq=nq)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, nq, D),
-                               lambda b, h, j, bt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nq, D), jnp.float32),
-            pltpu.VMEM((nq,), jnp.float32),
-            pltpu.VMEM((nq,), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, nq, D), q.dtype),
-        interpret=interpret,
-    )(*inputs)
+    return _paged_attention(q, k_pages, v_pages, block_table, length, scale,
+                            interpret, k_scale, v_scale)

@@ -1,9 +1,11 @@
 """Jit'd dispatch layer over the Pallas kernels.
 
-``use_pallas`` selects the kernel path (real TPU: compiled Mosaic; CPU
-tests: interpret=True).  The default pure-JAX path is what the 512-device
-dry-run lowers (Pallas TPU kernels cannot lower on a CPU-only host); on
-hardware the kernels are drop-in via ``set_kernel_mode``.
+``use_pallas`` selects the kernel path.  Kernels compile for the TPU by
+default; CPU tests must ask for the Pallas interpreter explicitly
+(``interpret=True``), so a run on the chip never interprets by accident.
+The default pure-JAX path is what the 512-device dry-run lowers (Pallas
+TPU kernels cannot lower on a CPU-only host); on hardware the kernels are
+drop-in via ``set_kernel_mode``.
 """
 from __future__ import annotations
 
@@ -17,16 +19,16 @@ from repro.kernels.matmul import matmul as _pl_matmul
 from repro.kernels.rmsnorm import rmsnorm as _pl_rmsnorm
 from repro.kernels.ssd_scan import ssd_scan as _pl_ssd
 
-_MODE = {"use_pallas": False, "interpret": True}
+_MODE = {"use_pallas": False, "interpret": False}
 
 
-def set_kernel_mode(use_pallas: bool, interpret: bool = True):
+def set_kernel_mode(use_pallas: bool, interpret: bool = False):
     _MODE["use_pallas"] = use_pallas
     _MODE["interpret"] = interpret
 
 
 @contextmanager
-def kernel_mode(use_pallas: bool, interpret: bool = True):
+def kernel_mode(use_pallas: bool, interpret: bool = False):
     old = dict(_MODE)
     set_kernel_mode(use_pallas, interpret)
     try:

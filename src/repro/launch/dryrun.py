@@ -17,6 +17,11 @@ Usage:
   python -m repro.launch.dryrun --all [--multi-pod] [--out out.json]
   python -m repro.launch.dryrun --all --subprocess   # one proc per cell
 
+The dry run is CPU-only by construction: it forces 512 virtual host
+devices and lowers against shapes, never a chip.  ``--subprocess`` starts
+one child process per cell, which is safe only because no process here
+holds a TPU; do not run it on a machine whose chip a parent holds.
+
 Plan variants (hillclimbing): --moe-mode ep, --remat block, --seq-kv,
 --kv-dtype int8, --activations seq.
 """
@@ -172,7 +177,8 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--subprocess", action="store_true",
-                    help="isolate each cell in its own process")
+                    help="isolate each cell in its own process "
+                         "(CPU-only: cells lower on virtual host devices)")
     ap.add_argument("--out", default=None)
     ap.add_argument("--moe-mode", default="tp", choices=["tp", "ep"])
     ap.add_argument("--remat", default="auto",

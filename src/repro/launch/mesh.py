@@ -11,19 +11,21 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 AUTO = None
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes, devices=None):
-    return compat.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with Auto axes: shardings propagate through
+    eager ops and ``jit`` without explicit-axis annotations."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def single_device_mesh():

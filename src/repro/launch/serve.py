@@ -69,7 +69,10 @@ import time
 import numpy as np
 
 
-def main(argv=None):
+def parse_args(argv=None):
+    """-> the parsed command line, validated; ``disagg`` becomes a
+    ``(P, D)`` tuple (or None) and ``scale_events`` a sorted list of
+    ``(tick, "scale" | "kill", value)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     help="registered config id (repro.configs): dense/MoE "
@@ -187,14 +190,22 @@ def main(argv=None):
                 ap.error("--scale-events expects comma-separated T:N or "
                          "T:kill:R entries")
         scale_events.sort()
+    args.disagg, args.scale_events = disagg, scale_events
+    return args
 
+
+def build_engine(args):
+    """-> the ServingEngine the command line describes: config, sharding
+    plan, mesh over the local devices, seeded parameters placed on that
+    mesh, scheduler and sampler.  ``main`` and ``chip_smoke.py`` both
+    build through here."""
     import jax
     from repro.configs import get_config, reduced
     from repro.configs.base import ShapeConfig
     from repro.core import model, steps
     from repro.core.partition import ShardingPlan
     from repro.launch.mesh import host_mesh
-    from repro.serving import (FairScheduler, PriorityScheduler, Request,
+    from repro.serving import (FairScheduler, PriorityScheduler,
                                SamplerConfig, ServingEngine)
 
     cfg = get_config(args.arch)
@@ -210,7 +221,7 @@ def main(argv=None):
                    if args.dp % d == 0 and
                    d * args.tp <= len(jax.devices())), default=1)
     mesh = host_mesh(tp=args.tp, dp=mesh_dp)
-    params = model.init_params(cfg, plan, seed=args.seed)
+    params = model.init_params(cfg, plan, seed=args.seed, mesh=mesh)
 
     scheduler = None                 # engine default: FCFS
     if args.policy == "priority":
@@ -228,9 +239,9 @@ def main(argv=None):
             prefill_chunk=args.prefill_chunk, sampler=sampler,
             prefix_cache=args.prefix_cache, scheduler=scheduler,
             rng_seed=args.seed, dp=args.dp, speculative=args.speculative,
-            overlap=args.overlap, disagg=disagg)
-        if scale_events:
-            def membership_hook(e, _pending=list(scale_events)):
+            overlap=args.overlap, disagg=args.disagg)
+        if args.scale_events:
+            def membership_hook(e, _pending=list(args.scale_events)):
                 while _pending and e.stats.ticks >= _pending[0][0]:
                     _, kind, val = _pending.pop(0)
                     if kind == "scale":
@@ -253,6 +264,17 @@ def main(argv=None):
                                jax.jit(decode_fn, donate_argnums=(1,)),
                                sampler=sampler,
                                scheduler=scheduler, rng_seed=args.seed)
+    return engine
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serving import Request
+
+    enable_compile_cache()
+    engine = build_engine(args)
+    cfg, disagg = engine.cfg, args.disagg
     rng = np.random.RandomState(args.seed)
     shared = rng.randint(2, cfg.vocab_size,
                          args.shared_prefix).astype(np.int32)

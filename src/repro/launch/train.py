@@ -72,8 +72,10 @@ def main(argv=None):
     from repro.checkpoint.manager import AsyncCheckpointer, CheckpointManager
     from repro.core import steps as _steps
     from repro.data import PackedBatches
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.runtime.ft import Heartbeat
 
+    enable_compile_cache()
     cfg, plan, mesh, step_fn, data_cfg = build(args)
     state = _steps.init_train_state(cfg, plan, seed=args.seed)
     start_step = 0

@@ -15,11 +15,13 @@ replicas.  ``ServingEngine.scale_to`` / ``kill_replica`` drive it.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.checkpoint.resharding import reshard_params
 from repro.core.partition import ShardingPlan
@@ -76,10 +78,22 @@ def reshard_replica_pools(cache, keep: Sequence[int], new_n_replicas: int):
     if not 0 < len(keep) <= new_n_replicas:
         raise ValueError(f"keep={list(keep)!r} incompatible with "
                          f"new_n_replicas={new_n_replicas}")
-    idx = jnp.asarray(list(keep), dtype=jnp.int32)
+    keep = tuple(int(r) for r in keep)
+    return jax.tree_util.tree_map(
+        lambda v: _reshard_leaf(v, keep, new_n_replicas,
+                                v.sharding if isinstance(v.sharding,
+                                                         NamedSharding)
+                                else None), cache)
 
-    def _leaf(v):
-        out = jnp.zeros((v.shape[0], new_n_replicas) + v.shape[2:], v.dtype)
-        return out.at[:, :idx.shape[0]].set(jnp.take(v, idx, axis=1))
 
-    return jax.tree_util.tree_map(_leaf, cache)
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _reshard_leaf(v, keep, new_n_replicas, sharding):
+    """One leaf of ``reshard_replica_pools``.  The result keeps the old
+    leaf's ``NamedSharding`` (replica axis on the data axes), so each data
+    shard builds only its own replicas' rows."""
+    out = jnp.zeros((v.shape[0], new_n_replicas) + v.shape[2:], v.dtype)
+    out = out.at[:, :len(keep)].set(
+        jnp.take(v, jnp.asarray(keep, jnp.int32), axis=1))
+    if sharding is not None:
+        out = jax.lax.with_sharding_constraint(out, sharding)
+    return out

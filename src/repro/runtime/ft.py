@@ -102,7 +102,14 @@ def supervise(cmd: list, cfg: Optional[FTConfig] = None,
     """Restart-on-failure supervisor (the per-job controller).  Returns the
     final exit code.  Exit code 0 = done; anything else restarts (with
     backoff) up to max_restarts — resumption correctness is the trainee's
-    job via --auto-resume."""
+    job via --auto-resume.
+
+    The trainee runs as a child process, and a chip belongs to one
+    process at a time: the calling process must not have initialized a
+    JAX backend (no ``jax.devices()``, no array, no compile), or the
+    child fails or hangs waiting for the chip.  Importing ``repro.runtime``
+    imports ``jax`` (through ``elastic``) but touches no device; that
+    stays true only while nothing in the package does at import."""
     cfg = cfg if cfg is not None else FTConfig()
     restarts = 0
     while True:

@@ -1,8 +1,8 @@
 """Serving layer: continuous-batching engine (mechanism), schedulers
 (policy), prefix/cross caches, dp router, and samplers.  See
 ARCHITECTURE.md for the end-to-end map and per-module invariants."""
-from repro.serving.engine import (EngineStats, ReplicaStats, Request,  # noqa: F401
-                                  ServingEngine)
+from repro.serving.engine import (EngineStats, PageTransferError,  # noqa: F401
+                                  ReplicaStats, Request, ServingEngine)
 from repro.serving.policies import FairScheduler, PriorityScheduler  # noqa: F401
 from repro.serving.prefix_cache import (CrossKVCache, HostSpillStore,  # noqa: F401
                                         RadixPrefixCache)

@@ -195,6 +195,14 @@ from repro.serving.scheduler import (Admission, FCFSScheduler, Scheduler,
 SPEC_DISABLE_AFTER = 4
 
 
+class PageTransferError(RuntimeError):
+    """A cross-replica page transfer failed before it touched the cache
+    (e.g. its source or destination replica died between the migration
+    plan and the device copy).  The engine survives exactly this failure
+    by rolling the migration back; any other exception from the transfer
+    step — a trace, compile or runtime error — propagates."""
+
+
 @dataclass
 class Request:
     rid: int
@@ -260,6 +268,7 @@ class EngineStats:
     spec_denied: int = 0               # admissions denied draft headroom
     handoffs: int = 0                  # prefill->decode page-run transfers
     pages_transferred: int = 0         # pages moved across replicas
+    transfer_failures: int = 0         # PageTransferErrors survived by rollback
     scale_events: int = 0              # scale_to membership changes applied
     crashes: int = 0                   # kill_replica recoveries
     migrations: int = 0                # in-flight slots moved off a drain
@@ -990,12 +999,13 @@ class ServingEngine:
                     self.cache = self.transfer_fn(
                         self.cache, jnp.int32(src_r), jnp.int32(dst_r),
                         jnp.asarray(src_pages), jnp.asarray(dst_pages))
-            except Exception:
+            except PageTransferError:
                 # mid-handoff fault: no refcount moved yet (handoff_refs
                 # runs only after the transfer), so retiring the claimed
                 # destination admission restores the pre-migration state
                 # exactly — no orphan pages on either side
                 self.scheds[dst_r].on_finish(dst_adm)
+                self.stats.transfer_failures += 1
                 return False
         self.scheds[src_r].on_migrated(adm, k, self.allocators[dst_r],
                                        dst_adm.pages[:k])

@@ -8,7 +8,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def pytest_addoption(parser):
@@ -20,5 +20,4 @@ def pytest_addoption(parser):
 
 @pytest.fixture(scope="session")
 def mesh1():
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            devices=jax.devices()[:1])
+    return make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])

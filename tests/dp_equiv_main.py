@@ -16,19 +16,18 @@ import sys  # noqa: E402
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import get_config, reduced  # noqa: E402
 from repro.core import model  # noqa: E402
 from repro.core.partition import ShardingPlan  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.serving import Request, ServingEngine  # noqa: E402
 
 
 def main():
     cfg = reduced(get_config("tinyllama-42m"), dtype="float32")
     plan = ShardingPlan(tp=1, kv_cache_dtype="float32")
-    m1 = compat.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    m2 = compat.make_mesh((2, 1), ("data", "model"))
+    m1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    m2 = make_mesh((2, 1), ("data", "model"))
     params = model.init_params(cfg, plan)
     rng = np.random.RandomState(0)
     spec = [(rid,

@@ -18,8 +18,10 @@ from typing import List, Tuple
 
 import numpy as np
 
+from repro.serving.engine import PageTransferError
 
-class TransferFault(RuntimeError):
+
+class TransferFault(PageTransferError):
     """Injected failure of a cross-replica page transfer."""
 
 

@@ -40,11 +40,10 @@ def test_decode_matches_prefill(name, mesh1):
 
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     pspecs = model.param_pspecs(cfg, PLAN)
-    full_fn = jax.jit(compat.shard_map(
-        full, mesh1, in_specs=(pspecs, P(None, None)),
-        out_specs=P(None, None, "model")))
+    full_fn = jax.jit(jax.shard_map(
+        full, mesh=mesh1, in_specs=(pspecs, P(None, None)),
+        out_specs=P(None, None, "model"), check_vma=False))
     with mesh1:
         ref_logits = np.asarray(full_fn(params, tokens), np.float64)
 

@@ -48,7 +48,7 @@ def test_paged_template_shards_replicas_over_data_axes():
     for shape, _, spec in trips:
         # (reps, n_replicas, n_pages, G, psz, D), replicas on the dp axes
         assert shape[1] == 2 and shape[2] == 8
-        assert tuple(spec)[1] == ("data",)
+        assert tuple(spec)[1] == "data"
 
 
 def test_fold_replica_pools_roundtrip():

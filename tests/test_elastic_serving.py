@@ -269,11 +269,38 @@ def test_crash_during_handoff_rolls_back(cfg, params, mesh1):
     # preempt + re-admit — still no request lost
     eng.scale_to(1)
     assert eng.stats.migrations == 0
+    assert eng.stats.transfer_failures == state["faults"] > 1
     eng.run(max_ticks=5000)
     assert all(r.done for r in reqs)
     assert _outputs(reqs) == ref
     eng.drain()
     _assert_leak_free(eng)
+
+
+def test_transfer_step_errors_propagate(cfg, params, mesh1):
+    """Only a transfer fault is survived and counted: an error raised by
+    the transfer step itself — a ``TypeError``, as a tracing or compile
+    failure raises — propagates out of the migration instead of
+    degrading to preemption."""
+    reqs = _requests(cfg, seed=3, n=2)
+    eng = _build(cfg, PLAN, mesh1, params["fp32"], dp=2, overlap=False)
+    for r in reqs:
+        eng.submit(r)
+    for _ in range(3):
+        eng.tick()
+    b_src = next(b for b, adm in enumerate(eng.admissions)
+                 if adm is not None and b // eng.Bp == 1)
+    state = inject_transfer_fault(eng, fail_calls=(1,))
+    assert eng._migrate_slot(b_src, [0]) is False
+    assert state["faults"] == 1 and eng.stats.transfer_failures == 1
+
+    def broken_step(*args, **kwargs):
+        raise TypeError("transfer step traced with a bad argument")
+
+    eng.transfer_fn = broken_step
+    with pytest.raises(TypeError, match="bad argument"):
+        eng._migrate_slot(b_src, [0])
+    assert eng.stats.transfer_failures == 1
 
 
 def test_crash_readmits_exact_continuation(cfg, params, mesh1):

@@ -13,6 +13,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 def _run_train(ckpt_dir, steps, crash_at=0, auto_resume=True):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"   # tests never cache
     cmd = [sys.executable, "-m", "repro.launch.train",
            "--arch", "tinyllama-42m", "--smoke",
            "--steps", str(steps), "--batch", "2", "--seq-len", "32",

@@ -255,9 +255,8 @@ def test_context_parallel_ssm_subprocess():
     cfg = reduced(get_config("mamba2-370m"), dtype="float32")
     rng = np.random.RandomState(0)
     B, S = 2, 64
-    from repro import compat
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            devices=jax.devices()[:1])
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, S)), jnp.int32)
     batch = {"tokens": tokens, "labels": tokens}
     losses = []

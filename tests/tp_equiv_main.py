@@ -17,17 +17,16 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import get_config, reduced  # noqa: E402
 from repro.configs.base import ShapeConfig  # noqa: E402
 from repro.core import model, steps  # noqa: E402
 from repro.core.partition import ShardingPlan  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def meshes():
-    m1 = compat.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    m8 = compat.make_mesh((2, 4), ("data", "model"))
+    m1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    m8 = make_mesh((2, 4), ("data", "model"))
     return m1, m8
 
 
@@ -52,7 +51,7 @@ def run_case(name, **overrides):
         plan = ShardingPlan(tp=tp, moe_capacity=64.0)
         state = steps.init_train_state(cfg, plan)
         ts, _ = steps.make_train_step(cfg, plan, mesh, shape=shape)
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             st2, stats = jax.jit(ts)(state, batch)
         losses.append(float(stats["loss"]))
         # decode one token from an empty cache

@@ -12,18 +12,18 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import get_config, reduced  # noqa: E402
 from repro.configs.base import ShapeConfig  # noqa: E402
 from repro.core import steps  # noqa: E402
 from repro.core.partition import ShardingPlan  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():
     cfg = reduced(get_config("qwen3-0.6b"), dtype="float32")
     B, S = 4, 32
     shape = ShapeConfig("t", "train", S, B)
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = ShardingPlan(tp=4)
     rng = np.random.RandomState(0)
     batches = []
