@@ -42,6 +42,11 @@ def n_dp(mesh, plan):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` keeps ``f``'s name and ``jax.jit`` names the
+    compiled module after it, so each serving ``make_*_step`` names its
+    per-shard body after the step (``paged_decode`` ->
+    ``jit_paged_decode``): the name a profiler trace's ``XLA Modules``
+    line and the compile listener's ``fun_name`` show."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
@@ -435,10 +440,10 @@ def make_decode_step(cfg, plan, mesh, shape: ShapeConfig):
     t, s = serve_templates(cfg, plan, shape, mesh)
     bt = _serve_bt(plan, shape, mesh)
 
-    def per_shard(params, cache, tokens, pos):
+    def decode(params, cache, tokens, pos):
         return model.forward_decode(params, cache, tokens, pos, cfg, plan, lay)
 
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(decode, mesh,
                     in_specs=(pspecs, s["cache"], s["tokens1"], s["pos"]),
                     out_specs=(P(bt, "model"), s["cache"]))
     return fn, t, s
@@ -452,25 +457,25 @@ def make_prefill_step(cfg, plan, mesh, shape: ShapeConfig):
     bt = _serve_bt(plan, shape, mesh)
 
     if cfg.is_encdec:
-        def per_shard(params, frames, dec_tokens, cache):
+        def prefill(params, frames, dec_tokens, cache):
             return model.forward_prefill(params, frames, cache, cfg, plan,
                                          lay, extra={"dec_tokens": dec_tokens})
-        fn = _shard_map(per_shard, mesh,
+        fn = _shard_map(prefill, mesh,
                         in_specs=(pspecs, s["frames"], s["dec_tokens"],
                                   s["cache"]),
                         out_specs=(P(bt, "model"), s["cache"]))
     elif cfg.frontend == "vision_patches":
-        def per_shard(params, prompt, image_embeds, cache):
+        def prefill(params, prompt, image_embeds, cache):
             return model.forward_prefill(params, prompt, cache, cfg, plan,
                                          lay, extra={"image_embeds": image_embeds})
-        fn = _shard_map(per_shard, mesh,
+        fn = _shard_map(prefill, mesh,
                         in_specs=(pspecs, s["prompt"], s["image_embeds"],
                                   s["cache"]),
                         out_specs=(P(bt, "model"), s["cache"]))
     else:
-        def per_shard(params, prompt, cache):
+        def prefill(params, prompt, cache):
             return model.forward_prefill(params, prompt, cache, cfg, plan, lay)
-        fn = _shard_map(per_shard, mesh,
+        fn = _shard_map(prefill, mesh,
                         in_specs=(pspecs, s["prompt"], s["cache"]),
                         out_specs=(P(bt, "model"), s["cache"]))
     return fn, t, s
@@ -562,7 +567,7 @@ def make_paged_decode_step(cfg, plan, mesh, batch: int, n_pages: int,
     n_cross = kvcache.pages_needed(cfg.enc_seq_len, page_size) \
         if has_cross else 0
 
-    def per_shard(params, cache, tokens, pos, block_table, *extra):
+    def paged_decode(params, cache, tokens, pos, block_table, *extra):
         # fold this shard's replicas into one pool; rows stay
         # replica-relative, so offset each row into its replica's range
         rep_row = jnp.arange(r_loc * batch, dtype=jnp.int32) // batch
@@ -595,7 +600,7 @@ def make_paged_decode_step(cfg, plan, mesh, batch: int, n_pages: int,
         t["cross_block_table"] = jax.ShapeDtypeStruct(
             (n_replicas * batch, n_cross), jnp.int32)
         extra_s.append(s["cross_block_table"])
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(paged_decode, mesh,
                     in_specs=(pspecs, s["cache"], s["tokens1"], s["pos"],
                               s["block_table"], *extra_s),
                     out_specs=(P(bt_ax, "model"), s["cache"]))
@@ -625,7 +630,7 @@ def make_verify_step(cfg, plan, mesh, batch: int, q_len: int, n_pages: int,
     r_loc = n_replicas_local(mesh, plan, n_replicas)
     bt_ax = batch_axes(plan)
 
-    def per_shard(params, cache, tokens, pos, qlen, block_table):
+    def verify(params, cache, tokens, pos, qlen, block_table):
         rep_row = jnp.arange(r_loc * batch, dtype=jnp.int32) // batch
         offs = rep_row[:, None] * n_pages
         pages = {"block_table": block_table + offs, "page_size": page_size}
@@ -643,7 +648,7 @@ def make_verify_step(cfg, plan, mesh, batch: int, q_len: int, n_pages: int,
          "qlen": jax.ShapeDtypeStruct((n_replicas * batch,), jnp.int32),
          "block_table": jax.ShapeDtypeStruct(
              (n_replicas * batch, n_max_pages), jnp.int32)}
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(verify, mesh,
                     in_specs=(pspecs, s["cache"], s["tokens"], s["pos"],
                               s["qlen"], s["block_table"]),
                     out_specs=(P(bt_ax, None, "model"), s["cache"]))
@@ -676,7 +681,7 @@ def make_prefill_chunk_step(cfg, plan, mesh, chunk: int, n_pages: int,
     n_cross = kvcache.pages_needed(cfg.enc_seq_len, page_size) \
         if has_cross else 0
 
-    def per_shard(params, cache, tokens, chunk_start, last_idx, block_table,
+    def prefill_chunk(params, cache, tokens, chunk_start, last_idx, block_table,
                   *extra):
         folded = kvcache.fold_replica_pools(cache)
         extra = list(extra)
@@ -717,7 +722,7 @@ def make_prefill_chunk_step(cfg, plan, mesh, chunk: int, n_pages: int,
         t["cross_block_table"] = jax.ShapeDtypeStruct(
             (n_replicas, n_cross), jnp.int32)
         extra_s.append(s["cross_block_table"])
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(prefill_chunk, mesh,
                     in_specs=(pspecs, s["cache"], s["tokens"],
                               s["chunk_start"], s["last_idx"],
                               s["block_table"], *extra_s),
@@ -742,7 +747,7 @@ def make_page_copy_step(cfg, plan, mesh, n_pages: int, page_size: int,
     r_loc = n_replicas_local(mesh, plan, n_replicas)
     bt_ax = batch_axes(plan)
 
-    def per_shard(cache, src, dst):
+    def page_copy(cache, src, dst):
         def leaf(pool):          # (reps, R_loc, n_pages, G, psz, D) folded
             pool = kvcache.fold_replica_pools(pool)
             for i in range(r_loc):
@@ -761,7 +766,7 @@ def make_page_copy_step(cfg, plan, mesh, n_pages: int, page_size: int,
     t = {"cache": cache_t,
          "src": jax.ShapeDtypeStruct((n_replicas,), jnp.int32),
          "dst": jax.ShapeDtypeStruct((n_replicas,), jnp.int32)}
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(page_copy, mesh,
                     in_specs=(s["cache"], s["src"], s["dst"]),
                     out_specs=s["cache"])
     return fn, t, s
@@ -795,7 +800,7 @@ def make_page_transfer_step(cfg, plan, mesh, n_pages: int, page_size: int,
     r_loc = n_replicas_local(mesh, plan, n_replicas)
     sizes = mesh_axis_sizes(mesh)
 
-    def per_shard(cache, src_rep, dst_rep, src_pages, dst_pages):
+    def page_transfer(cache, src_rep, dst_rep, src_pages, dst_pages):
         shard = jnp.int32(0)
         for a in plan.dp_axes:
             if sizes.get(a, 1) > 1:
@@ -832,7 +837,7 @@ def make_page_transfer_step(cfg, plan, mesh, n_pages: int, page_size: int,
          "dst_rep": jax.ShapeDtypeStruct((), jnp.int32),
          "src_pages": jax.ShapeDtypeStruct((n_lanes,), jnp.int32),
          "dst_pages": jax.ShapeDtypeStruct((n_lanes,), jnp.int32)}
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(page_transfer, mesh,
                     in_specs=(s["cache"], s["src_rep"], s["dst_rep"],
                               s["src_pages"], s["dst_pages"]),
                     out_specs=s["cache"])
@@ -888,7 +893,7 @@ def make_cross_kv_write_step(cfg, plan, mesh, n_pages: int, page_size: int,
         return (pool.at[:, pids, :, offs].set(val),
                 sc.at[:, pids, offs].set(amax * (1.0 / 127.0)))
 
-    def per_shard(params, cache, frames, cross_bt):
+    def cross_kv_write(params, cache, frames, cross_bt):
         folded = kvcache.fold_replica_pools(cache)
         for i in range(r_loc):
             enc = model.encode(params,
@@ -923,7 +928,7 @@ def make_cross_kv_write_step(cfg, plan, mesh, n_pages: int, page_size: int,
          "frames": jax.ShapeDtypeStruct((n_replicas, S_enc, cfg.d_model),
                                         jnp.dtype(cfg.dtype)),
          "cross_bt": jax.ShapeDtypeStruct((n_replicas, n_cross), jnp.int32)}
-    fn = _shard_map(per_shard, mesh,
+    fn = _shard_map(cross_kv_write, mesh,
                     in_specs=(pspecs, s["cache"], s["frames"], s["cross_bt"]),
                     out_specs=s["cache"])
     return fn, t, s
@@ -935,6 +940,16 @@ def zero_paged_cache_for(cfg, plan, mesh, n_pages, page_size,
     tmpl = kvcache.paged_cache_template(cfg, plan, lay, n_pages, page_size,
                                         n_replicas, n_slabs)
     return kvcache.zero_paged_cache(tmpl, mesh)
+
+
+@jax.jit
+def zero_slab(pool, replica, slab):
+    """-> an SSM state pool ``(reps, R, n_slabs, ...)`` with replica
+    ``replica``'s slab ``slab`` zeroed: the paged engine's reset of a
+    fresh request's recurrent state, as one compiled module named
+    ``jit_zero_slab`` on a trace.  Not donated: like ``.at[].set`` it
+    returns a new pool."""
+    return pool.at[:, replica, slab].set(0)
 
 
 _STEP_SET_MEMO: dict = {}

@@ -310,11 +310,15 @@ def main(argv=None):
     else:
         print("no tokens emitted")
     if engine.paged:
+        per_tick = 1e3 / max(stats.ticks, 1)
         print(f"pipeline: overlap={'on' if engine.overlap else 'off'} "
-              f"device_busy_fraction={stats.device_busy_fraction:.2f} "
               f"plan_ahead_ticks={stats.plan_ahead_ticks} "
               f"plan_invalidations={stats.plan_invalidations} "
               f"collect_wait={stats.collect_wait_s * 1e3:.1f}ms")
+        print(f"host per tick: plan={stats.plan_s * per_tick:.2f}ms "
+              f"wait={stats.collect_wait_s * per_tick:.2f}ms "
+              f"sample={stats.sample_s * per_tick:.2f}ms "
+              f"dispatch={stats.dispatch_s * per_tick:.2f}ms")
     if disagg is not None:
         print(f"disagg(P={disagg[0]} D={disagg[1]}): "
               f"handoffs={stats.handoffs} "

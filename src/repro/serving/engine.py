@@ -106,6 +106,10 @@ serial oracle.  Either way outputs are token-identical: admission/decode
 timing shifts are invisible to per-request RNG streams.  ``run()``,
 ``drain()`` and ``preempt()`` barrier on in-flight work first, so
 conservation accounting and SSM stashes never race a dispatched step.
+The host phases (plan, the collect's wait, sampling, dispatch) each run
+inside a named profiler span and add their seconds to an ``EngineStats``
+counter (``PHASE_COUNTERS``); the compiled steps carry their own names
+(``jit_paged_decode``, ``jit_prefill_chunk``, ...; ``core.steps``).
 
 **Disaggregated serving** (``disagg=(P, D)`` with ``dp == P + D``;
 attention-only archs): replicas split into P prefill-role and D
@@ -203,6 +207,34 @@ class PageTransferError(RuntimeError):
     step — a trace, compile or runtime error — propagates."""
 
 
+# The engine's host phases: profiler span -> the EngineStats field that
+# accumulates the same interval in seconds.
+PHASE_COUNTERS = {"engine.plan": "plan_s", "engine.wait": "collect_wait_s",
+                  "engine.sample": "sample_s",
+                  "engine.dispatch": "dispatch_s"}
+
+
+class _Phase:
+    """Times one host phase twice: a ``jax.profiler.TraceAnnotation`` (in
+    a profiler trace's host plane, on the clock of the device ops) and its
+    ``PHASE_COUNTERS`` field of ``EngineStats`` (``time.perf_counter``, so
+    the counter runs with the profiler off)."""
+    __slots__ = ("stats", "field", "span", "t0")
+
+    def __init__(self, stats, span: str):
+        self.stats, self.field = stats, PHASE_COUNTERS[span]
+        self.span = jax.profiler.TraceAnnotation(span)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        setattr(self.stats, self.field, getattr(self.stats, self.field) + dt)
+        self.span.__exit__(*exc)
+
+
 @dataclass
 class Request:
     rid: int
@@ -216,7 +248,11 @@ class Request:
     replica: int = -1                  # routed data shard (set at submit)
     out_tokens: list = field(default_factory=list)
     done: bool = False
+    # host stamps, time.perf_counter(): on Linux the same clock as
+    # time.monotonic() (CLOCK_MONOTONIC), and the clock a benchmark
+    # harness times its own spans with
     t_submit: float = 0.0
+    t_admitted: Optional[float] = None   # first admission into a slot
     t_first_token: float = 0.0
     t_done: float = 0.0
 
@@ -276,20 +312,14 @@ class EngineStats:
     readmitted: int = 0                # requests re-placed by drain/recovery
     plan_ahead_ticks: int = 0          # plan phases run with work in flight
     plan_invalidations: int = 0        # speculative plan entries rolled back
+    # host seconds per phase of the tick (``PHASE_COUNTERS``)
+    plan_s: float = 0.0                # plan phase: admissions, handoffs
     collect_wait_s: float = 0.0        # host time blocked at collect points
-    device_busy_s: float = 0.0         # dispatch->collect device intervals
-    tick_wall_s: float = 0.0           # total wall time inside tick()
+    sample_s: float = 0.0              # host sampling of fetched logits
+    dispatch_s: float = 0.0            # dispatch phase: inputs, enqueue
     tpot_s: list = field(default_factory=list)
     request_ttft: dict = field(default_factory=dict)   # rid -> seconds
     replicas: List[ReplicaStats] = field(default_factory=list)
-
-    @property
-    def device_busy_fraction(self) -> float:
-        """Fraction of tick wall time with dispatched work in flight — an
-        overlap health proxy (dispatch-to-collect intervals over total tick
-        time; approximate, since the device may finish before collect)."""
-        return min(self.device_busy_s / self.tick_wall_s, 1.0) \
-            if self.tick_wall_s else 0.0
 
     @property
     def ttft_s(self) -> list:
@@ -668,8 +698,9 @@ class ServingEngine:
     def _zero_slab(self, r: int, slab: int):
         """Fresh requests start from zero recurrent state; the previous
         occupant's state persists in the pool otherwise."""
+        from repro.core import steps as _steps
         self._update_kind(
-            "ssm", lambda leaf, i: leaf.at[:, r, slab].set(0))
+            "ssm", lambda leaf, i: _steps.zero_slab(leaf, r, slab))
 
     def _stash_slot(self, b: int, adm, n: int):
         """Checkpoint a preempted SSM-arch slot to host: the slab (state
@@ -751,7 +782,7 @@ class ServingEngine:
             # (engine seed, request seed), never on scheduling or routing
             seed = req.seed if req.seed is not None else req.rid
             req.rng = np.random.RandomState([self.rng_seed, seed])
-        req.t_submit = time.monotonic()
+        req.t_submit = time.perf_counter()
 
     def run(self, max_ticks: int = 10_000):
         while (self.has_pending() or
@@ -1225,12 +1256,14 @@ class ServingEngine:
                 jnp.asarray(self.last_token[:, None]),
                 jnp.asarray(self.pos))
         logits = np.asarray(jax.device_get(logits)).astype(np.float32)
-        now = time.monotonic()
-        for b, req in enumerate(self.slots):
-            if req is None:
-                continue
+        live = [(b, req) for b, req in enumerate(self.slots)
+                if req is not None]
+        with _Phase(self.stats, "engine.sample"):
+            toks = [self._sample_row(logits, b, req) for b, req in live]
+        now = time.perf_counter()
+        for (b, req), tok in zip(live, toks, strict=True):
             self.pos[b] += 1        # the decode step wrote last_token's KV
-            self._emit(b, req, self._sample_row(logits, b, req), now)
+            self._emit(b, req, tok, now)
         self.stats.ticks += 1
 
     def _sample_row(self, logits: np.ndarray, b: int, req: Request) -> int:
@@ -1266,6 +1299,7 @@ class ServingEngine:
         free = [b for b in range(self.B) if self.admissions[b] is None]
         for adm in self.scheds[0].plan(free):
             self.admissions[adm.slot] = adm
+            _stamp_admitted(adm.req)
             self._prefill_into(adm.slot, adm.req)
 
     def _prefill_into(self, b: int, req: Request):
@@ -1299,8 +1333,9 @@ class ServingEngine:
         # generated token: emit it (TTFT lands at prefill completion, and
         # max_new_tokens counts it)
         self.pos[b] = S
-        self._emit(b, req, self._sample_row(logits, 0, req),
-                   time.monotonic())
+        with _Phase(self.stats, "engine.sample"):
+            tok = self._sample_row(logits, 0, req)
+        self._emit(b, req, tok, time.perf_counter())
 
     # ------------------------------------------------------------ paged tick
     def _tick_paged(self):
@@ -1312,17 +1347,17 @@ class ServingEngine:
         by fault-injection harnesses or ops triggers) fires first, before
         any planning — scale_to/kill_replica barrier internally, so the
         hook sees (and leaves) a fully synchronous engine."""
-        t0 = time.monotonic()
         if self.membership_hook is not None:
             self.membership_hook(self)
-        tick_plan = self._plan_phase()
+        with _Phase(self.stats, "engine.plan"):
+            tick_plan = self._plan_phase()
         self._collect_phase()
         self._run_deferred_preempts(tick_plan)
-        self._dispatch_phase(tick_plan)
+        with _Phase(self.stats, "engine.dispatch"):
+            self._dispatch_phase(tick_plan)
         if not self.overlap:
             self._collect_phase()
         self.stats.ticks += 1
-        self.stats.tick_wall_s += time.monotonic() - t0
 
     def _rep_slots(self, r: int):
         return range(r * self.Bp, (r + 1) * self.Bp)
@@ -1410,6 +1445,7 @@ class ServingEngine:
                 b = self._gslot(r, adm.slot)
                 self.admissions[b] = adm
                 self.slot_state[b] = "prefill"
+                _stamp_admitted(adm.req)
                 if adm.cow is not None:
                     if n_cow == len(cow_rounds):
                         cow_rounds.append([None] * self.R)
@@ -1453,20 +1489,18 @@ class ServingEngine:
         if inf is None:
             return
         self._inflight = None
-        t0 = time.monotonic()
         handles = [h for h, comps in inf["pf"] if comps]
         step = inf["step"]
         if step is not None:
             handles.append(step[1])
-        vals = jax.device_get(handles) if handles else []
-        t1 = time.monotonic()
-        self.stats.collect_wait_s += t1 - t0
-        self.stats.device_busy_s += t1 - inf["t_dispatch"]
+        with _Phase(self.stats, "engine.wait"):
+            vals = jax.device_get(handles) if handles else []
         vi = 0
         for _, comps in inf["pf"]:
             if not comps:
                 continue
-            logits_np = np.asarray(vals[vi]).astype(np.float32)
+            with _Phase(self.stats, "engine.sample"):
+                logits_np = np.asarray(vals[vi]).astype(np.float32)
             vi += 1
             for r, b, rid, L in comps:
                 adm = self.admissions[b]
@@ -1481,8 +1515,9 @@ class ServingEngine:
                 # resumed requests re-enter with out_tokens non-empty, so
                 # TTFT is not re-recorded)
                 self.pos[b] = L
-                self._emit(b, req, self._sample_row(logits_np, r, req),
-                           time.monotonic())
+                with _Phase(self.stats, "engine.sample"):
+                    tok = self._sample_row(logits_np, r, req)
+                self._emit(b, req, tok, time.perf_counter())
                 if self.admissions[b] is None:
                     continue           # retired by that token
                 if self.roles is not None and self.roles[r] == "prefill":
@@ -1494,27 +1529,31 @@ class ServingEngine:
                     self.slot_state[b] = "decode"
         if step is None:
             return
-        logits = np.asarray(vals[vi]).astype(np.float32)
-        now = time.monotonic()
+        # slots still holding the request this step was dispatched for
+        live = [(b, self.admissions[b].req) for b, rid in step[2]
+                if self.admissions[b] is not None
+                and self.admissions[b].req.rid == rid]
         if step[0] == "decode":
-            for b, rid in step[2]:
-                adm = self.admissions[b]
-                if adm is None or adm.req.rid != rid:
-                    continue
+            # sample every row, then emit: an emission retires only its
+            # own slot, so the order changes nothing
+            with _Phase(self.stats, "engine.sample"):
+                logits = np.asarray(vals[vi]).astype(np.float32)
+                toks = [self._sample_row(logits, b, req) for b, req in live]
+            now = time.perf_counter()
+            for (b, req), tok in zip(live, toks, strict=True):
                 self.pos[b] += 1    # the decode step wrote last_token's KV
-                self._emit(b, adm.req, self._sample_row(logits, b, adm.req),
-                           now)
+                self._emit(b, req, tok, now)
         else:                        # verify
             drafts = step[3]
-            for b, rid in step[2]:
-                adm = self.admissions[b]
-                if adm is None or adm.req.rid != rid:
-                    continue
-                req = adm.req
+            with _Phase(self.stats, "engine.sample"):
+                logits = np.asarray(vals[vi]).astype(np.float32)
+            for b, req in live:
                 d = drafts.get(b, [])
-                out = speculative_sample(logits[b, :len(d) + 1], d,
-                                         self.sampler, self.cfg.vocab_size,
-                                         req.rng)
+                with _Phase(self.stats, "engine.sample"):
+                    out = speculative_sample(logits[b, :len(d) + 1], d,
+                                             self.sampler,
+                                             self.cfg.vocab_size, req.rng)
+                now = time.perf_counter()
                 emitted = 0
                 for tok in out:
                     self.pos[b] += 1    # verify wrote this position's KV
@@ -1584,8 +1623,7 @@ class ServingEngine:
         pf = self._dispatch_prefill()
         step = self._dispatch_step()
         if pf or step is not None:
-            self._inflight = {"pf": pf, "step": step,
-                              "t_dispatch": time.monotonic()}
+            self._inflight = {"pf": pf, "step": step}
 
     def _dispatch_handoffs(self, tick_plan: dict):
         """Execute the planned page-run transfers: one compiled gather →
@@ -1815,6 +1853,13 @@ class ServingEngine:
                 jnp.asarray(pos), jnp.asarray(qlen), jnp.asarray(bt))
         return ("verify", logits,
                 [(b, self.admissions[b].req.rid) for b in active], drafts)
+
+
+def _stamp_admitted(req: Request):
+    """Stamp a request's first admission; a resume after preemption or a
+    re-placement keeps it."""
+    if req.t_admitted is None:
+        req.t_admitted = time.perf_counter()
 
 
 def _splice_cache(big, lane, b):
