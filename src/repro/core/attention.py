@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import collectives as cc
+from repro.core.kvcache import page_rows
 
 NEG = -1e30
 
@@ -183,59 +184,82 @@ def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *, window=0,
 # Paged KV (block-table indirection over a page pool)
 # ---------------------------------------------------------------------------
 
-def gather_pages(pool, block_table):
+def _rows(pool, rows, at):
+    """Rows ``rows`` (B, n) of a pool's page axis.  With ``at`` = (layer,
+    replica (B,)) the pool is the stacked ``(reps, R, n_pages, ...)`` state
+    of the layer loop, and row b is gathered from (layer, replica[b])
+    straight out of it, so no layer's or replica's pool is ever sliced
+    out whole."""
+    if at is None:
+        return pool[rows]
+    layer, replica = at
+    return pool[layer, replica[:, None], rows]
+
+
+def gather_pages(pool, block_table, at=None):
     """Materialize each slot's logical KV stream from the page pool.
 
-    pool: (n_pages, G, psz, D); block_table: (B, n_max) int32 page ids.
+    pool: (n_pages, psz, G, D) token-major pages, or the stacked
+    (reps, R, n_pages, psz, G, D) pools with ``at`` = (layer, replica of
+    each row); block_table: (B, n_max) int32 page ids.
     -> (B, G, n_max * psz, D).  Pure-JAX gather: the Pallas kernel in
     ``repro.kernels.decode_attention`` streams pages via scalar-prefetched
     block tables instead of materializing this copy.
     """
-    n_pages, G, psz, D = pool.shape
+    psz, G, D = pool.shape[-3:]
     B, n_max = block_table.shape
-    g = jnp.take(pool, block_table.reshape(-1), axis=0)   # (B*n_max,G,psz,D)
-    g = g.reshape(B, n_max, G, psz, D)
-    return g.transpose(0, 2, 1, 3, 4).reshape(B, G, n_max * psz, D)
+    g = _rows(pool, block_table, at)                  # (B,n_max,psz,G,D)
+    return g.reshape(B, n_max * psz, G, D).transpose(0, 2, 1, 3)
 
 
-def gather_pages_dequant(pool, scales, block_table, dtype):
+def gather_pages_dequant(pool, scales, block_table, dtype, at=None):
     """``gather_pages`` for an int8 pool with per-(page, slot) scales.
 
-    pool: (n_pages, G, psz, D) int8; scales: (n_pages, psz) float32 —
-    one scale per token row, written atomically with the payload by
-    ``blocks._page_write`` / the cross-KV write step.
+    pool: (n_pages, psz, G, D) int8; scales: (n_pages * psz,) float32 —
+    one scale per token row, slot o of page p at p * psz + o, written
+    atomically with the payload by ``blocks._page_write`` / the cross-KV
+    write step (both stacked like the pool when ``at`` is given).
     -> (B, G, n_max * psz, D) in ``dtype``.
     """
     B, n_max = block_table.shape
-    psz = pool.shape[2]
-    g = gather_pages(pool, block_table).astype(jnp.float32)
-    s = jnp.take(scales, block_table.reshape(-1), axis=0)    # (B*n_max, psz)
+    psz = pool.shape[-3]
+    g = gather_pages(pool, block_table, at).astype(jnp.float32)
+    s = _rows(scales, page_rows(block_table, psz), at)    # (B, n_max*psz)
     s = s.reshape(B, 1, n_max * psz, 1)
     return (g * s).astype(dtype)
 
 
+def gather_kv(k_pool, v_pool, block_table, dtype, *, at=None,
+              k_scale=None, v_scale=None):
+    """-> the (K, V) streams, each (B, G, n_max * psz, D) in ``dtype``:
+    int8 pools dequantized through their per-row scales, float pools cast
+    after the gather, so only the pages the table names are converted."""
+    if k_scale is not None:
+        return (gather_pages_dequant(k_pool, k_scale, block_table, dtype, at),
+                gather_pages_dequant(v_pool, v_scale, block_table, dtype, at))
+    return (gather_pages(k_pool, block_table, at).astype(dtype),
+            gather_pages(v_pool, block_table, at).astype(dtype))
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_table, cur_pos, *,
                            window=0, softcap=0.0, scale=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, at=None):
     """Decode attention reading K/V through a block table.
 
-    q: (B, G, R, D); pools: (n_pages, G, psz, D); block_table: (B, n_max);
-    cur_pos: (B,) absolute position of the current token.  Slot s of the
-    gathered stream holds absolute position s by construction, so validity
-    is simply s <= cur_pos (plus the sliding window).
+    q: (B, G, R, D); pools: (n_pages, psz, G, D), or stacked with ``at``
+    as in ``gather_pages``; block_table: (B, n_max); cur_pos: (B,) absolute
+    position of the current token.  Slot s of the gathered stream holds
+    absolute position s by construction, so validity is simply s <=
+    cur_pos (plus the sliding window).
 
-    ``k_scale``/``v_scale`` ((n_pages, psz) float32): int8 pools are
+    ``k_scale``/``v_scale`` ((n_pages * psz,) float32): int8 pools are
     dequantized through their per-row scales before scoring.
     """
     B = q.shape[0]
-    L = block_table.shape[1] * k_pool.shape[2]
+    L = block_table.shape[1] * k_pool.shape[-3]
     kv_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
-    if k_scale is not None:
-        kf = gather_pages_dequant(k_pool, k_scale, block_table, q.dtype)
-        vf = gather_pages_dequant(v_pool, v_scale, block_table, q.dtype)
-    else:
-        kf = gather_pages(k_pool, block_table)
-        vf = gather_pages(v_pool, block_table)
+    kf, vf = gather_kv(k_pool, v_pool, block_table, q.dtype, at=at,
+                       k_scale=k_scale, v_scale=v_scale)
     return decode_attention(q, kf, vf, kv_pos,
                             cur_pos, window=window, softcap=softcap,
                             scale=scale, tag="attn/paged_decode")
@@ -243,7 +267,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, cur_pos, *,
 
 def paged_verify_attention(q, k_pool, v_pool, block_table, cur_pos, *,
                            window=0, softcap=0.0, scale=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, at=None):
     """Q-query decode attention for speculative verify.
 
     q: (B, G, R, Q, D) — per slot, query i sits at absolute position
@@ -260,13 +284,9 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, cur_pos, *,
     """
     B, G, R, Q, D = q.shape
     scale = scale if scale is not None else D ** -0.5
-    L = block_table.shape[1] * k_pool.shape[2]
-    if k_scale is not None:
-        kf = gather_pages_dequant(k_pool, k_scale, block_table, q.dtype)
-        vf = gather_pages_dequant(v_pool, v_scale, block_table, q.dtype)
-    else:
-        kf = gather_pages(k_pool, block_table)
-        vf = gather_pages(v_pool, block_table)
+    L = block_table.shape[1] * k_pool.shape[-3]
+    kf, vf = gather_kv(k_pool, v_pool, block_table, q.dtype, at=at,
+                       k_scale=k_scale, v_scale=v_scale)
     s = jnp.einsum("bgrqd,bgsd->bgrqs", q, kf,
                    preferred_element_type=jnp.float32) * scale
     if softcap:

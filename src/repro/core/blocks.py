@@ -19,8 +19,7 @@ from repro.configs.base import FFN_MOE, FFN_NONE, MIX_ATTN, MIX_SSM
 from repro.core import collectives as cc
 from repro.core import ssm as ssd
 from repro.core.attention import decode_attention, flash_attention, \
-    gather_pages, gather_pages_dequant, paged_decode_attention, \
-    paged_verify_attention
+    gather_kv, paged_decode_attention, paged_verify_attention
 from repro.core.layers import activation, apply_norm, apply_rope, rmsnorm, \
     rmsnorm_from_sumsq
 from repro.core.moe import moe_ffn_ep, moe_ffn_tp
@@ -188,18 +187,13 @@ def cross_attn_mixer(xn, pa, cfg, plan, lay, mode, cross_cache, enc_memory,
         q = rmsnorm(q, pa["q_norm"], cfg.norm_eps)
     qg = _group_q(q, lay)
     if cross_cache is not None and "ckp" in cross_cache:   # paged, read-only
-        cbt = pages["cross_block_table"]
         S_enc = cfg.enc_seq_len
-        if "cksp" in cross_cache:                          # int8 + scales
-            kg = gather_pages_dequant(cross_cache["ckp"], cross_cache["cksp"],
-                                      cbt, qg.dtype)[:, :, :S_enc]
-            vg = gather_pages_dequant(cross_cache["cvp"], cross_cache["cvsp"],
-                                      cbt, qg.dtype)[:, :, :S_enc]
-        else:
-            kg = gather_pages(_kv_dq(cross_cache["ckp"], qg.dtype),
-                              cbt)[:, :, :S_enc]
-            vg = gather_pages(_kv_dq(cross_cache["cvp"], qg.dtype),
-                              cbt)[:, :, :S_enc]
+        kg, vg = gather_kv(cross_cache["ckp"], cross_cache["cvp"],
+                           pages["cross_block_table"], qg.dtype,
+                           at=(pages["layer"], pages["replica"]),
+                           k_scale=cross_cache.get("cksp"),
+                           v_scale=cross_cache.get("cvsp"))
+        kg, vg = kg[:, :, :S_enc], vg[:, :, :S_enc]
         if mode == "decode":
             out = decode_attention(
                 qg[:, :, :, 0], kg, vg,
@@ -268,94 +262,75 @@ def _kv_write(kv, kg, vg, pos, plan):
 
 
 def _paged_attn(qg, kg, vg, kv, pages, mode, positions, pos, window, cfg):
-    """Paged-cache attention (decode token or prefill chunk).
+    """Paged-cache attention (decode token, verify block or prefill chunk).
 
-    kv: {"kp","vp"} page pools (n_pages, G, psz, D); pages: {"block_table"}.
-    Token t of a slot lives at page block_table[t // psz], offset t % psz;
-    the gathered stream therefore holds absolute position s at slot s and
-    validity reduces to s <= cur_pos (decode) / causal masking (chunk).
+    kv: {"kp","vp"[,"ksp","vsp"]} stacked page pools (reps, R, n_pages,
+    psz, G, D), the layer loop's carried state; pages: {"block_table",
+    "page_size", "layer", "replica"}.  Token t of row b lives at page
+    block_table[b, t // psz], offset t % psz, of layer ``pages["layer"]``
+    and replica ``pages["replica"][b]``; the gathered stream therefore
+    holds absolute position s at slot s and validity reduces to s <=
+    cur_pos (decode) / causal masking (chunk).
     Garbage between a prompt's end and its chunk boundary is never read:
     every later position is decode-written before it first becomes visible.
     """
-    bt = pages["block_table"]
-    psz = kv["kp"].shape[2]
-    quant = "ksp" in kv
+    bt, at = pages["block_table"], (pages["layer"], pages["replica"])
+    # decode writes one token per row; verify writes all Q tokens of the
+    # block (token i at pos + i: token 0 is the slot's last accepted token,
+    # the rest are drafts; padded/overflow rows carry position -1 and land
+    # on the scratch page); a prefill chunk writes the chunk
+    new = _page_write(kv, kg, vg, pos[:, None] if mode == "decode"
+                      else positions, bt, pages["page_size"], at)
+    read = dict(at=at, k_scale=new.get("ksp"), v_scale=new.get("vsp"))
     if mode == "decode":
-        new = _page_write(kv, kg, vg, pos[:, None], bt, psz)
-        if quant:
-            out = paged_decode_attention(
-                qg[:, :, :, 0], new["kp"], new["vp"], bt, pos, window=window,
-                scale=cfg.attn_scale, k_scale=new["ksp"], v_scale=new["vsp"])
-        else:
-            out = paged_decode_attention(
-                qg[:, :, :, 0], _kv_dq(new["kp"], qg.dtype),
-                _kv_dq(new["vp"], qg.dtype), bt, pos, window=window,
-                scale=cfg.attn_scale)
+        out = paged_decode_attention(qg[:, :, :, 0], new["kp"], new["vp"], bt,
+                                     pos, window=window,
+                                     scale=cfg.attn_scale, **read)
         return out[:, :, :, None, :], new
     if mode == "verify":
-        # speculative verify: token i of the block sits at position
-        # pos + i (token 0 = the slot's last accepted token, the rest are
-        # drafts).  Write all Q tokens' KV — padded/overflow rows carry
-        # position -1 and land on the scratch page — then score every
-        # position against the gathered stream in one pass; acceptance
-        # and rollback are host-side pos bookkeeping (rejected KV is
-        # masked by validity until the next step overwrites it)
-        new = _page_write(kv, kg, vg, positions, bt, psz)
-        if quant:
-            out = paged_verify_attention(
-                qg, new["kp"], new["vp"], bt, pos, window=window,
-                scale=cfg.attn_scale, k_scale=new["ksp"], v_scale=new["vsp"])
-        else:
-            out = paged_verify_attention(
-                qg, _kv_dq(new["kp"], qg.dtype), _kv_dq(new["vp"], qg.dtype),
-                bt, pos, window=window, scale=cfg.attn_scale)
+        # score every position against the gathered stream in one pass;
+        # acceptance and rollback are host-side pos bookkeeping (rejected
+        # KV is masked by validity until the next step overwrites it)
+        out = paged_verify_attention(qg, new["kp"], new["vp"], bt, pos,
+                                     window=window, scale=cfg.attn_scale,
+                                     **read)
         return out, new
-    # prefill chunk: write the chunk, then attend to the gathered prefix
-    new = _page_write(kv, kg, vg, positions, bt, psz)
-    if quant:
-        k_all = gather_pages_dequant(new["kp"], new["ksp"], bt, qg.dtype)
-        v_all = gather_pages_dequant(new["vp"], new["vsp"], bt, qg.dtype)
-    else:
-        k_all = gather_pages(_kv_dq(new["kp"], qg.dtype), bt)  # (B,G,L,D)
-        v_all = gather_pages(_kv_dq(new["vp"], qg.dtype), bt)
+    # prefill chunk: attend to the gathered prefix, the chunk included
+    k_all, v_all = gather_kv(new["kp"], new["vp"], bt, qg.dtype, **read)
     out = flash_attention(qg, k_all, v_all, causal=True, window=window,
                           scale=cfg.attn_scale, q_offset=positions[0, 0])
     return out, new
 
 
-def _page_write(kv, kg, vg, positions, bt, psz):
-    """Scatter new K/V into the page pool.  kg/vg: (B, G, C, D);
-    positions: (B, C) absolute token positions (C = 1 for decode).
-    Negative positions (padded verify queries) route to the scratch page
-    (page 0), whose contents are never read by a live slot.
+def _page_write(kv, kg, vg, positions, bt, psz, at):
+    """Scatter new K/V rows into the stacked page pools, in place.
+    kg/vg: (B, G, C, D); positions: (B, C) absolute token positions (C = 1
+    for decode); at: (layer, replica (B,)).  Each token is one (G, D) row,
+    written at (layer, replica, page, offset).  Negative positions (padded
+    verify queries) route to the scratch page (page 0), whose contents are
+    never read by a live slot.
 
     Quantized pools (``ksp``/``vsp`` present): each token row is quantized
     independently with its own per-row scale (``_row_quant``), and the
-    scale is scattered atomically with the payload into the per-(page,
+    scale is scattered atomically with the payload into the flat per-(page,
     slot) scale tensor."""
     B, G, C, D = kg.shape
     safe = jnp.maximum(positions, 0)
     pid = jnp.take_along_axis(bt, safe // psz, axis=1)         # (B, C)
     pid = jnp.where(positions >= 0, pid, 0)
-    off = safe % psz
-    flat_pid, flat_off = pid.reshape(-1), off.reshape(-1)
+    layer, rep = at
+    rep = jnp.broadcast_to(rep[:, None], (B, C))
+    at = (layer, rep, pid, safe % psz)                         # each (B, C)
     if "ksp" in kv:
         kq, ks = _row_quant(kg.transpose(0, 2, 1, 3))          # (B,C,G,D)
         vq, vs = _row_quant(vg.transpose(0, 2, 1, 3))
-        return {
-            "kp": kv["kp"].at[flat_pid, :, flat_off].set(
-                kq.reshape(B * C, G, D)),
-            "vp": kv["vp"].at[flat_pid, :, flat_off].set(
-                vq.reshape(B * C, G, D)),
-            "ksp": kv["ksp"].at[flat_pid, flat_off].set(ks.reshape(B * C)),
-            "vsp": kv["vsp"].at[flat_pid, flat_off].set(vs.reshape(B * C)),
-        }
+        at_s = (layer, rep, pid * psz + safe % psz)            # flat scales
+        return {"kp": kv["kp"].at[at].set(kq), "vp": kv["vp"].at[at].set(vq),
+                "ksp": kv["ksp"].at[at_s].set(ks),
+                "vsp": kv["vsp"].at[at_s].set(vs)}
     kq = _kv_q(kg, kv["kp"].dtype).transpose(0, 2, 1, 3)       # (B,C,G,D)
     vq = _kv_q(vg, kv["vp"].dtype).transpose(0, 2, 1, 3)
-    return {
-        "kp": kv["kp"].at[flat_pid, :, flat_off].set(kq.reshape(B * C, G, D)),
-        "vp": kv["vp"].at[flat_pid, :, flat_off].set(vq.reshape(B * C, G, D)),
-    }
+    return {"kp": kv["kp"].at[at].set(kq), "vp": kv["vp"].at[at].set(vq)}
 
 
 def _kv_fill(kv, kg, vg, positions, plan):
@@ -533,37 +508,42 @@ def ssm_mixer(xn, ps, cfg, plan, lay, mode, ssm_cache, chunk_last_idx=None):
 def _paged_ssm(xn, ps, cfg, plan, lay, mode, slab_pool, pages):
     """SSM mixer against the slab pools (paged serving).
 
-    slab_pool: {"statep","conv_xp","conv_Bp","conv_Cp"} with a leading
-    ``n_slabs`` dim; pages["slab_ids"]: (B,) slab id per batch row.  Each
-    row gathers its slab into the per-slot view ``ssm_mixer`` expects,
-    runs one decode token or one prefill chunk with carried state, and
-    scatters the updated state back.  Idle/prefilling decode lanes point
-    at the reserved scratch slab (id 0), so full-batch decode never
-    corrupts a live slab."""
-    sid = pages["slab_ids"]
+    slab_pool: {"statep","conv_xp","conv_Bp","conv_Cp"[,"sscalep"]}, the
+    stacked (reps, R, n_slabs, ...) pools the layer loop carries;
+    pages["slab_ids"], pages["replica"]: (B,) slab id and replica per
+    batch row, pages["layer"]: this layer's index into the stack.  Each
+    row gathers its slab of this layer into the per-slot view
+    ``ssm_mixer`` expects (conv tails unflattened), runs one decode token
+    or one prefill chunk with carried state, and scatters the updated
+    state back in place at (layer, replica, slab).  Idle/prefilling
+    decode lanes point at the reserved scratch slab (id 0), so full-batch
+    decode never corrupts a live slab."""
+    at = (pages["layer"], pages["replica"], pages["slab_ids"])
     quant = "sscalep" in slab_pool
     if quant:
         # int8 slabs: dequant through the per-(slab, head) scale on gather,
         # re-quantize the whole slab on scatter (full-overwrite semantics,
         # so the stored bytes depend only on the new state's value)
-        state = (slab_pool["statep"][sid].astype(jnp.float32) *
-                 slab_pool["sscalep"][sid][:, :, None, None])
+        state = (slab_pool["statep"][at].astype(jnp.float32) *
+                 slab_pool["sscalep"][at][:, :, None, None])
     else:
-        state = slab_pool["statep"][sid]
-    view = {"state": state,
-            "conv_x": slab_pool["conv_xp"][sid],
-            "conv_B": slab_pool["conv_Bp"][sid],
-            "conv_C": slab_pool["conv_Cp"][sid]}
+        state = slab_pool["statep"][at]
+    K1 = cfg.ssm_conv - 1
+    B = xn.shape[0]
+    view = {"state": state}
+    for k in ("conv_x", "conv_B", "conv_C"):     # flat channel-major tails
+        view[k] = slab_pool[k + "p"][at].reshape(B, -1, K1).swapaxes(1, 2)
     out, new = ssm_mixer(xn, ps, cfg, plan, lay, mode, view,
                          chunk_last_idx=(pages.get("last_idx")
                                          if mode != "decode" else None))
-    pools = {k + "p": slab_pool[k + "p"].at[sid].set(
-        v.astype(slab_pool[k + "p"].dtype))
+    pools = {k + "p": slab_pool[k + "p"].at[at].set(
+        (v if k == "state" else v.swapaxes(1, 2).reshape(B, -1))
+        .astype(slab_pool[k + "p"].dtype))
         for k, v in new.items() if not (quant and k == "state")}
     if quant:
         q, s = _row_quant(new["state"])                  # (B,H,P,N), (B,H)
-        pools["statep"] = slab_pool["statep"].at[sid].set(q)
-        pools["sscalep"] = slab_pool["sscalep"].at[sid].set(
+        pools["statep"] = slab_pool["statep"].at[at].set(q)
+        pools["sscalep"] = slab_pool["sscalep"].at[at].set(
             s.astype(slab_pool["sscalep"].dtype))
     return out, pools
 

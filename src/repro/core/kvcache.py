@@ -174,17 +174,28 @@ def _map_tmpl(tmpl, fn):
 # one static allocation whose placement never changes — while letting a
 # single compiled decode/prefill-chunk pair serve arbitrary request mixes.
 #
-# Pool layout keeps the contiguous convention with the page pool standing in
-# for the batch dim:   kp / vp : (reps, n_replicas, n_pages, tp*n_kv_loc,
-# psz, D) sharded P(None, dp_axes, None, tpax, None, None): heads follow
+# Pages are stored token-major:   kp / vp : (reps, n_replicas, n_pages, psz,
+# tp*n_kv_loc, D) sharded P(None, dp_axes, None, None, tpax, None).  One
+# token's (G, D) row is whole tiles in the minor dims, so a decode write of
+# one token per slot is a scatter of rows, and the compiler keeps the
+# pool's layout as stored (a head-major page, (G, psz, D), made every step
+# re-lay out the whole pool).  For the same reason the small pools keep a
+# long minor dim: int8 scales are flat, (reps, n_replicas, n_pages * psz),
+# and a slab's conv tails are one flat channel-major row; a minor dim of
+# psz or K-1 made the compiler copy those pools every layer.  Heads follow
 # TP, and the leading replica dim is sharded over the data axes so each
 # data shard holds only its own replicas' pages — the paper's
-# stationary-local-memory discipline at serving scale.  Block tables stay
-# replica-relative (ids in [0, n_pages)); ``core.steps`` folds each
-# shard's local replicas into one larger pool and offsets the tables
-# row-wise, so attention/kernels never see the replica dim.  With
-# n_replicas == 1 (the default) the layout degenerates to the old
+# stationary-local-memory discipline at serving scale.  Block tables and
+# slab ids stay replica-relative (ids in [0, n_pages)); the compiled steps
+# index the replica dim beside them, (replica, page), and never reshape a
+# pool.  With n_replicas == 1 (the default) the layout degenerates to the
 # replicated-pool dp=1 behavior.
+#
+# The stacked pools are in-place state of the layer loop: the paged steps
+# carry every layer's pools (pages, scales, slabs) through the layer scan
+# whole, and each layer reads and writes its own rows by (layer, replica,
+# page, offset) or (layer, replica, slab) (``model._run_paged_stack``), so
+# no compiled step slices a layer's pool out or copies a pool.
 #
 # Page 0 of every replica is reserved as a scratch page: idle decode lanes
 # point their block tables at it, so the fused decode step can always run
@@ -240,7 +251,8 @@ def paged_cache_template(cfg, plan, lay, n_pages: int, page_size: int,
     * ``ssm``   — ``statep``/``conv_xp``/``conv_Bp``/``conv_Cp`` slab
       pools: ``n_slabs`` rows of per-request recurrent state, read/written
       by slot-relative slab id (no paging — SSD state is O(1) per request
-      and cannot be shared),
+      and cannot be shared); a slab's conv tails are one flat,
+      channel-major row (``blocks._paged_ssm`` reshapes it),
     * ``cross_kv`` — ``ckp``/``cvp`` page pools holding the encoder
       memory's K/V.  Cross pages share the self-KV page-id space (one
       allocator covers both) and are immutable after the encode-time
@@ -253,10 +265,10 @@ def paged_cache_template(cfg, plan, lay, n_pages: int, page_size: int,
     **Quantized pools** (``plan.kv_cache_dtype == "int8"`` /
     ``plan.ssm_cache_dtype == "int8"``): payloads store int8 and each pool
     gains a small float32 scale side tensor — ``ksp``/``vsp`` (and
-    ``cksp``/``cvsp`` for cross) of shape (n_replicas, n_pages, page_size),
-    one scale per (page, token slot) so every token row is quantized
-    independently of write order (schedule/speculation/preemption
-    invariance by construction); ``sscalep`` of shape (n_replicas,
+    ``cksp``/``cvsp`` for cross) of shape (n_replicas, n_pages *
+    page_size), one scale per (page, token slot) so every token row is
+    quantized independently of write order (schedule/speculation/
+    preemption invariance by construction); ``sscalep`` of shape (n_replicas,
     n_slabs, tp*H), one scale per (slab, head), re-written wholesale on
     every state scatter.  A zero scale dequantizes to exact zeros, so
     ``zero_paged_cache`` leaves the quantized pools indistinguishable
@@ -271,25 +283,27 @@ def paged_cache_template(cfg, plan, lay, n_pages: int, page_size: int,
     d = cfg.head_dim_
     tpax = "model" if plan.tp > 1 else None
     dpax = tuple(plan.dp_axes)
-    pool = ((n_replicas, n_pages, plan.tp * lay.attn.n_kv_loc, page_size, d),
-            kvd, P(dpax, None, tpax, None, None))
-    scale = ((n_replicas, n_pages, page_size), jnp.float32,
-             P(dpax, None, None))
+    pool = ((n_replicas, n_pages, page_size, plan.tp * lay.attn.n_kv_loc, d),
+            kvd, P(dpax, None, None, tpax, None))
+    # flat: slot o of page p at p * page_size + o (``page_rows``)
+    scale = ((n_replicas, n_pages * page_size), jnp.float32, P(dpax, None))
     slab = None
     if "ssm" in cache_profile(cfg):
         assert n_slabs > 1, f"ssm layers need n_slabs > 1, got {n_slabs}"
         H, Pdim, N = lay.ssm.hq_loc, cfg.ssm_head_dim, cfg.ssm_state
         K = cfg.ssm_conv
         sd = jnp.int8 if ssm_pool_is_quantized(plan) else jnp.float32
+        # conv tails flat and channel-major: channel c's K-1 rows at
+        # c * (K-1) .. c * (K-1) + K-2, so TP shards whole channels
         slab = {
             "statep": ((n_replicas, n_slabs, plan.tp * H, Pdim, N),
                        sd, P(dpax, None, tpax, None, None)),
-            "conv_xp": ((n_replicas, n_slabs, K - 1, plan.tp * H * Pdim),
-                        jnp.dtype(cfg.dtype), P(dpax, None, None, tpax)),
-            "conv_Bp": ((n_replicas, n_slabs, K - 1, N), jnp.dtype(cfg.dtype),
-                        P(dpax, None, None, None)),
-            "conv_Cp": ((n_replicas, n_slabs, K - 1, N), jnp.dtype(cfg.dtype),
-                        P(dpax, None, None, None)),
+            "conv_xp": ((n_replicas, n_slabs, plan.tp * H * Pdim * (K - 1)),
+                        jnp.dtype(cfg.dtype), P(dpax, None, tpax)),
+            "conv_Bp": ((n_replicas, n_slabs, N * (K - 1)),
+                        jnp.dtype(cfg.dtype), P(dpax, None, None)),
+            "conv_Cp": ((n_replicas, n_slabs, N * (K - 1)),
+                        jnp.dtype(cfg.dtype), P(dpax, None, None)),
         }
         if sd == jnp.int8:
             slab["sscalep"] = ((n_replicas, n_slabs, plan.tp * H),
@@ -317,25 +331,18 @@ def paged_cache_template(cfg, plan, lay, n_pages: int, page_size: int,
     return tmpl
 
 
-def fold_replica_pools(cache):
-    """(reps, R_loc, n_pages, G, psz, D) -> (reps, R_loc*n_pages, G, psz, D).
-
-    Per-shard view: the shard's local replicas become one larger pool, so
-    the attention gather/scatter path is replica-agnostic.  Replica ``i``'s
-    page ``p`` lives at folded id ``i * n_pages + p`` (see
-    ``replica_table_offsets``)."""
-    return jax.tree_util.tree_map(
-        lambda pool: pool.reshape((pool.shape[0],
-                                   pool.shape[1] * pool.shape[2])
-                                  + pool.shape[3:]), cache)
-
-
-def unfold_replica_pools(cache, n_replicas_loc: int):
-    """Inverse of ``fold_replica_pools``."""
-    return jax.tree_util.tree_map(
-        lambda pool: pool.reshape(
-            (pool.shape[0], n_replicas_loc, pool.shape[1] // n_replicas_loc)
-            + pool.shape[2:]), cache)
+def page_rows(pids, rows_per_page: int):
+    """-> the rows along a pool leaf's page axis that hold pages ``pids``,
+    in order (the last axis of ``pids`` grows by ``rows_per_page``).  A
+    payload pool holds one row per page; a flat scale pool
+    (``ksp``/``vsp``/``cksp``/``cvsp``) holds ``page_size`` rows per page,
+    so ``rows_per_page`` is the leaf's page-axis length over the pages."""
+    pids = jnp.asarray(pids, jnp.int32)
+    if rows_per_page == 1:
+        return pids
+    rows = pids[..., None] * rows_per_page + jnp.arange(rows_per_page,
+                                                        dtype=jnp.int32)
+    return rows.reshape(pids.shape[:-1] + (-1,))
 
 
 def zero_paged_cache(tmpl, mesh):

@@ -442,6 +442,9 @@ def _run_stack(x, stack_params, groups, cfg, plan, lay, mode, positions,
                pos=None, enc_memory=None, cache=None, causal_specs=None,
                pages=None):
     """Scan every layer group.  cache: list aligned with groups (or None)."""
+    if pages is not None:
+        return _run_paged_stack(x, stack_params, groups, cfg, plan, lay, mode,
+                                positions, pos, cache, pages)
     new_cache = [] if cache is not None else None
     for gi, (group, gparams) in enumerate(zip(groups, stack_params, strict=True)):
         gcache = cache[gi] if cache is not None else None
@@ -452,8 +455,7 @@ def _run_stack(x, stack_params, groups, cfg, plan, lay, mode, positions,
             for pi, spec in enumerate(group.pattern):
                 ci = c_rep[pi] if c_rep is not None else None
                 xc, nc = layer_forward(xc, p_rep[pi], ci, cfg, plan, lay,
-                                       spec, mode, positions, pos, enc_memory,
-                                       pages)
+                                       spec, mode, positions, pos, enc_memory)
                 nc_rep.append(nc if nc is not None else {})
             return xc, (nc_rep if c_rep is not None else None)
 
@@ -477,6 +479,44 @@ def _run_stack(x, stack_params, groups, cfg, plan, lay, mode, positions,
                 x, nc = jax.lax.scan(body, x, (gparams, gcache))
         if new_cache is not None:
             new_cache.append(nc)
+    return x, new_cache
+
+
+def _run_paged_stack(x, stack_params, groups, cfg, plan, lay, mode,
+                     positions, pos, cache, pages):
+    """The paged steps' layer loop (decode, verify, prefill chunk).
+
+    Each group's stacked pools (pages, their scales, SSM slabs, cross
+    pages) are the loop's carried state, whole: layer ``li`` reads and
+    writes only its own rows of them by index (``pages["layer"]``), so the
+    donated pools are updated in place and no layer's pool is sliced out
+    or re-stacked.  Scanning them as ``xs``/``ys`` instead makes the
+    compiled step allocate and copy whole pools."""
+    new_cache = []
+    for group, gparams, gcache in zip(groups, stack_params, cache,
+                                      strict=True):
+
+        def body(carry, per_rep, group=group):   # bind the loop var (B023)
+            xc, pools = carry
+            p_rep, li = per_rep
+            layer_pages = dict(pages, layer=li)
+            new_pools = []
+            for pi, spec in enumerate(group.pattern):
+                xc, nc = layer_forward(xc, p_rep[pi], pools[pi], cfg, plan,
+                                       lay, spec, mode, positions, pos,
+                                       None, layer_pages)
+                new_pools.append(nc if nc is not None else {})
+            return (xc, new_pools), None
+
+        with cc.LEDGER.scaled(group.n_reps):
+            if group.n_reps == 1:
+                p_rep = jax.tree_util.tree_map(lambda a: a[0], gparams)
+                (x, gcache), _ = body((x, gcache), (p_rep, 0))
+            else:
+                (x, gcache), _ = jax.lax.scan(
+                    body, (x, gcache),
+                    (gparams, jnp.arange(group.n_reps, dtype=jnp.int32)))
+        new_cache.append(gcache)
     return x, new_cache
 
 

@@ -7,6 +7,7 @@ devices) and the 512-device production dry-run.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -502,10 +503,12 @@ def zero_cache_for(cfg, plan, mesh, batch, budget):
 # recompiles.  Heads keep the model-axis TP sharding; the page pools carry a
 # leading replica dim sharded over the data axes (``n_replicas``), so with
 # dp>1 each data shard stores only its own replicas' pages.  Block tables
-# stay replica-relative: each per-shard function folds its local replicas
-# into one larger pool and offsets table rows by ``local_replica *
-# n_pages``, so attention and the Pallas kernels never see the replica dim,
-# and n_replicas == 1 reproduces the old dp=1 behavior exactly.
+# stay replica-relative: each per-shard function passes every row's local
+# replica beside its page ids (``pages["replica"]``), and the layer loop
+# carries the stacked pools whole, reading and writing rows by (layer,
+# replica, page, offset) or (layer, replica, slab) in place
+# (``model._run_paged_stack``), so no step re-shapes, slices or copies a
+# pool.  n_replicas == 1 reproduces the dp=1 behavior exactly.
 #
 # Architecture coverage: attention layers read/write ``kp``/``vp`` page
 # pools through block tables; SSM/hybrid layers read/write their
@@ -568,20 +571,18 @@ def make_paged_decode_step(cfg, plan, mesh, batch: int, n_pages: int,
         if has_cross else 0
 
     def paged_decode(params, cache, tokens, pos, block_table, *extra):
-        # fold this shard's replicas into one pool; rows stay
-        # replica-relative, so offset each row into its replica's range
-        rep_row = jnp.arange(r_loc * batch, dtype=jnp.int32) // batch
-        offs = rep_row[:, None] * n_pages
-        pages = {"block_table": block_table + offs, "page_size": page_size}
+        # rows stay replica-relative: row i belongs to this shard's local
+        # replica i // batch, which indexes the pools beside the page ids
+        pages = {"block_table": block_table, "page_size": page_size,
+                 "replica": jnp.arange(r_loc * batch, dtype=jnp.int32)
+                 // batch}
         extra = list(extra)
         if has_ssm:
-            pages["slab_ids"] = extra.pop(0) + rep_row * n_slabs
+            pages["slab_ids"] = extra.pop(0)
         if has_cross:
-            pages["cross_block_table"] = extra.pop(0) + offs
-        logits, folded = model.forward_decode(
-            params, kvcache.fold_replica_pools(cache), tokens, pos, cfg,
-            plan, lay, pages=pages)
-        return logits, kvcache.unfold_replica_pools(folded, r_loc)
+            pages["cross_block_table"] = extra.pop(0)
+        return model.forward_decode(params, cache, tokens, pos, cfg, plan,
+                                    lay, pages=pages)
 
     s = {"cache": cache_s, "tokens1": P(bt_ax, None), "pos": P(bt_ax),
          "block_table": P(bt_ax, None)}
@@ -631,13 +632,11 @@ def make_verify_step(cfg, plan, mesh, batch: int, q_len: int, n_pages: int,
     bt_ax = batch_axes(plan)
 
     def verify(params, cache, tokens, pos, qlen, block_table):
-        rep_row = jnp.arange(r_loc * batch, dtype=jnp.int32) // batch
-        offs = rep_row[:, None] * n_pages
-        pages = {"block_table": block_table + offs, "page_size": page_size}
-        logits, folded = model.forward_verify(
-            params, kvcache.fold_replica_pools(cache), tokens, pos, qlen,
-            cfg, plan, lay, pages=pages)
-        return logits, kvcache.unfold_replica_pools(folded, r_loc)
+        pages = {"block_table": block_table, "page_size": page_size,
+                 "replica": jnp.arange(r_loc * batch, dtype=jnp.int32)
+                 // batch}
+        return model.forward_verify(params, cache, tokens, pos, qlen, cfg,
+                                    plan, lay, pages=pages)
 
     s = {"cache": cache_s, "tokens": P(bt_ax, None), "pos": P(bt_ax),
          "qlen": P(bt_ax), "block_table": P(bt_ax, None)}
@@ -683,25 +682,24 @@ def make_prefill_chunk_step(cfg, plan, mesh, chunk: int, n_pages: int,
 
     def prefill_chunk(params, cache, tokens, chunk_start, last_idx, block_table,
                   *extra):
-        folded = kvcache.fold_replica_pools(cache)
         extra = list(extra)
         slab_ids = extra.pop(0) if has_ssm else None
         cross_bt = extra.pop(0) if has_cross else None
         logits = []
         for i in range(r_loc):               # one chunk per local replica
-            pages = {"block_table": block_table[i:i + 1] + i * n_pages,
-                     "page_size": page_size}
+            pages = {"block_table": block_table[i:i + 1],
+                     "page_size": page_size,
+                     "replica": jnp.full((1,), i, jnp.int32)}
             if has_ssm:
-                pages["slab_ids"] = slab_ids[i:i + 1] + i * n_slabs
+                pages["slab_ids"] = slab_ids[i:i + 1]
                 pages["last_idx"] = last_idx[i]
             if has_cross:
-                pages["cross_block_table"] = cross_bt[i:i + 1] + i * n_pages
-            lg, folded = model.forward_prefill_chunk(
-                params, folded, tokens[i:i + 1], chunk_start[i],
+                pages["cross_block_table"] = cross_bt[i:i + 1]
+            lg, cache = model.forward_prefill_chunk(
+                params, cache, tokens[i:i + 1], chunk_start[i],
                 last_idx[i], cfg, plan, lay, pages)
             logits.append(lg)
-        return (jnp.concatenate(logits, axis=0),
-                kvcache.unfold_replica_pools(folded, r_loc))
+        return jnp.concatenate(logits, axis=0), cache
 
     s = {"cache": cache_s, "tokens": P(bt_ax, None),
          "chunk_start": P(bt_ax), "last_idx": P(bt_ax),
@@ -748,14 +746,16 @@ def make_page_copy_step(cfg, plan, mesh, n_pages: int, page_size: int,
     bt_ax = batch_axes(plan)
 
     def page_copy(cache, src, dst):
-        def leaf(pool):          # (reps, R_loc, n_pages, G, psz, D) folded
-            pool = kvcache.fold_replica_pools(pool)
+        def leaf(pool):          # (reps, R_loc, n_pages, psz, G, D)
+            per = pool.shape[2] // n_pages       # rows a page spans
+            size = (pool.shape[0], 1, per) + pool.shape[3:]
+            tail = (0,) * (pool.ndim - 3)
             for i in range(r_loc):
-                page = jax.lax.dynamic_slice_in_dim(
-                    pool, src[i] + i * n_pages, 1, axis=1)
-                pool = jax.lax.dynamic_update_slice_in_dim(
-                    pool, page, dst[i] + i * n_pages, axis=1)
-            return kvcache.unfold_replica_pools(pool, r_loc)
+                page = jax.lax.dynamic_slice(pool, (0, i, src[i] * per)
+                                             + tail, size)
+                pool = jax.lax.dynamic_update_slice(
+                    pool, page, (0, i, dst[i] * per) + tail)
+            return pool
         # only the self-KV pools: slab/cross ids live in other spaces
         return [[{kind: (jax.tree_util.tree_map(leaf, sub)
                          if kind == "kv" else sub)
@@ -811,19 +811,17 @@ def make_page_transfer_step(cfg, plan, mesh, n_pages: int, page_size: int,
         local_dst = dst_rep - base
         dst_ok = (local_dst >= 0) & (local_dst < r_loc)
 
-        def leaf(pool):          # folded page axis is axis 1 on every leaf
-            pool = kvcache.fold_replica_pools(pool)
-            rows = jnp.clip(local_src, 0, r_loc - 1) * n_pages + src_pages
-            data = jnp.take(pool, rows, axis=1)
+        def leaf(pool):          # (reps, R_loc, n_pages * rows a page, ...)
+            per = pool.shape[2] // n_pages
+            src_r = jnp.clip(local_src, 0, r_loc - 1)
+            data = pool[:, src_r, kvcache.page_rows(src_pages, per)]
             data = jnp.where(src_ok, data, jnp.zeros_like(data))
             data = cc.psum(data, tuple(plan.dp_axes), "page_transfer")
-            dst_rows = jnp.where(
-                dst_ok,
-                jnp.clip(local_dst, 0, r_loc - 1) * n_pages + dst_pages,
-                0)               # non-owners write their scratch page
-            pool = pool.at[:, dst_rows].set(
-                jnp.where(dst_ok, data, jnp.take(pool, dst_rows, axis=1)))
-            return kvcache.unfold_replica_pools(pool, r_loc)
+            # non-owners write their scratch page
+            dst_r = jnp.clip(local_dst, 0, r_loc - 1)
+            dst_rows = kvcache.page_rows(jnp.where(dst_ok, dst_pages, 0), per)
+            return pool.at[:, dst_r, dst_rows].set(
+                jnp.where(dst_ok, data, pool[:, dst_r, dst_rows]))
         # only the self-KV pools: slab/cross ids live in other spaces
         return [[{kind: (jax.tree_util.tree_map(leaf, sub)
                          if kind == "kv" else sub)
@@ -869,32 +867,33 @@ def make_cross_kv_write_step(cfg, plan, mesh, n_pages: int, page_size: int,
     S_enc = cfg.enc_seq_len
     n_cross = kvcache.pages_needed(S_enc, page_size)
 
-    def scatter(pool, kv1, bt_row, off):
-        """pool: (reps, R_loc*n_pages, G, psz, D); kv1: (reps, G, S_enc, D)
-        -> pool with position s written at page bt_row[s // psz] + off,
-        offset s % psz."""
-        pids = jnp.take(bt_row, jnp.arange(S_enc) // page_size) + off
+    def scatter(pool, kv1, bt_row, i):
+        """pool: (reps, R_loc, n_pages, psz, G, D); kv1: (reps, G, S_enc,
+        D) -> pool with position s written at replica i's page
+        bt_row[s // psz], offset s % psz."""
+        pids = jnp.take(bt_row, jnp.arange(S_enc) // page_size)
         offs = jnp.arange(S_enc) % page_size
-        val = _kv_q(kv1, pool.dtype).transpose(2, 0, 1, 3)  # (S_enc,reps,G,D)
-        return pool.at[:, pids, :, offs].set(val)
+        val = _kv_q(kv1, pool.dtype).transpose(0, 2, 1, 3)  # (reps,S_enc,G,D)
+        return pool.at[:, i, pids, offs].set(val)
 
-    def scatter_q(pool, sc, kv1, bt_row, off):
+    def scatter_q(pool, sc, kv1, bt_row, i):
         """int8 pools: per-token-row quantization, scale scattered into the
-        (reps, R_loc*n_pages, psz) side tensor atomically with the payload
-        (same row scheme as ``blocks._row_quant``)."""
-        pids = jnp.take(bt_row, jnp.arange(S_enc) // page_size) + off
+        flat (reps, R_loc, n_pages*psz) side tensor atomically with the
+        payload (same row scheme as ``blocks._row_quant``)."""
+        pids = jnp.take(bt_row, jnp.arange(S_enc) // page_size)
         offs = jnp.arange(S_enc) % page_size
         kf = kv1.astype(jnp.float32)
         amax = jnp.max(jnp.abs(kf), axis=(1, 3))            # (reps, S_enc)
         inv = jnp.where(amax > 0, 127.0 / jnp.maximum(amax, 1e-30), 0.0)
         q = jnp.clip(jnp.round(kf * inv[:, None, :, None]),
                      -127, 127).astype(jnp.int8)
-        val = q.transpose(2, 0, 1, 3)                       # (S_enc,reps,G,D)
-        return (pool.at[:, pids, :, offs].set(val),
-                sc.at[:, pids, offs].set(amax * (1.0 / 127.0)))
+        val = q.transpose(0, 2, 1, 3)                       # (reps,S_enc,G,D)
+        return (pool.at[:, i, pids, offs].set(val),
+                sc.at[:, i, pids * page_size + offs].set(
+                    amax * (1.0 / 127.0)))
 
     def cross_kv_write(params, cache, frames, cross_bt):
-        folded = kvcache.fold_replica_pools(cache)
+        cache = [list(pat) for pat in cache]
         for i in range(r_loc):
             enc = model.encode(params,
                                frames[i:i + 1].astype(jnp.dtype(cfg.dtype)),
@@ -904,23 +903,21 @@ def make_cross_kv_write_step(cfg, plan, mesh, n_pages: int, page_size: int,
                 for pi, kv in enumerate(per_pat):
                     if kv is None:
                         continue
-                    cr = folded[gi][pi]["cross"]
+                    cr = cache[gi][pi]["cross"]
                     if "cksp" in cr:
                         ckp, cksp = scatter_q(cr["ckp"], cr["cksp"],
-                                              kv["k"][:, 0], cross_bt[i],
-                                              i * n_pages)
+                                              kv["k"][:, 0], cross_bt[i], i)
                         cvp, cvsp = scatter_q(cr["cvp"], cr["cvsp"],
-                                              kv["v"][:, 0], cross_bt[i],
-                                              i * n_pages)
+                                              kv["v"][:, 0], cross_bt[i], i)
                         cr = {"ckp": ckp, "cvp": cvp,
                               "cksp": cksp, "cvsp": cvsp}
                     else:
                         cr = {"ckp": scatter(cr["ckp"], kv["k"][:, 0],
-                                             cross_bt[i], i * n_pages),
+                                             cross_bt[i], i),
                               "cvp": scatter(cr["cvp"], kv["v"][:, 0],
-                                             cross_bt[i], i * n_pages)}
-                    folded[gi][pi] = dict(folded[gi][pi], cross=cr)
-        return kvcache.unfold_replica_pools(folded, r_loc)
+                                             cross_bt[i], i)}
+                    cache[gi][pi] = dict(cache[gi][pi], cross=cr)
+        return cache
 
     s = {"cache": cache_s, "frames": P(bt_ax, None, None),
          "cross_bt": P(bt_ax, None)}
@@ -942,13 +939,13 @@ def zero_paged_cache_for(cfg, plan, mesh, n_pages, page_size,
     return kvcache.zero_paged_cache(tmpl, mesh)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def zero_slab(pool, replica, slab):
     """-> an SSM state pool ``(reps, R, n_slabs, ...)`` with replica
     ``replica``'s slab ``slab`` zeroed: the paged engine's reset of a
     fresh request's recurrent state, as one compiled module named
-    ``jit_zero_slab`` on a trace.  Not donated: like ``.at[].set`` it
-    returns a new pool."""
+    ``jit_zero_slab`` on a trace.  The pool is donated and zeroed in
+    place; a new pool would hold a second copy of every slab."""
     return pool.at[:, replica, slab].set(0)
 
 

@@ -184,7 +184,8 @@ import numpy as np
 
 from repro.core.kvcache import (SCRATCH_PAGE, SCRATCH_SLAB, PageAllocator,
                                 SlabAllocator, cache_profile,
-                                kv_pool_is_quantized, pages_needed)
+                                kv_pool_is_quantized, page_rows,
+                                pages_needed)
 from repro.serving.prefix_cache import (CrossKVCache, HostSpillStore,
                                         PromptLookupDraft, RadixPrefixCache)
 from repro.serving.router import Router
@@ -676,16 +677,22 @@ class ServingEngine:
                             else v) for k, v in d.items()}
                        for d in pat] for pat in self.cache]
 
+    def _rows(self, leaf, pids):
+        """Rows of a pool leaf's page axis that hold pages ``pids``: one a
+        page, or ``page_size`` a page in a flat scale pool
+        (``kvcache.page_rows``)."""
+        return page_rows(np.asarray(pids, np.int32),
+                         leaf.shape[2] // self.n_pool_pages)
+
     def _reset_scale_rows(self, r: int, pids):
         """Zero the per-(page, slot) scale rows of recycled pages in
         replica ``r`` — scale 0 dequantizes to exact zeros, so a recycled
         page can never pair a fresh payload with a stale scale (each write
         re-sets payload + scale atomically, but rows past a new occupant's
         length would otherwise keep the previous owner's scales)."""
-        idx = jnp.asarray(np.asarray(pids, np.int32))
-
         def upd(kind):
-            self.cache = [[{k: ({kk: (vv.at[:, r, idx].set(0.0)
+            self.cache = [[{k: ({kk: (vv.at[:, r, self._rows(vv, pids)]
+                                      .set(0.0)
                                       if kk.endswith("sp") else vv)
                                  for kk, vv in v.items()}
                                 if k == kind and isinstance(v, dict) else v)
@@ -714,10 +721,10 @@ class ServingEngine:
             stash["ssm"].append(np.asarray(leaf[:, r, adm.slab]))
         if adm.pages:
             k = pages_needed(n, self.page_size)
-            pids = jnp.asarray(np.asarray(adm.pages[:k], np.int32))
             stash["n_kv_pages"] = k
             for leaf in self._kind_leaves("kv"):
-                stash["kv"].append(np.asarray(leaf[:, r, pids]))
+                stash["kv"].append(np.asarray(
+                    leaf[:, r, self._rows(leaf, adm.pages[:k])]))
         self._stash[adm.req.rid] = stash
 
     def _restore_slot(self, b: int, adm, stash):
@@ -731,10 +738,10 @@ class ServingEngine:
                 jnp.asarray(ssm_payload[i])))
         k = stash["n_kv_pages"]
         if k:
-            pids = jnp.asarray(np.asarray(adm.pages[:k], np.int32))
             kv_payload = stash["kv"]
             self._update_kind(
-                "kv", lambda leaf, i: leaf.at[:, r, pids].set(
+                "kv", lambda leaf, i: leaf.at[
+                    :, r, self._rows(leaf, adm.pages[:k])].set(
                     jnp.asarray(kv_payload[i])))
         self.stats.slab_restores += 1
 
@@ -1181,17 +1188,15 @@ class ServingEngine:
             pc = self.prefix_caches[r]
             if pc is not None:
                 for toks, pages in pc.entries():
-                    pids = jnp.asarray(np.asarray(pages, np.int32))
                     store.put_prefix(
                         toks, len(pages),
-                        [np.asarray(leaf[:, r, pids])
+                        [np.asarray(leaf[:, r, self._rows(leaf, pages)])
                          for leaf in self._kind_leaves("kv")])
             if self.has_cross:
                 for key, pages in self.cross_caches[r].entries():
-                    pids = jnp.asarray(np.asarray(pages, np.int32))
                     store.put_cross(
                         key, len(pages),
-                        [np.asarray(leaf[:, r, pids])
+                        [np.asarray(leaf[:, r, self._rows(leaf, pages)])
                          for leaf in self._kind_leaves("cross")])
         return store
 
@@ -1216,10 +1221,10 @@ class ServingEngine:
                 pages = self.allocators[r].alloc(k)
                 if pages is None:
                     continue          # pool pressure: stay spilled
-                pids = jnp.asarray(np.asarray(pages, np.int32))
                 self._update_kind(
-                    "kv", lambda leaf, i, r=r, pids=pids, pl=payloads:
-                    leaf.at[:, r, pids].set(jnp.asarray(pl[i])))
+                    "kv", lambda leaf, i, r=r, pages=pages, pl=payloads:
+                    leaf.at[:, r, self._rows(leaf, pages)].set(
+                        jnp.asarray(pl[i])))
                 self.prefix_caches[r].insert(prompt, pages)
                 # the cache holds its own refs now; shared-prefix pages we
                 # over-allocated drop to rc 0 here and recycle harmlessly
@@ -1235,10 +1240,10 @@ class ServingEngine:
                 pages = self.allocators[r].alloc(k)
                 if pages is None:
                     continue
-                pids = jnp.asarray(np.asarray(pages, np.int32))
                 self._update_kind(
-                    "cross", lambda leaf, i, r=r, pids=pids, pl=payloads:
-                    leaf.at[:, r, pids].set(jnp.asarray(pl[i])))
+                    "cross", lambda leaf, i, r=r, pages=pages, pl=payloads:
+                    leaf.at[:, r, self._rows(leaf, pages)].set(
+                        jnp.asarray(pl[i])))
                 self.cross_caches[r].insert(key, pages)
                 self.allocators[r].decref(pages)
                 store.pages_restored += k

@@ -415,7 +415,8 @@ class HostSpillStore:
     pools afterwards (``_restore_from_spill``).  Keys are the caches' own
     identities: the leaf token path for radix entries, the frames digest
     for cross entries.  Payload lists hold one numpy array per cache leaf
-    of the kind, gathered as ``leaf[:, r, pids]`` — int8 payloads and
+    of the kind, gathered as the leaf's rows for pages ``pids`` in
+    replica ``r`` (``ServingEngine._rows``) — int8 payloads and
     their scale rows are separate leaves and ride along byte-identically
     (the SSM preemption stash proved this gather/restore mechanism).
 

@@ -60,5 +60,67 @@ def main():
     return 0
 
 
+def layouts():
+    """The carried pools are indexed by (layer, replica, page): two
+    replicas per data shard (dp=4 on the 2-device data mesh) and heads
+    split over a 2-device model axis (tp=2) give the one-device logits for
+    every served token, for KV pages and SSM slabs.  Logits, not tokens:
+    a tiny random model's greedy tokens barely depend on what attention
+    reads."""
+    m1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    m2 = make_mesh((2, 1), ("data", "model"))
+    mt = make_mesh((1, 2), ("data", "model"))
+
+    def run(cfg, plan, mesh, params, dp):
+        eng = ServingEngine.build_paged(cfg, plan, mesh, 2, 32, params,
+                                        page_size=4, prefill_chunk=8, dp=dp)
+        rows, sample = {}, eng._sample_row
+
+        def sample_row(logits, b, req):     # record every row sampled
+            rows[req.rid, len(req.out_tokens)] = np.asarray(
+                logits[b, :cfg.vocab_size], np.float64)
+            return sample(logits, b, req)
+        eng._sample_row = sample_row
+        rng = np.random.RandomState(0)
+        rs = [Request(rid=i, prompt=rng.randint(2, cfg.vocab_size, n)
+                      .astype(np.int32), max_new_tokens=5)
+              for i, n in enumerate([5, 11, 9, 14, 3, 7, 12, 6, 10, 4])]
+        for r in rs:
+            eng.submit(r)
+        eng.run(max_ticks=3000)
+        assert all(r.done for r in rs), [r.rid for r in rs if not r.done]
+        assert all(st.routed > 0 for st in eng.stats.replicas), \
+            "a replica served nothing"
+        return rows
+
+    def same(got, want, what):
+        assert got.keys() == want.keys(), what
+        for k in want:
+            np.testing.assert_allclose(got[k], want[k], atol=1e-4,
+                                       err_msg=f"{what} {k}")
+
+    # tp=2 is compared in float: int8 rounding can turn the psum's other
+    # summation order into a different stored byte
+    for name, kvd, ssd, tp2 in (("tinyllama-42m", "float32", "", True),
+                                ("hymba-1.5b", "int8", "int8", False),
+                                ("hymba-1.5b", "float32", "", True)):
+        cfg = reduced(get_config(name), dtype="float32")
+        p1 = ShardingPlan(tp=1, kv_cache_dtype=kvd, ssm_cache_dtype=ssd)
+        params = model.init_params(cfg, p1, seed=1)
+        oracle = run(cfg, p1, m1, params, 1)
+        if not tp2:
+            same(run(cfg, p1, m2, params, 4), oracle,
+                 f"{name} {kvd}: two replicas per data shard")
+            continue
+        p2 = ShardingPlan(tp=2, kv_cache_dtype=kvd, ssm_cache_dtype=ssd)
+        same(run(cfg, p2, mt, model.init_params(cfg, p2, seed=1, mesh=mt),
+                 1), oracle, f"{name} {kvd}: tp=2")
+        if name == "tinyllama-42m":
+            same(run(cfg, p1, m2, params, 4), oracle,
+                 f"{name} {kvd}: two replicas per data shard")
+    print("paged-layouts OK: dp=4 on 2 shards and tp=2 == 1 device")
+    return 0
+
+
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(layouts() if sys.argv[1:] == ["--layouts"] else main())

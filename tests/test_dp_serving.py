@@ -46,22 +46,9 @@ def test_paged_template_shards_replicas_over_data_axes():
         and isinstance(x[0], tuple))
     assert trips
     for shape, _, spec in trips:
-        # (reps, n_replicas, n_pages, G, psz, D), replicas on the dp axes
+        # (reps, n_replicas, n_pages, psz, G, D), replicas on the dp axes
         assert shape[1] == 2 and shape[2] == 8
         assert tuple(spec)[1] == "data"
-
-
-def test_fold_replica_pools_roundtrip():
-    import jax.numpy as jnp
-    pool = jnp.arange(2 * 3 * 4 * 5).reshape(1, 2, 3 * 4 * 5) \
-        .reshape(1, 2, 3, 4, 5).astype(jnp.float32)
-    folded = kvcache.fold_replica_pools(pool)
-    assert folded.shape == (1, 6, 4, 5)
-    # replica i's page p lands at folded id i*n_pages + p
-    np.testing.assert_array_equal(np.asarray(folded[0, 3 + 2]),
-                                  np.asarray(pool[0, 1, 2]))
-    back = kvcache.unfold_replica_pools(folded, 2)
-    np.testing.assert_array_equal(np.asarray(back), np.asarray(pool))
 
 
 # ---------------------------------------------------------------------------
@@ -351,6 +338,25 @@ def test_dp2_equivalence_on_real_data_mesh_subprocess():
         r.stdout[-3000:] + r.stderr[-2000:]
 
 
+def test_paged_pools_on_real_meshes_subprocess():
+    """Two replicas per data shard and tp=2 head sharding on REAL 2-device
+    meshes give the 1-device oracle's logits for every served token, for
+    KV pages and SSM slabs.  Runs ``tests/dp_equiv_main.py --layouts``."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    r = subprocess.run([sys.executable,
+                        os.path.join(root, "tests", "dp_equiv_main.py"),
+                        "--layouts"],
+                       capture_output=True, text=True, env=env,
+                       timeout=1200)
+    assert "paged-layouts OK" in r.stdout, \
+        r.stdout[-3000:] + r.stderr[-2000:]
+
+
 def test_n_replicas_must_cover_data_extent():
     from repro.core import steps as _steps
 
@@ -378,7 +384,6 @@ def _assert_scale_hygiene(eng):
         clean = sorted(a._free_set - a._scale_dirty)
         if not clean:
             continue
-        idx = np.asarray(clean, np.int32)
         for pat in eng.cache:
             for d in pat:
                 for kind in ("kv", "cross"):
@@ -387,7 +392,8 @@ def _assert_scale_hygiene(eng):
                         continue
                     for kk, vv in leaves.items():
                         if kk.endswith("sp"):
-                            rows = np.asarray(vv[:, rr, idx])
+                            rows = np.asarray(
+                                vv[:, rr, eng._rows(vv, clean)])
                             assert not rows.any(), (rr, kk, clean)
 
 

@@ -376,7 +376,7 @@ def test_spill_restore_int8_byte_identity(cfg, params, mesh1):
                  if eng.prefix_caches[r].n_cached_pages > 0)
     before = {}
     for toks, pages in eng.prefix_caches[donor].entries():
-        before[toks] = [np.asarray(leaf[:, donor, list(pages)])
+        before[toks] = [np.asarray(leaf[:, donor, eng._rows(leaf, pages)])
                         for leaf in eng._kind_leaves("kv")]
     assert before, "no radix entries to spill"
     keep = 1 - donor
@@ -389,7 +389,7 @@ def test_spill_restore_int8_byte_identity(cfg, params, mesh1):
         assert n == len(toks), "restored prefix not found"
         for leaf, want in zip(eng._kind_leaves("kv"), payloads):
             np.testing.assert_array_equal(
-                np.asarray(leaf[:, 0, list(pages)]), want)
+                np.asarray(leaf[:, 0, eng._rows(leaf, pages)]), want)
     _assert_leak_free(eng)
 
 

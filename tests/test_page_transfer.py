@@ -76,17 +76,23 @@ def _kv_leaves(cache):
     return out
 
 
-def _fill_kv(cache, rep, pids, rng):
+def _rows(v, pids, n_pages):
+    """Rows of leaf ``v``'s page axis holding pages ``pids``: a flat scale
+    pool spans page_size rows a page."""
+    return np.asarray(kvcache.page_rows(np.asarray(pids, np.int32),
+                                        v.shape[2] // n_pages))
+
+
+def _fill_kv(cache, rep, pids, rng, n_pages):
     """Write deterministic random values into replica ``rep``'s pages
     ``pids`` on every self-KV leaf (payload and scale tensors alike)."""
-    pids = np.asarray(pids, np.int32)
-
     def leaf(v):
         if v.ndim < 3:
             return v
-        fill = rng.randint(-127, 128, (v.shape[0], len(pids))
+        rows = _rows(v, pids, n_pages)
+        fill = rng.randint(-127, 128, (v.shape[0], len(rows))
                            + v.shape[3:]).astype(v.dtype)
-        return v.at[:, rep, pids].set(fill)
+        return v.at[:, rep, rows].set(fill)
 
     return [[{k: (jax.tree_util.tree_map(leaf, sub) if k == "kv" else sub)
               for k, sub in d.items()} for d in pat] for pat in cache]
@@ -103,7 +109,7 @@ def test_transfer_step_moves_payload_and_scales_byte_identical(mesh1, plan):
     rng = np.random.RandomState(0)
     src_pages, dst_pages = [2, 4], [1, 3]
     bystander = 5
-    cache = _fill_kv(cache, 0, src_pages + [bystander], rng)
+    cache = _fill_kv(cache, 0, src_pages + [bystander], rng, n_pages)
     before = [np.asarray(v) for v in _kv_leaves(cache)]
     with mesh1:
         out = fn(cache, np.int32(0), np.int32(1),
@@ -118,13 +124,18 @@ def test_transfer_step_moves_payload_and_scales_byte_identical(mesh1, plan):
         # destination replica's pages carry the exact source bytes —
         # int8 payloads and float32 scale rows never round-trip through
         # a dequantize/requantize
+        def rows(p):
+            return _rows(b4, [p], n_pages)
+
         for sp, dp in zip(src_pages, dst_pages):
-            np.testing.assert_array_equal(af[:, 1, dp], b4[:, 0, sp])
+            np.testing.assert_array_equal(af[:, 1, rows(dp)],
+                                          b4[:, 0, rows(sp)])
         # the source pages and untouched pages are bitwise unchanged
         for p in src_pages + [bystander]:
-            np.testing.assert_array_equal(af[:, 0, p], b4[:, 0, p])
-        np.testing.assert_array_equal(af[:, 1, bystander],
-                                      b4[:, 1, bystander])
+            np.testing.assert_array_equal(af[:, 0, rows(p)],
+                                          b4[:, 0, rows(p)])
+        np.testing.assert_array_equal(af[:, 1, rows(bystander)],
+                                      b4[:, 1, rows(bystander)])
 
 
 # ---------------------------------------------------------------------------

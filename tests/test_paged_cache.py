@@ -49,13 +49,20 @@ def test_pages_needed():
 # ---------------------------------------------------------------------------
 
 def _scatter_to_pages(k, bt, psz, n_pages):
-    """Contiguous (B, G, S, D) -> page pool (n_pages, G, psz, D)."""
+    """Contiguous (B, G, S, D) -> head-major page pool (n_pages, G, psz,
+    D), the Pallas kernels' layout."""
     B, G, S, D = k.shape
     pool = np.zeros((n_pages, G, psz, D), k.dtype)
     for b in range(B):
         for t in range(S):
             pool[bt[b, t // psz], :, t % psz] = k[b, :, t]
     return pool
+
+
+def _token_major(pool):
+    """Head-major pages (n_pages, G, psz, D) -> the served pools' token-
+    major pages (n_pages, psz, G, D)."""
+    return np.ascontiguousarray(pool.transpose(0, 2, 1, 3))
 
 
 def _random_tables(rng, B, n_max, n_pages):
@@ -74,8 +81,8 @@ def test_paged_decode_attention_matches_contiguous():
     k = rng.randn(B, G, S, D).astype(np.float32)
     v = rng.randn(B, G, S, D).astype(np.float32)
     bt = _random_tables(rng, B, n_max, n_pages)
-    kp = _scatter_to_pages(k, bt, psz, n_pages)
-    vp = _scatter_to_pages(v, bt, psz, n_pages)
+    kp = _token_major(_scatter_to_pages(k, bt, psz, n_pages))
+    vp = _token_major(_scatter_to_pages(v, bt, psz, n_pages))
     kv_pos = np.broadcast_to(np.arange(S, dtype=np.int32), (B, S))
     for window in (0, 7):
         ref = decode_attention(jnp.asarray(q), jnp.asarray(k),
@@ -126,8 +133,8 @@ def test_paged_verify_attention_matches_ref():
     k = rng.randn(B, G, S, D).astype(np.float32)
     v = rng.randn(B, G, S, D).astype(np.float32)
     bt = _random_tables(rng, B, n_max, n_pages)
-    kp = _scatter_to_pages(k, bt, psz, n_pages)
-    vp = _scatter_to_pages(v, bt, psz, n_pages)
+    kp = _token_major(_scatter_to_pages(k, bt, psz, n_pages))
+    vp = _token_major(_scatter_to_pages(v, bt, psz, n_pages))
     got = paged_verify_attention(jnp.asarray(q), jnp.asarray(kp),
                                  jnp.asarray(vp), jnp.asarray(bt),
                                  jnp.asarray(pos))
@@ -245,6 +252,110 @@ def test_paged_steps_match_contiguous_mixed_lengths(mesh1):
         np.testing.assert_allclose(np.asarray(got_logits),
                                    np.asarray(ref_logits), atol=1e-5)
         alloc.free(pages)
+
+
+def _changed_rows(before, after, n_lead):
+    """-> set of index tuples over the first ``n_lead`` axes of a leaf
+    where ``after`` differs from ``before`` anywhere in the trailing
+    axes (bitwise)."""
+    diff = before != after
+    diff = diff.reshape(diff.shape[:n_lead] + (-1,)).any(-1)
+    return set(map(tuple, np.argwhere(diff).tolist()))
+
+
+@pytest.mark.parametrize("name", ["qwen3-0.6b", "mamba2-370m"])
+def test_paged_steps_write_only_addressed_rows(name, mesh1):
+    """One prefill chunk and one decode step update the carried pools in
+    place: every layer's (page, offset) rows and slab entries that the
+    step addresses change, and every other layer, page, offset and slab
+    stays bit-identical.  The logits are the contiguous reference path's
+    (head-major caches, never paged)."""
+    cfg = reduced(get_config(name), dtype="float32")
+    params = model.init_params(cfg, PLAN)
+    has_ssm = steps.paged_extra_inputs(cfg)[0]
+    S, PSZ, CHUNK, L = 32, 4, 16, 11
+    N_MAX, N_PAGES, N_SLABS = S // PSZ, 2 * (S // PSZ) + 1, 3
+    bt = np.zeros((2, N_MAX), np.int32)          # row 1: idle, scratch page
+    bt[0] = np.arange(N_MAX) * 2 + 3             # pages 3, 5, ... 17
+    sid = 2
+    prompt = np.random.RandomState(0).randint(2, cfg.vocab_size, L) \
+        .astype(np.int32)
+
+    # reference: exact-length contiguous prefill, then one decode step
+    pre, _, _ = steps.make_prefill_step(cfg, PLAN, mesh1,
+                                        ShapeConfig("p", "decode", S, 1))
+    dec, _, _ = steps.make_decode_step(cfg, PLAN, mesh1,
+                                       ShapeConfig("d", "decode", S, 1))
+    with mesh1:
+        ref_pre, cc = jax.jit(pre)(params, jnp.asarray(prompt[None]),
+                                   steps.zero_cache_for(cfg, PLAN, mesh1, 1,
+                                                        S))
+        tok = int(np.argmax(ref_pre[0]))
+        ref_dec, _ = jax.jit(dec)(params, cc, jnp.asarray([[tok]], jnp.int32),
+                                  jnp.asarray([L], jnp.int32))
+
+    # paged: every pool filled with noise, the request's slab zeroed as the
+    # engine zeroes a fresh one
+    chunk_fn, _, _ = steps.make_prefill_chunk_step(
+        cfg, PLAN, mesh1, CHUNK, N_PAGES, PSZ, N_MAX,
+        n_slabs=N_SLABS if has_ssm else 0)
+    pdec, _, _ = steps.make_paged_decode_step(
+        cfg, PLAN, mesh1, 2, N_PAGES, PSZ, N_MAX,
+        n_slabs=N_SLABS if has_ssm else 0)
+    cache = steps.zero_paged_cache_for(cfg, PLAN, mesh1, N_PAGES, PSZ,
+                                       n_slabs=N_SLABS if has_ssm else 0)
+    rng = np.random.RandomState(1)
+    cache = jax.tree_util.tree_map(
+        lambda x: jnp.asarray(rng.randn(*x.shape), x.dtype), cache)
+    cache = [[{k: (jax.tree_util.tree_map(
+        lambda v: steps.zero_slab(v, 0, sid), sub) if k == "ssm" else sub)
+        for k, sub in d.items()} for d in pat] for pat in cache]
+    toks = np.zeros((1, CHUNK), np.int32)
+    toks[0, :L] = prompt
+    slab_in = [jnp.asarray([sid], jnp.int32)] if has_ssm else []
+    with mesh1:
+        got_pre, c1 = jax.jit(chunk_fn)(
+            params, cache, jnp.asarray(toks), jnp.asarray([0], jnp.int32),
+            jnp.asarray([L - 1], jnp.int32), jnp.asarray(bt[:1]), *slab_in)
+        slab_in = [jnp.asarray([sid, 0], jnp.int32)] if has_ssm else []
+        got_dec, c2 = jax.jit(pdec)(
+            params, c1, jnp.asarray([[tok], [0]], jnp.int32),
+            jnp.asarray([L, 0], jnp.int32), jnp.asarray(bt), *slab_in)
+    np.testing.assert_allclose(np.asarray(got_pre[0]), np.asarray(ref_pre[0]),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_dec[0]), np.asarray(ref_dec[0]),
+                               atol=1e-5)
+
+    # (page, offset) rows each step addresses: the chunk writes all CHUNK
+    # positions of row 0; decode writes position L of row 0 and position 0
+    # of the idle row, which points at the scratch page and slab.  The live
+    # request's own rows (prompt positions, then position L) must change.
+    def page_of(t):
+        return int(bt[0, t // PSZ]), t % PSZ
+
+    chunk_rows = {page_of(t) for t in range(CHUNK)}
+    dec_rows = {page_of(L), (0, 0)}
+    for before, after, rows, live_rows, slabs in (
+            (cache, c1, chunk_rows, {page_of(t) for t in range(L)}, {sid}),
+            (c1, c2, dec_rows, {page_of(L)}, {sid, 0})):
+        for pat_b, pat_a in zip(before, after, strict=True):
+            for d_b, d_a in zip(pat_b, pat_a, strict=True):
+                assert d_b.keys() == d_a.keys()
+                for kind in d_b:
+                    for key, leaf in d_b[kind].items():
+                        b, a = np.asarray(leaf), np.asarray(d_a[kind][key])
+                        layers = range(b.shape[0])
+                        if kind == "kv":     # (reps, R, n_pages, psz, G, D)
+                            want = {(i, 0) + r for i in layers for r in rows}
+                            live = {(i, 0) + r for i in layers
+                                    for r in live_rows}
+                            got = _changed_rows(b, a, 4)
+                        else:                # (reps, R, n_slabs, ...)
+                            want = {(i, 0, j) for i in layers for j in slabs}
+                            live = {(i, 0, sid) for i in layers}
+                            got = _changed_rows(b, a, 3)
+                        assert got <= want, (kind, key, sorted(got - want))
+                        assert live <= got, (kind, key, sorted(live - got))
 
 
 # ---------------------------------------------------------------------------

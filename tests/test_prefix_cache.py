@@ -140,7 +140,7 @@ def test_page_copy_step(mesh1):
                       jnp.asarray([5], jnp.int32))
     for old, new in zip(jax.tree_util.tree_leaves(cache),
                         jax.tree_util.tree_leaves(out), strict=True):
-        # pools carry a leading replica dim: (reps, R, n_pages, G, psz, D)
+        # pools carry a leading replica dim: (reps, R, n_pages, psz, G, D)
         old, new = np.asarray(old)[:, 0], np.asarray(new)[:, 0]
         np.testing.assert_array_equal(new[:, 5], old[:, 2])     # copied
         keep = [i for i in range(N_PAGES) if i != 5]

@@ -51,10 +51,10 @@ def test_template_int8_gains_scale_leaves():
     i8 = _template_keys(cfg, PLAN_I8)
     assert ("kv", "ksp") not in fp and ("kv", "vsp") not in fp
     assert i8[("kv", "kp")][1] == jnp.int8
-    # one float32 scale per (replica, page, token slot)
+    # one float32 scale per (replica, page, token slot), pages flat
     for k in ("ksp", "vsp"):
         shape, dtype = i8[("kv", k)]
-        assert shape == (1, 8, 4) and dtype == jnp.float32
+        assert shape == (1, 8 * 4) and dtype == jnp.float32
 
 
 def test_template_int8_ssm_and_cross():
@@ -70,7 +70,7 @@ def test_template_int8_ssm_and_cross():
     enc = _cfg("seamless-m4t-large-v2")
     i8e = _template_keys(enc, ShardingPlan(tp=1, kv_cache_dtype="int8"))
     assert i8e[("cross", "ckp")][1] == jnp.int8
-    assert i8e[("cross", "cksp")] == ((1, 8, 4), jnp.float32)
+    assert i8e[("cross", "cksp")] == ((1, 8 * 4), jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -119,8 +119,11 @@ def test_gather_pages_dequant_matches_ref():
     kp, ks = _quantized_pool(rng, 9, 2, 4, 16)
     bt = np.stack([rng.permutation(np.arange(1, 9))[:4]
                    for _ in range(2)]).astype(np.int32)
-    got = gather_pages_dequant(jnp.asarray(kp), jnp.asarray(ks),
-                               jnp.asarray(bt), jnp.float32)
+    # the served pools store pages token-major, (n_pages, psz, H, D), and
+    # their scales flat, (n_pages * psz,)
+    got = gather_pages_dequant(jnp.asarray(kp.transpose(0, 2, 1, 3)),
+                               jnp.asarray(ks.reshape(-1)), jnp.asarray(bt),
+                               jnp.float32)
     want = _gather_ref(np.asarray(ref_dequant_pool(jnp.asarray(kp),
                                                    jnp.asarray(ks))), bt)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
