@@ -104,7 +104,7 @@ def compare(ref, cfgfile: dict, spec: dict, seed32: int, recs: list,
     if not recs:
         return {"served_gap": None, "control_gap": None, "tokens": 0,
                 "requests": 0}
-    w = weights.canonical(spec, seed32)
+    w = weights.canonical(spec, seed32, cfgfile["reference"])
     n_rows = -(-max_rows // ROW_BLOCK) * ROW_BLOCK
     fn = jax.jit(functools.partial(_gaps, ref, cfgfile, control))
     worst, worst_c, n_tok = 0.0, 0.0, 0

@@ -1,12 +1,14 @@
 """The system under test as the benchmark drives it: the paged ServingEngine.
 
 ``build`` makes the engine the way ``repro.launch.serve.build_engine``
-does for a one-replica paged deployment (plan, mesh, sampler,
-``ServingEngine.build_paged``), except that the weights are the
-benchmark's own: drawn by ``bench/weights.py`` from the seed and laid out
-for the program by ``program_params`` in the same jitted call, straight
-into the shardings the program's steps expect.  Everything else (pools,
-scheduler, compiled steps) is the program's.
+does for a paged deployment (plan, mesh, sampler,
+``ServingEngine.build_paged``) at the tp and dp that the configuration's
+``serving`` block states, except that the weights are the benchmark's
+own: drawn by ``bench/weights.py`` from the seed and laid out for the
+program by ``program_params`` in the same jitted call, straight into the
+shardings the program's steps expect.  How a family's weights and sizes
+map onto the program is its adapter's (``bench/families/<family>.py``).
+Everything else (pools, scheduler, compiled steps) is the program's.
 """
 from __future__ import annotations
 
@@ -16,59 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import families, weights
 
-# Per reference family: the program's parameter path for each canonical
-# weight.  Top-level names map to a path from the root, layer names to a
-# path inside one layer of ``stacks``.
-LAYOUT = {
-    "qwen3": {
-        "top": {"embed": ("embed", "table"),
-                "final_norm": ("final_norm", "scale")},
-        "layer": {"ln1": ("ln1", "scale"), "q": ("attn", "wq"),
-                  "k": ("attn", "wk"), "v": ("attn", "wv"),
-                  "o": ("attn", "wo"), "q_norm": ("attn", "q_norm"),
-                  "k_norm": ("attn", "k_norm"), "ln2": ("ln2", "scale"),
-                  "gate": ("ffn", "w_gate"), "up": ("ffn", "w_up"),
-                  "down": ("ffn", "w_down")},
-    },
-    "mamba2": {
-        "top": {"embed": ("embed", "table"),
-                "final_norm": ("final_norm", "scale")},
-        "layer": {"norm": ("ln1", "scale"), "in_z": ("ssm", "in_z"),
-                  "in_x": ("ssm", "in_x"), "in_B": ("ssm", "in_B"),
-                  "in_C": ("ssm", "in_C"), "in_dt": ("ssm", "in_dt"),
-                  "conv_x": ("ssm", "conv_x"), "conv_B": ("ssm", "conv_B"),
-                  "conv_C": ("ssm", "conv_C"), "dt_bias": ("ssm", "dt_bias"),
-                  "A_log": ("ssm", "A_log"), "D": ("ssm", "D"),
-                  "gnorm": ("ssm", "norm_scale"), "out": ("ssm", "out")},
-    },
-}
-
-# The program's RMSNorms multiply by (1 + scale); the canonical weights
-# hold the multiplier itself, as published.
-OFFSET_NORMS = {"final_norm", "ln1", "ln2", "q_norm", "k_norm", "norm",
-                "gnorm"}
-
-# The program's config attribute for each size the configuration file
-# states, per family (file key -> attribute); the harness refuses to run
-# a program whose model differs from the file.  ``vocab_size`` is the
-# embedding's rows: the file's vocabulary padded to its
-# ``pad_vocab_size_multiple``, where it has one.
-SIZES = {
-    "qwen3": {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-              "num_attention_heads": "n_heads",
-              "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim_",
-              "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-              "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"},
-    "mamba2": {"n_layer": "n_layers", "d_model": "d_model",
-               "d_state": "ssm_state", "expand": "ssm_expand",
-               "headdim": "ssm_head_dim", "d_conv": "ssm_conv",
-               "vocab_size": "vocab_size", "norm_epsilon": "norm_eps"},
-}
 # Attributes the program takes as options: the harness sets them to what
-# the file states before the check above, so that the program runs as the
-# configuration says.
+# the file states before it checks the family's ``SIZES``, so that the
+# program runs as the configuration says.
 OPTIONS = ("vocab_size", "norm_eps")
 
 
@@ -84,27 +38,68 @@ def stated(cfgfile: dict, key: str) -> float:
 
 def model_config(cfgfile: dict):
     """The program's ModelConfig for a configuration file: its options
-    set as the file states, and its sizes checked against the file."""
+    set as the file states, and its sizes checked against the file by the
+    family's ``SIZES`` (an attribute compared as a number, or a function of
+    the ModelConfig compared with the file's value as it stands)."""
     from repro.configs import get_config, reduced
     cfg = get_config(cfgfile["arch"])
     if cfgfile.get("smoke"):
         cfg = reduced(cfg)
-    sizes = SIZES[cfgfile["reference"]]
+    sizes = families.load(cfgfile["reference"]).SIZES
     cfg = dataclasses.replace(cfg, **{
         attr: type(getattr(cfg, attr))(stated(cfgfile, key))
-        for key, attr in sizes.items() if attr in OPTIONS})
+        for key, attr in sizes.items()
+        if isinstance(attr, str) and attr in OPTIONS})
     for key, attr in sizes.items():
-        if float(getattr(cfg, attr)) != float(stated(cfgfile, key)):
+        if callable(attr):
+            if attr(cfg) != cfgfile[key]:
+                raise SystemExit(f"bench: the program's {cfg.name} gives "
+                                 f"{key} = {attr(cfg)!r}, the configuration "
+                                 f"file states {cfgfile[key]!r}")
+        elif float(getattr(cfg, attr)) != float(stated(cfgfile, key)):
             raise SystemExit(f"bench: the program's {cfg.name}.{attr} = "
                              f"{getattr(cfg, attr)}, the configuration "
                              f"file states {key} = {cfgfile[key]}")
     return cfg
 
 
+def _paths(tree, prefix=()) -> dict:
+    """{path: leaf} of a nested dict; a path is the tuple of its keys."""
+    if not isinstance(tree, dict):
+        return {prefix: tree}
+    return {p: v for k, sub in tree.items()
+            for p, v in _paths(sub, prefix + (k,)).items()}
+
+
+def _nest(flat: dict) -> dict:
+    """The nested dict of ``{path: leaf}``."""
+    out = {}
+    for path, v in flat.items():
+        node = out
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = v
+    return out
+
+
+def layer_holders(cfg, tmpl) -> dict:
+    """{layer path: the global indices of the program's layers that hold
+    it, in layer order}.  Row r of a canonical layer tensor belongs to the
+    r-th of them; in a uniform stack row r is layer r."""
+    holders, offset = {}, 0
+    for group, gt in zip(cfg.layer_groups(), tmpl["stacks"], strict=True):
+        plen = len(group.pattern)
+        for i in range(group.n_layers):
+            for path in _paths(gt[i % plen]):
+                holders.setdefault(path, []).append(offset + i)
+        offset += group.n_layers
+    return holders
+
+
 def program_params(cfg, plan, mesh, fam: str, spec: dict, seed32: int):
     """-> the program's parameter tree holding the canonical weights of
     ``spec`` for ``seed32``, made in one jitted call into the shardings of
-    ``model.param_pspecs``."""
+    ``model.param_pspecs``, laid out by the family's adapter."""
     from jax.sharding import NamedSharding
 
     from repro.core import model
@@ -113,13 +108,24 @@ def program_params(cfg, plan, mesh, fam: str, spec: dict, seed32: int):
     tmpl = model.model_template(cfg)
     abstract = model.abstract_params(cfg, plan)
     pspecs = model.param_pspecs(cfg, plan)
-    layout = LAYOUT[fam]
-    by_path = {v: k for k, v in layout["layer"].items()}
-    top = {v: k for k, v in layout["top"].items()}
+    adapter = families.load(fam)
+    by_path = {v: k for k, v in adapter.LAYOUT["layer"].items()}
+    top = {v: k for k, v in adapter.LAYOUT["top"].items()}
+    holders = layer_holders(cfg, tmpl)
+    for path, layers in holders.items():
+        name = by_path.get(path)
+        if name is None or name not in spec:
+            raise SystemExit(f"bench: the program's layer weight "
+                             f"{'/'.join(path)} has no canonical weight "
+                             f"(bench/families/{fam}.py names {name!r})")
+        if spec[name][0][0] != len(layers):
+            raise SystemExit(f"bench: canonical weight {name} has "
+                             f"{spec[name][0][0]} rows; the program holds "
+                             f"{'/'.join(path)} in {len(layers)} layers")
     used = set()
 
     def convert(name, full, pspec, want):
-        if name in OFFSET_NORMS:
+        if name in adapter.OFFSET_NORMS:
             full = (full.astype(jnp.float32) - 1.0).astype(full.dtype)
         if full.dtype != want.dtype:
             raise SystemExit(f"bench: weight {name} is {full.dtype}, the "
@@ -131,39 +137,38 @@ def program_params(cfg, plan, mesh, fam: str, spec: dict, seed32: int):
             plan, lay).astype(want.dtype)
 
     def build(key):
-        canon = weights.generate(spec, key)
+        canon = weights.generate(spec, key, fam)
+        want_top = _paths({k: v for k, v in abstract.items()
+                           if k != "stacks"})
         out = {}
-        for tkey, node in tmpl.items():
-            if tkey == "stacks":
-                continue
-            out[tkey] = {}
-            for leaf, ps in node.items():
-                name = top[(tkey, leaf)]
-                used.add(name)
-                full = canon[name].reshape(ps.full)
-                out[tkey][leaf] = convert(name, full, ps,
-                                          abstract[tkey][leaf])
+        for path, ps in _paths({k: v for k, v in tmpl.items()
+                                if k != "stacks"}).items():
+            name = top[path]
+            used.add(name)
+            out[path] = convert(name, canon[name].reshape(ps.full), ps,
+                                want_top[path])
+        out = _nest(out)
         stacks, offset = [], 0
         for g, (group, gt) in enumerate(zip(cfg.layer_groups(),
                                             tmpl["stacks"], strict=True)):
             plen = len(group.pattern)
             per_pat = []
             for pi, lt in enumerate(gt):
-                idx = offset + pi + plen * np.arange(group.n_reps)
+                layers = offset + pi + plen * np.arange(group.n_reps)
+                want_lt = _paths(abstract["stacks"][g][pi])
                 node = {}
-                for sub, leaves in lt.items():
-                    node[sub] = {}
-                    for leaf, ps in leaves.items():
-                        name = by_path[(sub, leaf)]
-                        used.add(name)
-                        full = canon[name][idx].reshape(
-                            (group.n_reps,) + tuple(ps.full))
-                        want = abstract["stacks"][g][pi][sub][leaf]
-                        node[sub][leaf] = jax.vmap(
-                            lambda f, ps=ps, name=name, want=want: convert(
-                                name, f, ps, jax.ShapeDtypeStruct(
-                                    want.shape[1:], want.dtype)))(full)
-                per_pat.append(node)
+                for path, ps in _paths(lt).items():
+                    name = by_path[path]
+                    used.add(name)
+                    rows = np.searchsorted(holders[path], layers)
+                    full = canon[name][rows].reshape(
+                        (group.n_reps,) + tuple(ps.full))
+                    want = want_lt[path]
+                    node[path] = jax.vmap(
+                        lambda f, ps=ps, name=name, want=want: convert(
+                            name, f, ps, jax.ShapeDtypeStruct(
+                                want.shape[1:], want.dtype)))(full)
+                per_pat.append(_nest(node))
             stacks.append(per_pat)
             offset += group.n_layers
         out["stacks"] = stacks
@@ -193,9 +198,10 @@ def build(cfgfile: dict, cell: dict, mix: dict, spec: dict, seed32: int,
     from repro.serving import SamplerConfig, ServingEngine
     cfg = model_config(cfgfile)
     srv = cfgfile["serving"]
-    plan = ShardingPlan(tp=1, kv_cache_dtype=srv["kv_dtype"],
+    tp, dp = int(srv["tp"]), int(srv["dp"])
+    plan = ShardingPlan(tp=tp, kv_cache_dtype=srv["kv_dtype"],
                         ssm_cache_dtype=srv.get("ssm_state_dtype", ""))
-    mesh = host_mesh(tp=1, dp=1)
+    mesh = host_mesh(tp=tp, dp=dp)
     params = program_params(cfg, plan, mesh, cfgfile["reference"], spec,
                             seed32)
     samp = mix.get("sampling", {})
@@ -205,7 +211,7 @@ def build(cfgfile: dict, cell: dict, mix: dict, spec: dict, seed32: int,
         cfg, plan, mesh, cell["slots"], cell["seq_budget"], params,
         page_size=srv["page_size"], prefill_chunk=srv["prefill_chunk"],
         sampler=sampler, prefix_cache=bool(srv.get("prefix_cache")),
-        rng_seed=engine_seed32, dp=1, overlap=bool(srv.get("overlap", True)),
+        rng_seed=engine_seed32, dp=dp, overlap=bool(srv.get("overlap", True)),
         eos_id=NO_EOS)
 
 

@@ -7,8 +7,15 @@ Everything is found by name: the cell's entry in ``BENCHMARK.json`` names
 its configuration (``bench/configs/<config>.json``) and traffic mix
 (``bench/traffic/<mix>.json``); its deployment (slots, token budget,
 offered rate, the check's sample and limit) is ``bench/cells/<cell>.json``;
-each metric is read by ``bench/metrics/<metric>.py``, and the plain
-reference is ``bench/reference/<family>.py``.
+each metric is read by ``bench/metrics/<metric>.py``.  A model family,
+named by the configuration's ``reference`` key, is two files: the plain
+reference ``bench/reference/<family>.py`` (``weight_spec``, ``hidden``,
+``head``; it imports nothing of the program) and the adapter
+``bench/families/<family>.py`` (the program's layout of each canonical
+weight, the sizes checked against the file, the norms held less one, any
+init of its own, and the cost model; ``bench/families/__init__.py`` lists
+what it must define).  The configuration's ``serving`` tp x dp is the
+cell's chip count.
 
 A run: refuse a device that is not a TPU (or too few chips); make the
 weights from the seed; build the paged engine; warm up the cell's shapes
@@ -67,9 +74,15 @@ def resolve(bench_file: Path, name: str) -> dict:
     layer = [m for m in bench["per_layer"]
              if name in m.get("workloads", [name] if m["moves"] in names
                               else [])]
+    cfgfile = load_json(data / conf["file"])
+    srv = cfgfile["serving"]
+    if cell["chips"] != srv["tp"] * srv["dp"]:
+        raise SystemExit(f"bench: workload {name} asks for {cell['chips']} "
+                         f"chips; its configuration serves on tp={srv['tp']}"
+                         f" x dp={srv['dp']}")
     return {"cell": cell, "deploy": load_json(data / "bench" / "cells"
                                               / f"{name}.json"),
-            "cfgfile": load_json(data / conf["file"]),
+            "cfgfile": cfgfile,
             "mix": load_json(data / "bench" / "traffic"
                              / f"{cell['traffic']}.json"),
             "end_to_end": e2e, "per_layer": layer}

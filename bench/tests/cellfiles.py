@@ -1,7 +1,10 @@
 """A scratch benchmark made of data files only: a tiny qwen3 cell that the
-harness finds by name and runs on the CPU without any edit of its code."""
+harness finds by name and runs on the CPU without any edit of its code.
+``python bench/tests/cellfiles.py <dir>`` runs it once with the
+configuration at tp=2 on two forced host devices and prints its result
+line."""
+import copy
 import json
-import shutil
 from pathlib import Path
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -42,15 +45,27 @@ CELL = {"slots": 4, "seq_budget": 256, "rate_rps": 4.0, "trace_seconds": 1,
                   "limit": 0.05}}
 
 
-def write(root: Path) -> Path:
-    """Lay the scratch benchmark out under ``root``; -> its BENCHMARK.json."""
+def tiny_config() -> dict:
+    return json.loads((DATA / "tiny.json").read_text())
+
+
+def write(root: Path, config: dict | None = None,
+          chips: int | None = None) -> Path:
+    """Lay the scratch benchmark out under ``root``, its configuration
+    ``config`` (the tiny qwen3 file by default) and its cell on ``chips``
+    (the configuration's tp x dp by default); -> its BENCHMARK.json."""
     for d in ("configs", "traffic", "cells"):
         (root / "bench" / d).mkdir(parents=True, exist_ok=True)
-    shutil.copy(DATA / "tiny.json", root / "bench/configs/qwen3-tiny.json")
+    config = tiny_config() if config is None else config
+    if chips is None:
+        chips = config["serving"]["tp"] * config["serving"]["dp"]
+    (root / "bench/configs/qwen3-tiny.json").write_text(json.dumps(config))
     (root / "bench/traffic/tinychat.json").write_text(json.dumps(MIX))
     (root / "bench/cells/tiny-chat.json").write_text(json.dumps(CELL))
+    bench = copy.deepcopy(BENCHMARK)
+    bench["workloads"][0]["chips"] = chips
     path = root / "BENCHMARK.json"
-    path.write_text(json.dumps(BENCHMARK))
+    path.write_text(json.dumps(bench))
     return path
 
 
@@ -61,3 +76,31 @@ def argv(bench_file: Path, trace: int = 0, seed: int = 123456789012):
 
 def last_json(out: str) -> dict:
     return json.loads(out.strip().splitlines()[-1])
+
+
+def main(root: str) -> int:
+    """Run the scratch cell once at tp=2 on two host devices."""
+    import os
+    import sys
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=2")
+    top = Path(__file__).resolve().parents[2]
+    sys.path[:0] = [str(top), str(top / "src")]
+    from bench import program, run
+    build = program.build
+
+    def logged(*args, **kwargs):
+        engine = build(*args, **kwargs)
+        print(f"mesh {dict(engine.mesh.shape)} tp {engine.plan.tp}",
+              file=sys.stderr)
+        return engine
+
+    program.build = logged
+    config = tiny_config()
+    config["serving"]["tp"] = 2
+    return run.main(argv(write(Path(root), config)), require_chip=False)
+
+
+if __name__ == "__main__":
+    import sys
+    sys.exit(main(sys.argv[1]))

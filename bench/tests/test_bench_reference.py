@@ -17,6 +17,9 @@ for _p in (str(ROOT), str(ROOT / "src"), str(Path(__file__).parent)):
 import cellfiles  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
+# every tiny configuration here is cross-checked: a new family's file is
+# tested by being added
+CONFIGS = sorted(p.name for p in DATA.glob("*.json"))
 
 # bf16 weights and activations in the program against float32 in the
 # reference: over two layers the logits agree to a few bf16 steps of
@@ -53,7 +56,7 @@ def _serve(cfgfile, seed, n_req=3):
     return ref, spec, reqs, rows
 
 
-@pytest.mark.parametrize("name", ["tiny.json", "mamba2-tiny.json"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_reference_matches_paged_engine_logits(name):
     import jax
     import jax.numpy as jnp
@@ -61,7 +64,8 @@ def test_reference_matches_paged_engine_logits(name):
     from bench import traffic, weights
     cfgfile = json.loads((DATA / name).read_text())
     ref, spec, reqs, rows = _serve(cfgfile, seed=11)
-    w = weights.canonical(spec, traffic.seed32(11, "weights"))
+    w = weights.canonical(spec, traffic.seed32(11, "weights"),
+                          cfgfile["reference"])
     head = np.asarray(ref.head(w, cfgfile), np.float32)
     worst = 0.0
     for r in reqs:
@@ -79,7 +83,7 @@ def test_reference_matches_paged_engine_logits(name):
     assert worst <= LOGIT_RTOL, worst
 
 
-@pytest.mark.parametrize("name", ["tiny.json", "mamba2-tiny.json"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_served_gap_is_nought_and_control_reads_higher(name):
     """The check's two readings at a reduced size: the program's greedy
     tokens sit on the reference's best logit, and the float8 control's
