@@ -25,6 +25,7 @@ ATTN_NONE = "none"          # attention-free (pure SSM layer)
 MIX_ATTN = "attn"           # plain MHSA/GQA
 MIX_SSM = "ssm"             # mamba2 SSD block
 MIX_HYBRID = "hybrid"       # parallel attn + ssm heads (hymba)
+MIX_NONE = "none"           # no mixer (an FFN- or expert-only layer)
 
 # FFN kinds
 FFN_DENSE = "dense"         # (gated) MLP
@@ -35,7 +36,7 @@ FFN_NONE = "none"           # no FFN (mamba2 blocks)
 @dataclass(frozen=True)
 class LayerSpec:
     """One transformer layer's structure."""
-    mixer: str = MIX_ATTN                 # attn | ssm | hybrid
+    mixer: str = MIX_ATTN                 # attn | ssm | hybrid | none
     attn: str = ATTN_FULL                 # full | window | none
     ffn: str = FFN_DENSE                  # dense | moe | none
     cross_attn: bool = False              # decoder cross-attention (enc-dec)
@@ -91,8 +92,14 @@ class ModelConfig:
     causal: bool = True               # False for encoders
     attn_scale: Optional[float] = None
 
+    # --- layer pattern ------------------------------------------------------
+    # One character per layer, each layer one pre-norm residual sublayer:
+    # "M" an SSM mixer, "E" the expert FFN, "*" attention.  "" -> every
+    # layer's kind follows ``family``.
+    layer_pattern: str = ""
+
     # --- FFN / MoE ----------------------------------------------------------
-    act: str = "silu"                 # silu (gated) | gelu
+    act: str = "silu"                 # silu (gated) | gelu | relu2
     gated_ffn: bool = True
     n_experts: int = 0
     n_shared_experts: int = 0
@@ -100,6 +107,15 @@ class ModelConfig:
     moe_d_ff: int = 0                 # per-expert intermediate size
     first_k_dense: int = 0            # deepseek: first k layers use dense FFN
     dense_ff_override: int = 0        # width of those dense layers
+    shared_d_ff: int = 0              # shared-expert width (0 -> moe_d_ff
+                                      #   * n_shared_experts)
+    router: str = "softmax"           # softmax | sigmoid: scores by sigmoid,
+                                      #   top-k on score + a correction bias,
+                                      #   weights renormalised and scaled by
+                                      #   routed_scale
+    routed_scale: float = 1.0
+    expert_first: int = 0             # the experts this layer holds:
+    experts_held: int = 0             #   [first, first + held); 0 -> all
 
     # --- SSM (mamba2 / SSD) ---------------------------------------------------
     ssm_state: int = 0
@@ -107,6 +123,9 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_conv: int = 4
     ssm_chunk: int = 256              # SSD chunk length
+    ssm_heads: int = 0                # 0 -> ssm_expand * d_model // ssm_head_dim
+    ssm_groups: int = 1               # B/C groups (heads share a group's B/C)
+    ssm_conv_bias: bool = False
 
     # --- enc-dec / frontends --------------------------------------------------
     n_enc_layers: int = 0             # >0 -> encoder-decoder
@@ -130,6 +149,23 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def ssm_heads_(self) -> int:
+        return self.ssm_heads or \
+            self.ssm_expand * self.d_model // self.ssm_head_dim
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads_ * self.ssm_head_dim
+
+    @property
+    def experts_held_(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def shared_d_ff_(self) -> int:
+        return self.shared_d_ff or self.moe_d_ff * self.n_shared_experts
+
+    @property
     def is_encdec(self) -> bool:
         return self.n_enc_layers > 0
 
@@ -150,6 +186,8 @@ class ModelConfig:
         return specs
 
     def _spec_for_layer(self, i: int) -> LayerSpec:
+        if self.layer_pattern:
+            return PATTERN_SPECS[self.layer_pattern[i]]
         # attention kind
         if self.family == "ssm":
             return LayerSpec(mixer=MIX_SSM, attn=ATTN_NONE, ffn=FFN_NONE)
@@ -197,6 +235,14 @@ class ModelConfig:
         return self.sliding_window if spec.attn == ATTN_WINDOW else 0
 
 
+# The layer kinds of ``ModelConfig.layer_pattern``.
+PATTERN_SPECS = {
+    "M": LayerSpec(mixer=MIX_SSM, attn=ATTN_NONE, ffn=FFN_NONE),
+    "E": LayerSpec(mixer=MIX_NONE, attn=ATTN_NONE, ffn=FFN_MOE),
+    "*": LayerSpec(mixer=MIX_ATTN, attn=ATTN_FULL, ffn=FFN_NONE),
+}
+
+
 def factor_layer_groups(specs) -> list:
     """Greedy periodic factoring: find the shortest repeating pattern prefix,
     emit (reps, pattern) groups; remainder becomes its own group(s)."""
@@ -206,7 +252,7 @@ def factor_layer_groups(specs) -> list:
     while i < n:
         # find longest run of a minimal period starting at i
         best = (1, 1)  # (period, reps)
-        for period in (1, 2, 3, 4, 6, 8):
+        for period in (1, 2, 3, 4, 6, 7, 8):
             if i + period > n:
                 break
             reps = 1
@@ -284,6 +330,7 @@ ASSIGNED = [
     "mamba2-370m", "gemma3-12b", "gemma3-27b", "qwen3-0.6b",
     "mistral-large-123b", "deepseek-moe-16b", "mixtral-8x22b",
     "seamless-m4t-large-v2", "hymba-1.5b", "pixtral-12b",
+    "nemotron3-nano-30b-a3b",
 ]
 
 PAPER_MODELS = ["tinyllama-42m", "tinyllama-42m-64h", "mobilebert"]
@@ -295,7 +342,7 @@ def _ensure_loaded():
         return
     from repro.configs import (  # noqa: F401
         mamba2_370m, gemma3, qwen3, mistral_large, deepseek_moe, mixtral,
-        seamless_m4t, hymba, pixtral, paper_models,
+        seamless_m4t, hymba, pixtral, paper_models, nemotron_h,
     )
 
 
@@ -317,18 +364,31 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     elif cfg.sliding_window:
         scale["sliding_window"] = 64
     if cfg.n_experts:
-        scale.update(n_experts=min(cfg.n_experts, 8),
+        n_exp = min(cfg.n_experts, 8)
+        scale.update(n_experts=n_exp,
                      top_k=min(cfg.top_k, 2),
                      n_shared_experts=min(cfg.n_shared_experts, 1),
                      moe_d_ff=64, first_k_dense=min(cfg.first_k_dense, 1),
-                     dense_ff_override=256 if cfg.first_k_dense else 0)
+                     dense_ff_override=256 if cfg.first_k_dense else 0,
+                     shared_d_ff=128 if cfg.shared_d_ff else 0)
+        if cfg.experts_held:        # the same share of fewer experts
+            scale.update(
+                expert_first=cfg.expert_first * n_exp // cfg.n_experts,
+                experts_held=max(2, cfg.experts_held * n_exp
+                                 // cfg.n_experts))
     if cfg.ssm_state:
         scale.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
+        if cfg.ssm_heads:
+            scale.update(ssm_heads=8, ssm_groups=min(cfg.ssm_groups, 2))
     if cfg.is_encdec:
         scale.update(n_enc_layers=2, enc_seq_len=64)
     if cfg.n_frontend_embeds:
         scale.update(n_frontend_embeds=16)
     if cfg.family == "hybrid":
         scale.update(n_layers=4)
+    if cfg.layer_pattern:           # one whole period of the pattern
+        period = len(cfg.layer_groups()[0].pattern)
+        scale.update(n_layers=period,
+                     layer_pattern=cfg.layer_pattern[:period])
     scale.update(overrides)
     return dataclasses.replace(cfg, name=cfg.name + "-smoke", **scale)

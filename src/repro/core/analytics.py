@@ -26,7 +26,6 @@ F32 = 4
 
 # scan-based flash attention computes all kv chunks for full-causal layers
 CAUSAL_SCAN_WASTE = 2.0
-MOE_CAPACITY = 1.25
 
 
 @dataclass
@@ -146,16 +145,18 @@ def layer_cost(cfg: ModelConfig, plan: ShardingPlan, spec, B: int, S: int,
         _mm(c, "ffn", T, E, f_loc, w_dtype=wdt, count=nmat - 1)
         _mm(c, "ffn", T, f_loc, E, w_dtype=wdt)
     elif spec.ffn == FFN_MOE:
-        cap = max(1, int(MOE_CAPACITY * T * cfg.top_k / cfg.n_experts))
+        # dropless: every computed expert runs every token's row
+        # (core/moe.py), the rows of tokens that did not pick it weighted 0
         if plan.moe_mode == "ep":
-            n_loc = cfg.n_experts // plan.tp
+            n_loc = cfg.experts_held_ // plan.tp
             ftot = cfg.moe_d_ff
-            _mm(c, "moe", n_loc * cap, E, ftot, w_dtype=wdt, count=nmat - 1)
-            _mm(c, "moe", n_loc * cap, ftot, E, w_dtype=wdt)
+            _mm(c, "moe", n_loc * T, E, ftot, w_dtype=wdt, count=nmat - 1)
+            _mm(c, "moe", n_loc * T, ftot, E, w_dtype=wdt)
         else:
             ef = lay.moe_ffn.loc
-            _mm(c, "moe", cfg.n_experts * cap, E, ef, w_dtype=wdt, count=nmat - 1)
-            _mm(c, "moe", cfg.n_experts * cap, ef, E, w_dtype=wdt)
+            n_held = cfg.experts_held_
+            _mm(c, "moe", n_held * T, E, ef, w_dtype=wdt, count=nmat - 1)
+            _mm(c, "moe", n_held * T, ef, E, w_dtype=wdt)
         c.add_flops("moe_router", 2.0 * T * E * cfg.n_experts)
         if cfg.n_shared_experts:
             sf = lay.shared_ffn.loc

@@ -15,14 +15,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import FFN_MOE, FFN_NONE, MIX_ATTN, MIX_SSM
+from repro.configs.base import FFN_MOE, FFN_NONE, MIX_ATTN, MIX_NONE, \
+    MIX_SSM
 from repro.core import collectives as cc
 from repro.core import ssm as ssd
 from repro.core.attention import decode_attention, flash_attention, \
     gather_kv, paged_decode_attention, paged_verify_attention
 from repro.core.layers import activation, apply_norm, apply_rope, rmsnorm, \
     rmsnorm_from_sumsq
-from repro.core.moe import moe_ffn_ep, moe_ffn_tp
+from repro.core.moe import moe_ffn
 
 
 W8_SCALE = 64.0        # per-tensor int8 weight scale (deployment experiments;
@@ -414,39 +415,50 @@ def ssm_mixer(xn, ps, cfg, plan, lay, mode, ssm_cache, chunk_last_idx=None):
     chunked = chunk_last_idx is not None
     cp = bool(plan.cp_axes) and mode != "decode" and not chunked and \
         cc.axis_size(plan.cp_axes) > 1
+    G = cfg.ssm_groups
+    bx = _lo(ps["conv_x_bias"]) if cfg.ssm_conv_bias else None
+    bB, bC = ps.get("conv_B_bias"), ps.get("conv_C_bias")
     z = jnp.einsum("bse,ehp->bshp", xn, _lo(ps["in_z"]))         # (B,S,H,P)
     xi = jnp.einsum("bse,ehp->bshp", xn, _lo(ps["in_x"]))
     dt_raw = jnp.einsum("bse,eh->bsh", xn, _lo(ps["in_dt"]))
-    Bm = jnp.einsum("bse,en->bsn", xn, ps["in_B"])               # replicated
+    Bm = jnp.einsum("bse,en->bsn", xn, ps["in_B"])   # replicated, (B,S,G*N)
     Cm = jnp.einsum("bse,en->bsn", xn, ps["in_C"])
 
     xi_f = xi.reshape(B, S, H * Pd)
     K = cfg.ssm_conv
     if mode == "decode":
         xi_f, cs_x = ssd.causal_conv(xi_f, _lo(ps["conv_x"]).reshape(H * Pd, -1),
-                                     ssm_cache["conv_x"])
-        Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"], ssm_cache["conv_B"])
-        Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"], ssm_cache["conv_C"])
+                                     ssm_cache["conv_x"], bias=bx)
+        Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"], ssm_cache["conv_B"],
+                                   bias=bB)
+        Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"], ssm_cache["conv_C"],
+                                   bias=bC)
     elif chunked:
         xi_f, cs_x = ssd.causal_conv(xi_f, _lo(ps["conv_x"]).reshape(H * Pd, -1),
                                      ssm_cache["conv_x"],
-                                     tail_idx=chunk_last_idx)
+                                     tail_idx=chunk_last_idx, bias=bx)
         Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"], ssm_cache["conv_B"],
-                                   tail_idx=chunk_last_idx)
+                                   tail_idx=chunk_last_idx, bias=bB)
         Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"], ssm_cache["conv_C"],
-                                   tail_idx=chunk_last_idx)
+                                   tail_idx=chunk_last_idx, bias=bC)
     elif cp:
         # conv halo: previous shard's last K-1 pre-conv rows
         xi_f, cs_x = ssd.causal_conv(xi_f, _lo(ps["conv_x"]).reshape(H * Pd, -1),
-                                     _cp_halo(xi_f, plan, K))
-        Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"], _cp_halo(Bm, plan, K))
-        Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"], _cp_halo(Cm, plan, K))
+                                     _cp_halo(xi_f, plan, K), bias=bx)
+        Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"], _cp_halo(Bm, plan, K),
+                                   bias=bB)
+        Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"], _cp_halo(Cm, plan, K),
+                                   bias=bC)
     else:
-        xi_f, cs_x = ssd.causal_conv(xi_f, _lo(ps["conv_x"]).reshape(H * Pd, -1))
-        Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"])
-        Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"])
+        xi_f, cs_x = ssd.causal_conv(xi_f, _lo(ps["conv_x"]).reshape(H * Pd, -1),
+                                     bias=bx)
+        Bm, cs_B = ssd.causal_conv(Bm, ps["conv_B"], bias=bB)
+        Cm, cs_C = ssd.causal_conv(Cm, ps["conv_C"], bias=bC)
     xi = jax.nn.silu(xi_f).reshape(B, S, H, Pd)
     Bm, Cm = jax.nn.silu(Bm), jax.nn.silu(Cm)
+    if G > 1:                        # each local head's group's B and C
+        Bm, Cm = _head_groups(Bm, cfg, plan, lay), \
+            _head_groups(Cm, cfg, plan, lay)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) +
                          _lo(ps["dt_bias"]).astype(jnp.float32))
     A = -jnp.exp(_lo(ps["A_log"]).astype(jnp.float32))
@@ -472,7 +484,8 @@ def ssm_mixer(xn, ps, cfg, plan, lay, mode, ssm_cache, chunk_last_idx=None):
             xi, dt, Bm, Cm, A, D, cfg.ssm_chunk, return_extras=True)
         S_in, S_glob = _cp_state_prefix(C_loc, D_loc, plan)
         # fold the incoming state in (linear correction; exact)
-        y_corr = jnp.einsum("bsn,bhpn->bshp", Cm.astype(jnp.float32),
+        y_corr = jnp.einsum("bshn,bhpn->bshp" if G > 1 else
+                            "bsn,bhpn->bshp", Cm.astype(jnp.float32),
                             S_in) * cum_decay[..., None]
         y = y0 + y_corr.astype(y0.dtype)
         new_cache = None
@@ -493,16 +506,42 @@ def ssm_mixer(xn, ps, cfg, plan, lay, mode, ssm_cache, chunk_last_idx=None):
             new_cache = {"state": state, "conv_x": cs_x, "conv_B": cs_B,
                          "conv_C": cs_C}
 
-    # gated RMSNorm over the FULL d_inner (cross-shard sum of squares: one
-    # tiny psum — O(B*S) bytes, counted by the ledger)
     g = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(B, S, H * Pd)
-    sumsq = jnp.sum(jnp.square(g).astype(jnp.float32), axis=-1, keepdims=True)
-    sumsq = cc.psum(sumsq, plan.tp_axes, "block/ssm_norm")
-    g = rmsnorm_from_sumsq(g, sumsq, cfg.ssm_expand * cfg.d_model,
-                           _lo(ps["norm_scale"]), cfg.norm_eps)
+    if G > 1:
+        # gated RMSNorm over each group's channels; a shard holds whole
+        # groups (``_head_groups``), so no sum crosses shards
+        gg = g.reshape(B, S, -1, cfg.ssm_inner // G)
+        gg = rmsnorm_from_sumsq(
+            gg, jnp.sum(jnp.square(gg), axis=-1, keepdims=True),
+            cfg.ssm_inner // G, _lo(ps["norm_scale"]).reshape(gg.shape[2:]),
+            cfg.norm_eps)
+        g = gg.reshape(B, S, H * Pd)
+    else:
+        # gated RMSNorm over the FULL d_inner (cross-shard sum of squares:
+        # one tiny psum — O(B*S) bytes, counted by the ledger)
+        sumsq = jnp.sum(jnp.square(g).astype(jnp.float32), axis=-1,
+                        keepdims=True)
+        sumsq = cc.psum(sumsq, plan.tp_axes, "block/ssm_norm")
+        g = rmsnorm_from_sumsq(g, sumsq, cfg.ssm_inner,
+                               _lo(ps["norm_scale"]), cfg.norm_eps)
     out = jnp.einsum("bsx,xe->bse", g.astype(xn.dtype),
                      _lo(ps["out"]).reshape(H * Pd, E))
     return out, new_cache
+
+
+def _head_groups(u, cfg, plan, lay):
+    """(B, S, G*N) B or C of every group -> (B, S, H_loc, N), each local
+    head's group's, heads H/G to a group.  A shard holds whole groups."""
+    G, hl = cfg.ssm_groups, lay.ssm
+    per_group = cfg.ssm_heads_ // G
+    if hl.hq_pad != hl.n_q or hl.hq_loc % per_group:
+        raise ValueError(f"{cfg.name}: tp={plan.tp} splits an SSM group of "
+                         f"{per_group} heads across shards")
+    g_loc = hl.hq_loc // per_group
+    u = u.reshape(u.shape[:2] + (G, -1))
+    u = jax.lax.dynamic_slice_in_dim(u, tp_index(plan) * g_loc, g_loc,
+                                     axis=2)
+    return jnp.repeat(u, per_group, axis=2)
 
 
 def _paged_ssm(xn, ps, cfg, plan, lay, mode, slab_pool, pages):
@@ -562,20 +601,23 @@ def dense_ffn(xn, pf, cfg):
 
 
 def ffn_sublayer(xn, pf, cfg, plan, spec):
-    """-> partial output (B,S,E), reduced by the caller's post-FFN psum."""
-    if spec.ffn == FFN_MOE:
+    """-> (partial output (B,S,E), reduced by the caller's post-FFN psum;
+    picks (B,S) on held experts for an expert layer, else None)."""
+    if spec.ffn != FFN_MOE:
+        with jax.named_scope("mlp"):
+            return dense_ffn(xn, pf, cfg), None
+    with jax.named_scope("moe"):
         pf_moe = {"router": pf["router"],
                   "experts": jax.tree_util.tree_map(_lo, pf["experts"])}
-        if plan.moe_mode == "ep":
-            y = moe_ffn_ep(xn, pf_moe, cfg, tp_index(plan), plan.tp,
-                           capacity_factor=plan.moe_capacity)
-        else:
-            y = moe_ffn_tp(xn, pf_moe, cfg,
-                           capacity_factor=plan.moe_capacity)
+        if plan.moe_mode == "ep":     # whole experts, a range per shard
+            n = cfg.experts_held_ // plan.tp
+            first = cfg.expert_first + tp_index(plan) * n
+        else:                         # every held expert, F-sliced over tp
+            first, n = cfg.expert_first, cfg.experts_held_
+        y, picks = moe_ffn(xn, pf_moe, cfg, first, n)
         if cfg.n_shared_experts:
             y = y + dense_ffn(xn, pf["shared"], cfg)
-        return y
-    return dense_ffn(xn, pf, cfg)
+        return y, picks
 
 
 # ---------------------------------------------------------------------------
@@ -584,40 +626,49 @@ def ffn_sublayer(xn, pf, cfg, plan, spec):
 
 def layer_forward(x, p, cache, cfg, plan, lay, spec, mode, positions,
                   pos=None, enc_memory=None, pages=None):
-    """One transformer layer.  Returns (x, new_cache)."""
+    """One transformer layer.  Returns (x, new_cache, picks): ``picks``
+    (B,S) counts each token's picks on the held experts of an expert
+    layer, else None.  Each sublayer runs under a named scope
+    ("attn", "mamba", "moe", "mlp") that profiles show."""
     cache = cache or {}
     new_cache = dict(cache)
 
+    def run_attn(h):
+        with jax.named_scope("attn"):
+            return attn_mixer(h, p["attn"], cfg, plan, lay, spec, mode,
+                              cache.get("kv"), positions, pos, pages)
+
     def run_ssm(h):
         sc = cache.get("ssm")
-        if sc is not None and "statep" in sc:      # slab pools (paged)
-            return _paged_ssm(h, p["ssm"], cfg, plan, lay, mode, sc, pages)
-        return ssm_mixer(h, p["ssm"], cfg, plan, lay, mode, sc)
+        with jax.named_scope("mamba"):
+            if sc is not None and "statep" in sc:      # slab pools (paged)
+                return _paged_ssm(h, p["ssm"], cfg, plan, lay, mode, sc,
+                                  pages)
+            return ssm_mixer(h, p["ssm"], cfg, plan, lay, mode, sc)
 
     # ---- mixer sublayer ----------------------------------------------------
-    h = apply_norm(x, p["ln1"], cfg)
-    if spec.mixer == MIX_ATTN:
-        partial, nkv = attn_mixer(h, p["attn"], cfg, plan, lay, spec, mode,
-                                  cache.get("kv"), positions, pos, pages)
-        if nkv is not None:
-            new_cache["kv"] = nkv
-    elif spec.mixer == MIX_SSM:
-        partial, nssm = run_ssm(h)
-        if nssm is not None:
-            new_cache["ssm"] = nssm
-    else:  # hybrid: parallel attn + ssm heads, fused before ONE psum
-        pa, nkv = attn_mixer(h, p["attn"], cfg, plan, lay, spec, mode,
-                             cache.get("kv"), positions, pos, pages)
-        ps_, nssm = run_ssm(h)
-        partial = 0.5 * (pa + ps_)
-        if nkv is not None:
-            new_cache["kv"] = nkv
-        if nssm is not None:
-            new_cache["ssm"] = nssm
-    red = cc.psum(partial, plan.tp_axes, "block/mixer")  # sync #1
-    if cfg.sandwich_norm:
-        red = apply_norm(red, p["post_ln1"], cfg)
-    x = x + red
+    if spec.mixer != MIX_NONE:
+        h = apply_norm(x, p["ln1"], cfg)
+        if spec.mixer == MIX_ATTN:
+            partial, nkv = run_attn(h)
+            if nkv is not None:
+                new_cache["kv"] = nkv
+        elif spec.mixer == MIX_SSM:
+            partial, nssm = run_ssm(h)
+            if nssm is not None:
+                new_cache["ssm"] = nssm
+        else:  # hybrid: parallel attn + ssm heads, fused before ONE psum
+            pa, nkv = run_attn(h)
+            ps_, nssm = run_ssm(h)
+            partial = 0.5 * (pa + ps_)
+            if nkv is not None:
+                new_cache["kv"] = nkv
+            if nssm is not None:
+                new_cache["ssm"] = nssm
+        red = cc.psum(partial, plan.tp_axes, "block/mixer")  # sync #1
+        if cfg.sandwich_norm:
+            red = apply_norm(red, p["post_ln1"], cfg)
+        x = x + red
 
     # ---- cross-attention sublayer (enc-dec decoders) ------------------------
     if spec.cross_attn:
@@ -630,12 +681,13 @@ def layer_forward(x, p, cache, cfg, plan, lay, spec, mode, positions,
         x = x + cc.psum(partial, plan.tp_axes, "block/xattn")
 
     # ---- FFN sublayer --------------------------------------------------------
+    picks = None
     if spec.ffn != FFN_NONE:
         h = apply_norm(x, p["ln2"], cfg)
-        partial = ffn_sublayer(h, p["ffn"], cfg, plan, spec)
+        partial, picks = ffn_sublayer(h, p["ffn"], cfg, plan, spec)
         red = cc.psum(partial, plan.tp_axes, "block/ffn")  # sync #2
         if cfg.sandwich_norm:
             red = apply_norm(red, p["post_ln2"], cfg)
         x = x + red
 
-    return x, (new_cache if new_cache else None)
+    return x, (new_cache if new_cache else None), picks

@@ -94,10 +94,10 @@ def layer_cache_template(cfg, plan, lay, spec, batch: int, budget: int,
                       P(batch_axes, tpax, None, None)),
             "conv_x": ((batch, K - 1, cx), jnp.dtype(cfg.dtype),
                        P(batch_axes, None, tpax)),
-            "conv_B": ((batch, K - 1, N), jnp.dtype(cfg.dtype),
-                       P(batch_axes, None, None)),
-            "conv_C": ((batch, K - 1, N), jnp.dtype(cfg.dtype),
-                       P(batch_axes, None, None)),
+            "conv_B": ((batch, K - 1, cfg.ssm_groups * N),
+                       jnp.dtype(cfg.dtype), P(batch_axes, None, None)),
+            "conv_C": ((batch, K - 1, cfg.ssm_groups * N),
+                       jnp.dtype(cfg.dtype), P(batch_axes, None, None)),
         }
     if "cross_kv" in spec.cache_kinds():
         S_enc = cfg.enc_seq_len
@@ -300,9 +300,9 @@ def paged_cache_template(cfg, plan, lay, n_pages: int, page_size: int,
                        sd, P(dpax, None, tpax, None, None)),
             "conv_xp": ((n_replicas, n_slabs, plan.tp * H * Pdim * (K - 1)),
                         jnp.dtype(cfg.dtype), P(dpax, None, tpax)),
-            "conv_Bp": ((n_replicas, n_slabs, N * (K - 1)),
+            "conv_Bp": ((n_replicas, n_slabs, cfg.ssm_groups * N * (K - 1)),
                         jnp.dtype(cfg.dtype), P(dpax, None, None)),
-            "conv_Cp": ((n_replicas, n_slabs, N * (K - 1)),
+            "conv_Cp": ((n_replicas, n_slabs, cfg.ssm_groups * N * (K - 1)),
                         jnp.dtype(cfg.dtype), P(dpax, None, None)),
         }
         if sd == jnp.int8:

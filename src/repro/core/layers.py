@@ -45,6 +45,8 @@ def apply_norm(x, p, cfg):
 def activation(x, kind: str):
     if kind == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if kind == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 

@@ -28,7 +28,8 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import (FFN_DENSE, FFN_MOE, FFN_NONE,
-                                MIX_ATTN, MIX_HYBRID, MIX_SSM, ModelConfig)
+                                MIX_ATTN, MIX_HYBRID, MIX_NONE, MIX_SSM,
+                                ModelConfig)
 from repro.core import collectives as cc
 from repro.core.blocks import _lo, layer_forward, tp_index
 from repro.core.layers import apply_norm, sharded_embed, sharded_logits, \
@@ -48,6 +49,7 @@ class ParamSpec:
     init: str = "normal"      # normal | zeros | ones | a_log | dt_bias
     scale: float = 0.02
     ffn_dim: int = 0          # per-layer F (dense layers with overrides)
+    dtype: str = ""           # held in this dtype whatever the weights'
 
     @property
     def is_leaf(self):
@@ -83,26 +85,30 @@ def _attn_t(cfg, n_layers_total):
 
 def _ssm_t(cfg, n_layers_total):
     E = cfg.d_model
-    d_inner = cfg.ssm_expand * E
-    Pd, N = cfg.ssm_head_dim, cfg.ssm_state
-    H = d_inner // Pd
+    Pd, N, H = cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_heads_
+    GN = cfg.ssm_groups * N               # B and C of every group
     K = cfg.ssm_conv
     out_scale = 0.02 / math.sqrt(2 * n_layers_total)
-    return {
+    t = {
         "in_z": ParamSpec("ssm_col_heads", (E, H, Pd)),
         "in_x": ParamSpec("ssm_col_heads", (E, H, Pd)),
         "in_dt": ParamSpec("ssm_col_head_vec", (E, H)),
-        "in_B": ParamSpec("replicated", (E, N)),
-        "in_C": ParamSpec("replicated", (E, N)),
+        "in_B": ParamSpec("replicated", (E, GN)),
+        "in_C": ParamSpec("replicated", (E, GN)),
         "conv_x": ParamSpec("ssm_conv_heads", (H, Pd, K), scale=0.2),
-        "conv_B": ParamSpec("replicated", (N, K), scale=0.2),
-        "conv_C": ParamSpec("replicated", (N, K), scale=0.2),
+        "conv_B": ParamSpec("replicated", (GN, K), scale=0.2),
+        "conv_C": ParamSpec("replicated", (GN, K), scale=0.2),
         "A_log": ParamSpec("ssm_head_vec", (H,), "a_log"),
         "D": ParamSpec("ssm_head_vec", (H,), "ones"),
         "dt_bias": ParamSpec("ssm_head_vec", (H,), "dt_bias"),
         "norm_scale": ParamSpec("ssm_flat_heads", (H, Pd), "zeros"),
         "out": ParamSpec("ssm_row_heads", (H, Pd, E), scale=out_scale),
     }
+    if cfg.ssm_conv_bias:
+        t["conv_x_bias"] = ParamSpec("ssm_flat_heads", (H, Pd), "zeros")
+        t["conv_B_bias"] = ParamSpec("replicated", (GN,), "zeros")
+        t["conv_C_bias"] = ParamSpec("replicated", (GN,), "zeros")
+    return t
 
 
 def _dense_ffn_t(cfg, d_ff, n_layers_total):
@@ -117,24 +123,28 @@ def _dense_ffn_t(cfg, d_ff, n_layers_total):
 
 
 def _moe_ffn_t(cfg, n_layers_total):
+    """The router spans every expert; the layer holds ``experts_held_``."""
     E = cfg.d_model
-    F = cfg.moe_d_ff
+    F, n = cfg.moe_d_ff, cfg.experts_held_
     out_scale = 0.02 / math.sqrt(2 * n_layers_total)
-    ex = {"w_up": ParamSpec("moe_col", (cfg.n_experts, E, F)),
-          "w_down": ParamSpec("moe_row", (cfg.n_experts, F, E),
-                              scale=out_scale)}
+    ex = {"w_up": ParamSpec("moe_col", (n, E, F)),
+          "w_down": ParamSpec("moe_row", (n, F, E), scale=out_scale)}
     if cfg.gated_ffn:
-        ex["w_gate"] = ParamSpec("moe_col", (cfg.n_experts, E, F))
-    t = {"router": {"w": ParamSpec("replicated", (E, cfg.n_experts))},
-         "experts": ex}
+        ex["w_gate"] = ParamSpec("moe_col", (n, E, F))
+    router = {"w": ParamSpec("replicated", (E, cfg.n_experts))}
+    if cfg.router == "sigmoid":     # float32, as the sigmoid router is held
+        router = {"w": ParamSpec("replicated", (E, cfg.n_experts),
+                                 dtype="float32"),
+                  "bias": ParamSpec("replicated", (cfg.n_experts,), "zeros",
+                                    dtype="float32")}
+    t = {"router": router, "experts": ex}
     if cfg.n_shared_experts:
-        t["shared"] = _dense_ffn_t(cfg, F * cfg.n_shared_experts,
-                                   n_layers_total)
+        t["shared"] = _dense_ffn_t(cfg, cfg.shared_d_ff_, n_layers_total)
     return t
 
 
 def layer_template(cfg, spec, n_layers_total):
-    t = {"ln1": _norm_t(cfg)}
+    t = {"ln1": _norm_t(cfg)} if spec.mixer != MIX_NONE else {}
     if spec.mixer in (MIX_ATTN, MIX_HYBRID):
         t["attn"] = _attn_t(cfg, n_layers_total)
     if spec.mixer in (MIX_SSM, MIX_HYBRID):
@@ -262,16 +272,14 @@ def shard_full(spec: ParamSpec, full, cfg, plan: ShardingPlan,
         return x.reshape(tp, dl.loc, x.shape[1])
     if k == "moe_col":        # (n_exp,E,F)
         if plan.moe_mode == "ep":
-            n_loc = cfg.n_experts // tp
-            return full.reshape(tp, n_loc, *full.shape[1:])
+            return full.reshape(tp, full.shape[0] // tp, *full.shape[1:])
         dl = dim_layout(full.shape[2], tp)
         x = pad_axis(full, 2, dl.n_pad)
         x = x.reshape(*x.shape[:2], tp, dl.loc)
         return jnp.moveaxis(x, 2, 0)
     if k == "moe_row":        # (n_exp,F,E)
         if plan.moe_mode == "ep":
-            n_loc = cfg.n_experts // tp
-            return full.reshape(tp, n_loc, *full.shape[1:])
+            return full.reshape(tp, full.shape[0] // tp, *full.shape[1:])
         dl = dim_layout(full.shape[1], tp)
         x = pad_axis(full, 1, dl.n_pad)
         x = x.reshape(x.shape[0], tp, dl.loc, x.shape[2])
@@ -353,7 +361,9 @@ def abstract_params(cfg, plan, dtype=None):
         shape = sharded_shape(spec, cfg, plan, lay)
         if reps:
             shape = (reps,) + shape
-        if spec.init in ("a_log", "dt_bias"):
+        if spec.dtype:
+            d = jnp.dtype(spec.dtype)
+        elif spec.init in ("a_log", "dt_bias"):
             d = jnp.float32
         elif spec.kind == "replicated":
             d = jnp.dtype(cfg.dtype)     # norms/routers stay high precision
@@ -401,13 +411,15 @@ def init_params(cfg, plan, seed=0, dtype=None, mesh=None):
             keys.append(jax.random.fold_in(jax.random.PRNGKey(seed),
                                            counter[0]))
         keep_f32 = spec.init in ("a_log", "dt_bias")
+        leaf_dt = jnp.dtype(spec.dtype) if spec.dtype else \
+            (jnp.float32 if keep_f32 else dt)
 
         def build(keys):
             leaves = []
             for key in keys:
                 sh = shard_full(spec, _init_full(spec, key), cfg, plan, lay)
                 sh = _mask_invalid_heads(spec, sh, cfg, plan, lay)
-                leaves.append(sh.astype(jnp.float32 if keep_f32 else dt))
+                leaves.append(sh.astype(leaf_dt))
             return jnp.stack(leaves) if reps else leaves[0]
 
         if mesh is None:
@@ -439,12 +451,8 @@ def _init_full(spec: ParamSpec, key):
 # ---------------------------------------------------------------------------
 
 def _run_stack(x, stack_params, groups, cfg, plan, lay, mode, positions,
-               pos=None, enc_memory=None, cache=None, causal_specs=None,
-               pages=None):
+               pos=None, enc_memory=None, cache=None, causal_specs=None):
     """Scan every layer group.  cache: list aligned with groups (or None)."""
-    if pages is not None:
-        return _run_paged_stack(x, stack_params, groups, cfg, plan, lay, mode,
-                                positions, pos, cache, pages)
     new_cache = [] if cache is not None else None
     for gi, (group, gparams) in enumerate(zip(groups, stack_params, strict=True)):
         gcache = cache[gi] if cache is not None else None
@@ -454,8 +462,9 @@ def _run_stack(x, stack_params, groups, cfg, plan, lay, mode, positions,
             nc_rep = []
             for pi, spec in enumerate(group.pattern):
                 ci = c_rep[pi] if c_rep is not None else None
-                xc, nc = layer_forward(xc, p_rep[pi], ci, cfg, plan, lay,
-                                       spec, mode, positions, pos, enc_memory)
+                xc, nc, _ = layer_forward(xc, p_rep[pi], ci, cfg, plan, lay,
+                                          spec, mode, positions, pos,
+                                          enc_memory)
                 nc_rep.append(nc if nc is not None else {})
             return xc, (nc_rep if c_rep is not None else None)
 
@@ -482,6 +491,13 @@ def _run_stack(x, stack_params, groups, cfg, plan, lay, mode, positions,
     return x, new_cache
 
 
+def counts_expert_picks(cfg) -> bool:
+    """True when the paged steps also return each row's count of (expert
+    layer, pick) pairs that fell on the experts this chip holds: models
+    that have expert layers."""
+    return any(s.ffn == FFN_MOE for s in cfg.layer_specs())
+
+
 def _run_paged_stack(x, stack_params, groups, cfg, plan, lay, mode,
                      positions, pos, cache, pages):
     """The paged steps' layer loop (decode, verify, prefill chunk).
@@ -491,33 +507,47 @@ def _run_paged_stack(x, stack_params, groups, cfg, plan, lay, mode,
     writes only its own rows of them by index (``pages["layer"]``), so the
     donated pools are updated in place and no layer's pool is sliced out
     or re-stacked.  Scanning them as ``xs``/``ys`` instead makes the
-    compiled step allocate and copy whole pools."""
+    compiled step allocate and copy whole pools.
+
+    -> (x, cache, picks): ``picks`` (B, S) counts each position's (expert
+    layer, pick) pairs on held experts where ``counts_expert_picks``,
+    else None."""
     new_cache = []
+    picks = jnp.zeros(positions.shape, jnp.int32) \
+        if counts_expert_picks(cfg) else None
     for group, gparams, gcache in zip(groups, stack_params, cache,
                                       strict=True):
 
         def body(carry, per_rep, group=group):   # bind the loop var (B023)
-            xc, pools = carry
+            xc, pools, pk = carry
             p_rep, li = per_rep
             layer_pages = dict(pages, layer=li)
             new_pools = []
             for pi, spec in enumerate(group.pattern):
-                xc, nc = layer_forward(xc, p_rep[pi], pools[pi], cfg, plan,
-                                       lay, spec, mode, positions, pos,
-                                       None, layer_pages)
+                xc, nc, lpk = layer_forward(xc, p_rep[pi], pools[pi], cfg,
+                                            plan, lay, spec, mode, positions,
+                                            pos, None, layer_pages)
                 new_pools.append(nc if nc is not None else {})
-            return (xc, new_pools), None
+                if lpk is not None:
+                    pk = pk + lpk
+            return (xc, new_pools, pk), None
 
         with cc.LEDGER.scaled(group.n_reps):
             if group.n_reps == 1:
                 p_rep = jax.tree_util.tree_map(lambda a: a[0], gparams)
-                (x, gcache), _ = body((x, gcache), (p_rep, 0))
+                (x, gcache, picks), _ = body((x, gcache, picks), (p_rep, 0))
             else:
-                (x, gcache), _ = jax.lax.scan(
-                    body, (x, gcache),
+                (x, gcache, picks), _ = jax.lax.scan(
+                    body, (x, gcache, picks),
                     (gparams, jnp.arange(group.n_reps, dtype=jnp.int32)))
         new_cache.append(gcache)
-    return x, new_cache
+    return x, new_cache, picks
+
+
+def _with_picks(out, picks):
+    """A paged step's first output: ``out`` alone, or ``(out, picks)``
+    for a model whose steps count expert picks (``counts_expert_picks``)."""
+    return out if picks is None else (out, picks)
 
 
 def embed_tokens(params, tokens, cfg, plan, lay):
@@ -650,16 +680,21 @@ def forward_decode(params, cache, tokens, pos, cfg, plan, lay, pages=None):
     positions = pos[:, None]
     x = embed_tokens(params, tokens, cfg, plan, lay)
     groups = cfg.layer_groups()
-    x, cache = _run_stack(x, params["stacks"], groups, cfg, plan, lay,
-                          "decode", positions, pos=pos, cache=cache,
-                          pages=pages)
+    picks = None
+    if pages is None:
+        x, cache = _run_stack(x, params["stacks"], groups, cfg, plan, lay,
+                              "decode", positions, pos=pos, cache=cache)
+    else:
+        x, cache, picks = _run_paged_stack(x, params["stacks"], groups, cfg,
+                                           plan, lay, "decode", positions,
+                                           pos, cache, pages)
     x = apply_norm(x, params["final_norm"], cfg)
     logits = final_logits(params, x, cfg, lay)[:, 0]
-    return logits, cache
+    return _with_picks(logits, None if picks is None else picks[:, 0]), \
+        cache
 
 
-def forward_verify(params, cache, tokens, pos, qlen, cfg, plan, lay,
-                   pages=None):
+def forward_verify(params, cache, tokens, pos, qlen, cfg, plan, lay, pages):
     """Speculative verify: score Q consecutive positions per slot at once.
 
     tokens: (B, Q) — column 0 is the slot's last accepted token, columns
@@ -677,9 +712,9 @@ def forward_verify(params, cache, tokens, pos, qlen, cfg, plan, lay,
                           positions, -1)
     x = embed_tokens(params, tokens, cfg, plan, lay)
     groups = cfg.layer_groups()
-    x, cache = _run_stack(x, params["stacks"], groups, cfg, plan, lay,
-                          "verify", positions, pos=pos, cache=cache,
-                          pages=pages)
+    x, cache, _ = _run_paged_stack(x, params["stacks"], groups, cfg, plan,
+                                   lay, "verify", positions, pos, cache,
+                                   pages)
     x = apply_norm(x, params["final_norm"], cfg)
     logits = final_logits(params, x, cfg, lay)
     return logits, cache
@@ -701,9 +736,13 @@ def forward_prefill_chunk(params, cache, tokens, chunk_start, last_idx, cfg,
     positions = chunk_start + jnp.broadcast_to(jnp.arange(C), (B, C))
     x = embed_tokens(params, tokens, cfg, plan, lay)
     groups = cfg.layer_groups()
-    x, cache = _run_stack(x, params["stacks"], groups, cfg, plan, lay,
-                          "prefill", positions, cache=cache, pages=pages)
+    x, cache, picks = _run_paged_stack(x, params["stacks"], groups, cfg,
+                                       plan, lay, "prefill", positions, None,
+                                       cache, pages)
     x = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
     x = apply_norm(x, params["final_norm"], cfg)
     logits = final_logits(params, x, cfg, lay)[:, 0]
-    return logits, cache
+    if picks is not None:           # the prompt's positions only
+        picks = jnp.sum(jnp.where(jnp.arange(C) <= last_idx, picks, 0),
+                        axis=1)
+    return _with_picks(logits, picks), cache

@@ -1,6 +1,7 @@
 """Mixture-of-Experts FFN.
 
-Two distribution modes (``plan.moe_mode``):
+One routed layer, ``moe_ffn``, for both distribution modes
+(``plan.moe_mode``); they differ only in which experts a shard computes:
 
 * ``tp`` — **paper-faithful**: every expert's intermediate dimension is
   sliced across the model axis exactly like a dense FC layer (the paper's
@@ -8,13 +9,22 @@ Two distribution modes (``plan.moe_mode``):
   collectives: routed partial outputs fold into the block's single post-FFN
   psum.  This is the only zero-duplication option when
   ``n_experts < tp`` (mixtral: 8 experts on 16 shards).
-* ``ep`` — beyond-paper expert parallelism: experts sharded whole across the
-  model axis (requires ``n_experts % tp == 0``); tokens are exchanged with
-  two ``all_to_all``s.  Fewer, larger matmuls (MXU-friendlier) at the cost
-  of a different collective pattern — evaluated in the §Perf hillclimb.
+* ``ep`` — beyond-paper expert parallelism: experts stored whole,
+  ``experts_held_ / tp`` per shard.  With replicated activations (the
+  paper's layout) no all_to_all is needed: every shard routes all tokens,
+  runs only its own experts and emits a partial combine that folds into
+  the same post-FFN psum — fewer, larger matmuls (MXU-friendlier).
 
-Routing uses capacity-bounded dispatch via sort + gather/scatter (no one-hot
-dispatch matmuls, so HLO FLOPs stay honest).
+A layer may hold only a range of the experts (``cfg.expert_first``,
+``cfg.experts_held``), as one chip of an expert-parallel deployment does:
+it routes over all ``n_experts`` and returns the held experts' part.
+
+Routing drops nothing.  Each computed expert runs as one dense bucket
+whose capacity is the step's token count, at the token's own row (a token
+picks an expert at most once); rows of tokens that did not pick it are
+weighted by zero.  So a token's output never depends on its batch-mates,
+at any batch or chunk size, at the cost of computing every token's row
+for every computed expert.
 """
 from __future__ import annotations
 
@@ -24,109 +34,58 @@ import jax.numpy as jnp
 from repro.core.layers import activation
 
 
-def _capacity(T: int, k: int, n_experts: int, factor: float) -> int:
-    """Expert capacity with a decode-safe floor: tiny token counts (decode
-    steps) get capacity >= min(T, 16) so adversarial routing cannot drop
-    tokens; the statistical capacity bound governs large T (prefill/train)."""
-    return max(int(factor * T * k / n_experts), min(T, 16), 1)
+def route(xt, router, cfg):
+    """Top-``cfg.top_k`` routing over all ``cfg.n_experts``.
+    x: (T, E) -> (gates (T,k) f32, idx (T,k) i32).
 
-
-def router_topk(x, w_router, top_k: int, n_experts: int):
-    """x: (T, E) -> (gates (T,k) f32 normalized, idx (T,k) i32)."""
-    logits = jnp.einsum("te,en->tn", x.astype(jnp.float32),
-                        w_router.astype(jnp.float32))
-    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    ``cfg.router == "softmax"``: the top k of softmax(x . w), renormalised.
+    ``"sigmoid"``: scores sigmoid(x . w); the top k of score + the
+    correction bias are picked, and their scores, renormalised to sum to
+    1, are scaled by ``cfg.routed_scale``.  The bias steers the choice
+    only, never the weights."""
+    logits = jnp.einsum("te,en->tn", xt.astype(jnp.float32),
+                        router["w"].astype(jnp.float32))
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + router["bias"].astype(jnp.float32),
+                               cfg.top_k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        return gates * cfg.routed_scale, idx
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
     gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
     return gates, idx
 
 
-def _bucket_by_expert(x, idx, gates, n_experts: int, capacity: int):
-    """Scatter tokens into per-expert buckets.
+def moe_ffn(x, p, cfg, first, n: int):
+    """Routed FFN over the experts ``[first, first + n)`` that this shard
+    computes (``first`` may be traced; ``p["experts"]`` holds those ``n``).
 
-    Returns (buckets (n_exp, cap, E), combine info for scatter-back).
-    Tokens over capacity are dropped (standard MoE semantics).
-    """
-    T, k = idx.shape
-    E = x.shape[-1]
-    flat_e = idx.reshape(-1)                       # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(T), k)
-    flat_g = gates.reshape(-1)
-    # position of each (token, expert) pair within its expert bucket
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    pos_in_bucket = jnp.arange(T * k) - jnp.searchsorted(sorted_e, sorted_e,
-                                                         side="left")
-    keep = pos_in_bucket < capacity
-    slot = jnp.where(keep, sorted_e * capacity + pos_in_bucket, n_experts * capacity)
-    src_t = flat_t[order]
-    buckets = jnp.zeros((n_experts * capacity + 1, E), x.dtype)
-    buckets = buckets.at[slot].set(x[src_t])
-    return (buckets[:-1].reshape(n_experts, capacity, E),
-            dict(slot=slot, src_t=src_t, gate=flat_g[order], keep=keep, T=T))
-
-
-def _combine(buckets_out, info, E):
-    """Scatter expert outputs back, weighted by gates."""
-    flat = jnp.concatenate(
-        [buckets_out.reshape(-1, E),
-         jnp.zeros((1, E), buckets_out.dtype)], axis=0)
-    picked = flat[jnp.minimum(info["slot"], flat.shape[0] - 1)]
-    w = jnp.where(info["keep"], info["gate"], 0.0).astype(picked.dtype)
-    out = jnp.zeros((info["T"], E), buckets_out.dtype)
-    return out.at[info["src_t"]].add(picked * w[:, None])
-
-
-def _expert_ffn(buckets, w_gate, w_up, w_down, act, gated):
-    """buckets: (n_exp, cap, E); weights: (n_exp, E, F), (n_exp, F, E)."""
-    if gated:
-        h = activation(jnp.einsum("nce,nef->ncf", buckets, w_gate), act) * \
-            jnp.einsum("nce,nef->ncf", buckets, w_up)
+    Returns the part of the result those experts give: each token's picks
+    that fall on them, weighted by their gates; picks on other experts are
+    another shard's or chip's part and are left out.
+    x: (B, S, E) -> (partial output (B, S, E), picks (B, S) int32: each
+    token's picks on the layer's held experts, ``cfg.expert_first`` to
+    ``cfg.expert_first + cfg.experts_held_``, the same on every shard)."""
+    B, S, E = x.shape
+    xt = x.reshape(B * S, E)
+    gates, idx = route(xt, p["router"], cfg)
+    local = idx - first
+    mine = (local >= 0) & (local < n)                        # (T, k)
+    # (T, n): each computed expert's gate for each token, 0 where not picked
+    comb = jnp.sum(jnp.where(mine[..., None] & (local[..., None] ==
+                                                jnp.arange(n)),
+                             gates[..., None], 0.0), axis=1)
+    ex = p["experts"]
+    up = jnp.einsum("te,nef->ntf", xt, ex["w_up"])
+    if cfg.gated_ffn:
+        h = activation(jnp.einsum("te,nef->ntf", xt, ex["w_gate"]),
+                       cfg.act) * up
     else:
-        h = activation(jnp.einsum("nce,nef->ncf", buckets, w_up), act)
-    return jnp.einsum("ncf,nfe->nce", h, w_down)
-
-
-def moe_ffn_tp(x, p, cfg, capacity_factor=1.25):
-    """Paper-faithful TP MoE.  x: (B, S, E) replicated; expert weights are
-    F-sliced: w_gate/w_up (n_exp, E, f_loc), w_down (n_exp, f_loc, E).
-    Returns the PARTIAL output (B, S, E) — summed in the block's post-FFN psum."""
-    B, S, E = x.shape
-    T = B * S
-    xt = x.reshape(T, E)
-    gates, idx = router_topk(xt, p["router"]["w"], cfg.top_k, cfg.n_experts)
-    capacity = _capacity(T, cfg.top_k, cfg.n_experts, capacity_factor)
-    buckets, info = _bucket_by_expert(xt, idx, gates, cfg.n_experts, capacity)
-    ex = p["experts"]
-    out = _expert_ffn(buckets, ex.get("w_gate"), ex["w_up"], ex["w_down"],
-                      cfg.act, cfg.gated_ffn)
-    y = _combine(out, info, E)
-    return y.reshape(B, S, E)
-
-
-def moe_ffn_ep(x, p, cfg, shard_idx, tp, capacity_factor=1.25):
-    """Expert-parallel MoE (beyond-paper variant).
-
-    Expert weights are stored whole, ``n_experts/tp`` per shard:
-    w_* (n_exp_loc, E, F_full).  With replicated activations (the paper's
-    layout) no all_to_all is needed: every shard buckets all tokens, runs
-    only its LOCAL experts, and emits a partial combine that folds into the
-    block's existing post-FFN psum — the two-sync contract is preserved
-    while matmuls become tp x larger per expert (MXU-friendlier than the
-    paper-faithful F=88 slices of deepseek-moe)."""
-    B, S, E = x.shape
-    T = B * S
-    n_loc = cfg.n_experts // tp
-    xt = x.reshape(T, E)
-    gates, idx = router_topk(xt, p["router"]["w"], cfg.top_k, cfg.n_experts)
-    capacity = _capacity(T, cfg.top_k, cfg.n_experts, capacity_factor)
-    buckets, info = _bucket_by_expert(xt, idx, gates, cfg.n_experts, capacity)
-    local = jax.lax.dynamic_slice_in_dim(buckets, shard_idx * n_loc, n_loc,
-                                         axis=0)
-    ex = p["experts"]
-    out_local = _expert_ffn(local, ex.get("w_gate"), ex["w_up"], ex["w_down"],
-                            cfg.act, cfg.gated_ffn)
-    out_full = jnp.zeros((cfg.n_experts, capacity, E), out_local.dtype)
-    out_full = jax.lax.dynamic_update_slice_in_dim(
-        out_full, out_local, shard_idx * n_loc, axis=0)
-    y = _combine(out_full, info, E)
-    return y.reshape(B, S, E)
+        h = activation(up, cfg.act)
+    out = jnp.einsum("ntf,nfe->nte", h, ex["w_down"])
+    y = jnp.sum(out.astype(jnp.float32) * comb.T[..., None], axis=0)
+    held = (idx >= cfg.expert_first) & \
+        (idx < cfg.expert_first + cfg.experts_held_)
+    picks = jnp.sum(held, axis=-1, dtype=jnp.int32).reshape(B, S)
+    return y.astype(x.dtype).reshape(B, S, E), picks

@@ -44,7 +44,6 @@ class ShardingPlan:
     seq_shard_kv: bool = False        # long-context decode: shard KV seq on dp
     activations: str = "replicated"   # replicated (paper) | seq (RS+AG, beyond-paper)
     moe_mode: str = "tp"              # tp (paper-faithful F-slice) | ep (all_to_all)
-    moe_capacity: float = 1.25        # per-DP-shard expert capacity factor
     remat: str = "none"               # none | block (training)
     kv_cache_dtype: str = "bfloat16"  # "int8": quantized page pools with
                                       #   per-(page, slot) scales (paged) /
@@ -173,16 +172,14 @@ def model_layout(cfg: ModelConfig, plan: ShardingPlan) -> ModelLayout:
     attn = head_layout(cfg.n_heads, cfg.n_kv_heads, tp)
     ssm = None
     if cfg.ssm_state:
-        d_inner = cfg.ssm_expand * cfg.d_model
-        n_ssm_heads = d_inner // cfg.ssm_head_dim
-        ssm = head_layout(n_ssm_heads, n_ssm_heads, tp)
+        ssm = head_layout(cfg.ssm_heads_, cfg.ssm_heads_, tp)
     moe_ffn = dim_layout(cfg.moe_d_ff, tp) if cfg.n_experts else None
-    shared = (dim_layout(cfg.moe_d_ff * cfg.n_shared_experts, tp)
+    shared = (dim_layout(cfg.shared_d_ff_, tp)
               if cfg.n_shared_experts else None)
     dense_override = (dim_layout(cfg.dense_ff_override, tp)
                       if cfg.dense_ff_override else None)
-    experts = dim_layout(cfg.n_experts, tp) if (cfg.n_experts and
-                                                plan.moe_mode == "ep") else None
+    experts = dim_layout(cfg.experts_held_, tp) if (
+        cfg.n_experts and plan.moe_mode == "ep") else None
     return ModelLayout(
         attn=attn,
         ssm=ssm,

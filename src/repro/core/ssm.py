@@ -6,7 +6,8 @@ attention heads):
 
     x  : (B, S, H, P)   local heads H, head dim P
     dt : (B, S, H)      softplus-activated step sizes
-    Bm, Cm : (B, S, N)  state projections (n_groups=1 -> shared per shard)
+    Bm, Cm : (B, S, N)  state projections shared by every head (one
+                        group), or (B, S, H, N): each head's own group's
     A  : (H,)           negative per-head decay
     state : (B, H, P, N)
 
@@ -19,8 +20,8 @@ import jax
 import jax.numpy as jnp
 
 
-def causal_conv(x, w, state=None, tail_idx=None):
-    """Depthwise causal conv.  x: (B, S, C), w: (C, K).
+def causal_conv(x, w, state=None, tail_idx=None, bias=None):
+    """Depthwise causal conv.  x: (B, S, C), w: (C, K), bias: (C,) or None.
     state: (B, K-1, C) previous inputs (decode) or None (prefill).
     tail_idx: scalar index of the last *valid* input row — the returned
     state is the K-1 inputs ending there (inclusive), so a chunk whose
@@ -34,6 +35,8 @@ def causal_conv(x, w, state=None, tail_idx=None):
     else:
         xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)
     y = sum(xp[:, i:i + S, :] * w[:, i].astype(x.dtype) for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     if K == 1:
         return y, jnp.zeros((B, 0, C), x.dtype)
     if tail_idx is None:
@@ -58,19 +61,22 @@ def ssd_chunked(x, dt, Bm, Cm, A, D, chunk: int, state0=None,
     """
     B, S, H, P = x.shape
     N = Bm.shape[-1]
+    per_head = Bm.ndim == 4            # (B,S,H,N): each head's group
+    hn = "hn" if per_head else "n"     # the B/C axes in the einsums
     pad = (-S) % chunk
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
+        bc_pad = ((0, 0), (0, pad)) + ((0, 0),) * (Bm.ndim - 2)
+        Bm = jnp.pad(Bm, bc_pad)
+        Cm = jnp.pad(Cm, bc_pad)
     Sp = S + pad
     nc, Q = Sp // chunk, chunk
 
     xf = x.astype(jnp.float32).reshape(B, nc, Q, H, P)
     dtf = dt.astype(jnp.float32).reshape(B, nc, Q, H)
-    Bf = Bm.astype(jnp.float32).reshape(B, nc, Q, N)
-    Cf = Cm.astype(jnp.float32).reshape(B, nc, Q, N)
+    Bf = Bm.astype(jnp.float32).reshape((B, nc, Q) + Bm.shape[2:])
+    Cf = Cm.astype(jnp.float32).reshape((B, nc, Q) + Cm.shape[2:])
     Af = A.astype(jnp.float32)
 
     a = dtf * Af                                   # (B,nc,Q,H), <= 0
@@ -78,17 +84,20 @@ def ssd_chunked(x, dt, Bm, Cm, A, D, chunk: int, state0=None,
     cs_last = cs[:, :, -1]                         # (B,nc,H)
 
     # intra-chunk: y[i] = sum_{j<=i} (C_i.B_j) exp(cs_i-cs_j) dt_j x_j
-    G = jnp.einsum("bcin,bcjn->bcij", Cf, Bf)      # (B,nc,Q,Q)
     seg = cs[:, :, :, None, :] - cs[:, :, None, :, :]   # (B,nc,Q,Q,H) i,j
     causal = jnp.tril(jnp.ones((Q, Q), bool))
     L = jnp.where(causal[None, None, :, :, None], jnp.exp(seg), 0.0)
-    W = G[..., None] * L                           # (B,nc,Q,Q,H)
+    if per_head:
+        W = jnp.einsum("bcihn,bcjhn->bcijh", Cf, Bf) * L
+    else:
+        G = jnp.einsum("bcin,bcjn->bcij", Cf, Bf)  # (B,nc,Q,Q)
+        W = G[..., None] * L                       # (B,nc,Q,Q,H)
     xdt = xf * dtf[..., None]                      # (B,nc,Q,H,P)
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", W, xdt)
 
     # chunk state contributions: sum_j exp(cs_last - cs_j) dt_j B_j (x) x_j
     decay_to_end = jnp.exp(cs_last[:, :, None, :] - cs)       # (B,nc,Q,H)
-    contrib = jnp.einsum("bcjh,bcjn,bcjhp->bchpn",
+    contrib = jnp.einsum(f"bcjh,bcj{hn},bcjhp->bchpn",
                          decay_to_end * dtf, Bf, xf)          # (B,nc,H,P,N)
     chunk_decay = jnp.exp(cs_last)                            # (B,nc,H)
 
@@ -104,7 +113,7 @@ def ssd_chunked(x, dt, Bm, Cm, A, D, chunk: int, state0=None,
     S_prevs = jnp.moveaxis(S_prevs, 0, 1)                     # (B,nc,H,P,N)
 
     # inter-chunk: y[i] += C_i . (exp(cs_i) * S_prev)
-    y_inter = jnp.einsum("bcin,bchpn->bcihp", Cf, S_prevs) * \
+    y_inter = jnp.einsum(f"bci{hn},bchpn->bcihp", Cf, S_prevs) * \
         jnp.exp(cs)[..., None]
     y = (y_intra + y_inter).reshape(B, Sp, H, P)[:, :S]
     y = y + xf.reshape(B, Sp, H, P)[:, :S] * D.astype(jnp.float32)[None, None, :, None]
@@ -119,12 +128,15 @@ def ssd_chunked(x, dt, Bm, Cm, A, D, chunk: int, state0=None,
 
 
 def ssd_decode_step(x, dt, Bm, Cm, A, D, state):
-    """One token.  x: (B,H,P) dt: (B,H) Bm/Cm: (B,N) state: (B,H,P,N)."""
+    """One token.  x: (B,H,P) dt: (B,H) Bm/Cm: (B,N), or (B,H,N) per
+    head, state: (B,H,P,N)."""
+    hn = "hn" if Bm.ndim == 3 else "n"
     xf = x.astype(jnp.float32)
     dtf = dt.astype(jnp.float32)
     dec = jnp.exp(dtf * A.astype(jnp.float32))                # (B,H)
-    contrib = jnp.einsum("bh,bn,bhp->bhpn", dtf, Bm.astype(jnp.float32), xf)
+    contrib = jnp.einsum(f"bh,b{hn},bhp->bhpn", dtf, Bm.astype(jnp.float32),
+                         xf)
     state = state * dec[..., None, None] + contrib
-    y = jnp.einsum("bn,bhpn->bhp", Cm.astype(jnp.float32), state)
+    y = jnp.einsum(f"b{hn},bhpn->bhp", Cm.astype(jnp.float32), state)
     y = y + xf * D.astype(jnp.float32)[None, :, None]
     return y.astype(x.dtype), state

@@ -552,7 +552,9 @@ def make_paged_decode_step(cfg, plan, mesh, batch: int, n_pages: int,
                            n_replicas: int = 1, n_slabs: int = 0):
     """-> (decode_fn(params, cache, tokens (R*B,1), pos (R*B,), block_table
     (R*B, n_max)[, slab_ids (R*B,)][, cross_block_table (R*B, n_cross)])
-    -> (logits, cache), templates, specs).
+    -> (logits, cache), templates, specs).  Where
+    ``model.counts_expert_picks(cfg)`` the first output is ``(logits,
+    picks (R*B,))``: each row's (expert layer, pick) pairs on held experts.
 
     ``batch`` is the per-replica slot count; the global decode batch covers
     all ``n_replicas`` replicas' slots (rows r*B..r*B+B-1 belong to replica
@@ -601,10 +603,13 @@ def make_paged_decode_step(cfg, plan, mesh, batch: int, n_pages: int,
         t["cross_block_table"] = jax.ShapeDtypeStruct(
             (n_replicas * batch, n_cross), jnp.int32)
         extra_s.append(s["cross_block_table"])
+    out_s = P(bt_ax, "model")
+    if model.counts_expert_picks(cfg):
+        out_s = (out_s, P(bt_ax))
     fn = _shard_map(paged_decode, mesh,
                     in_specs=(pspecs, s["cache"], s["tokens1"], s["pos"],
                               s["block_table"], *extra_s),
-                    out_specs=(P(bt_ax, "model"), s["cache"]))
+                    out_specs=(out_s, s["cache"]))
     return fn, t, s
 
 
@@ -659,7 +664,10 @@ def make_prefill_chunk_step(cfg, plan, mesh, chunk: int, n_pages: int,
                             n_replicas: int = 1, n_slabs: int = 0):
     """-> (chunk_fn(params, cache, tokens (R,C), chunk_start (R,), last_idx
     (R,), block_table (R, n_max)[, slab_ids (R,)][, cross_block_table
-    (R, n_cross)]) -> (logits (R, V), cache), templates, specs).
+    (R, n_cross)]) -> (logits (R, V), cache), templates, specs).  Where
+    ``model.counts_expert_picks(cfg)`` the first output is ``(logits,
+    picks (R,))``: each row's (expert layer, pick) pairs on held experts
+    over the chunk's prompt positions.
 
     Row r advances one prefill chunk for replica r; a replica with nothing
     to prefill rides along pointed at its scratch page (all-SCRATCH_PAGE
@@ -699,7 +707,8 @@ def make_prefill_chunk_step(cfg, plan, mesh, chunk: int, n_pages: int,
                 params, cache, tokens[i:i + 1], chunk_start[i],
                 last_idx[i], cfg, plan, lay, pages)
             logits.append(lg)
-        return jnp.concatenate(logits, axis=0), cache
+        return jax.tree_util.tree_map(
+            lambda *rows: jnp.concatenate(rows, axis=0), *logits), cache
 
     s = {"cache": cache_s, "tokens": P(bt_ax, None),
          "chunk_start": P(bt_ax), "last_idx": P(bt_ax),
@@ -720,11 +729,14 @@ def make_prefill_chunk_step(cfg, plan, mesh, chunk: int, n_pages: int,
         t["cross_block_table"] = jax.ShapeDtypeStruct(
             (n_replicas, n_cross), jnp.int32)
         extra_s.append(s["cross_block_table"])
+    out_s = P(bt_ax, "model")
+    if model.counts_expert_picks(cfg):
+        out_s = (out_s, P(bt_ax))
     fn = _shard_map(prefill_chunk, mesh,
                     in_specs=(pspecs, s["cache"], s["tokens"],
                               s["chunk_start"], s["last_idx"],
                               s["block_table"], *extra_s),
-                    out_specs=(P(bt_ax, "model"), s["cache"]))
+                    out_specs=(out_s, s["cache"]))
     return fn, t, s
 
 
@@ -965,16 +977,14 @@ def paged_step_set(cfg, plan, mesh, batch: int, n_pages: int, page_size: int,
     replica count.  Memoizing the jitted closures on the step shape makes
     repeated reconfiguration — and fault-injection suites that build many
     engines over the same config — pay compilation once per distinct
-    (cfg, mesh, plan-shape, batch, pool, n_replicas) tuple.  The memo holds
+    (cfg, mesh, plan, batch, pool, n_replicas) tuple.  The memo holds
     cfg/mesh strongly so their ids cannot be recycled under a live key.
 
     Donation matches the engine's call conventions: every step that
     threads the cache donates it (arg 1 after params, or arg 0 for the
     param-less copy/transfer steps)."""
-    key = (id(cfg), id(mesh), plan.tp, str(plan.kv_cache_dtype),
-           str(plan.ssm_cache_dtype), tuple(plan.dp_axes), batch, n_pages,
-           page_size, n_max_pages, prefill_chunk, n_replicas, n_slabs,
-           speculative)
+    key = (id(cfg), id(mesh), plan, batch, n_pages, page_size, n_max_pages,
+           prefill_chunk, n_replicas, n_slabs, speculative)
     hit = _STEP_SET_MEMO.get(key)
     if hit is not None:
         return hit[2]

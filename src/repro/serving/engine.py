@@ -248,6 +248,11 @@ class Request:
     rng: Optional[np.random.RandomState] = None   # set at submit
     replica: int = -1                  # routed data shard (set at submit)
     out_tokens: list = field(default_factory=list)
+    # parallel to out_tokens, for a model whose paged steps count expert
+    # picks (``model.counts_expert_picks``): the (expert layer, pick) pairs
+    # on held experts that the step making each token computed for this
+    # request (for a first token, every chunk of its prompt)
+    expert_picks: list = field(default_factory=list)
     done: bool = False
     # host stamps, time.perf_counter(): on Linux the same clock as
     # time.monotonic() (CLOCK_MONOTONIC), and the clock a benchmark
@@ -392,6 +397,10 @@ class ServingEngine:
         # must be invalidated when a page is recycled (see _plan_admissions)
         self.quant_pools = paged and kv_pool_is_quantized(plan) and \
             ("kv" in prof or "cross_kv" in prof)
+        from repro.core.model import counts_expert_picks
+        self.counts_picks = paged and counts_expert_picks(cfg)
+        self._logits_host = {}          # shape -> float32 buffer, reused
+        self._prompt_picks: dict = {}  # rid -> picks of its prompt chunks
         self.overlap = bool(overlap) and paged
         self.disagg = None
         self.roles: Optional[List[str]] = None
@@ -1260,7 +1269,7 @@ class ServingEngine:
                 self.params, self.cache,
                 jnp.asarray(self.last_token[:, None]),
                 jnp.asarray(self.pos))
-        logits = np.asarray(jax.device_get(logits)).astype(np.float32)
+        logits = self._host_logits(jax.device_get(logits))
         live = [(b, req) for b, req in enumerate(self.slots)
                 if req is not None]
         with _Phase(self.stats, "engine.sample"):
@@ -1271,13 +1280,32 @@ class ServingEngine:
             self._emit(b, req, tok, now)
         self.stats.ticks += 1
 
+    def _host_logits(self, out) -> np.ndarray:
+        """Fetched logits as float32, cast into a host buffer of their
+        shape that every tick reuses.  Casting into a fresh array each
+        tick (67 MB at 128 rows of 131,072) took about 43 ms a tick on a
+        v5e host in a process that had compiled its own steps, against
+        about 5 ms in one that loaded them from the compile cache.  Valid
+        until the next call for the same shape: a sampler that keeps a
+        row copies it."""
+        out = np.asarray(out)
+        buf = self._logits_host.get(out.shape)
+        if buf is None:
+            buf = self._logits_host[out.shape] = np.empty(out.shape,
+                                                          np.float32)
+        np.copyto(buf, out, casting="unsafe")
+        return buf
+
     def _sample_row(self, logits: np.ndarray, b: int, req: Request) -> int:
         """Sample row b from the request's own stream (schedule-invariant)."""
         return int(sample_from_logits(logits[b:b + 1], self.sampler,
                                       self.cfg.vocab_size, req.rng)[0])
 
-    def _emit(self, b: int, req: Request, tok: int, now: float):
-        """Record one generated token for slot b; retire the slot when done.
+    def _emit(self, b: int, req: Request, tok: int, now: float,
+              picks: Optional[int] = None):
+        """Record one generated token for slot b (and, where the steps
+        count them, the expert picks that made it); retire the slot when
+        done.
 
         The caller owns ``pos``: decode ticks advance it past the KV they
         just wrote before emitting; prefill completion leaves it at the
@@ -1287,6 +1315,8 @@ class ServingEngine:
             req.t_first_token = now
             self.stats.request_ttft[req.rid] = now - req.t_submit
         req.out_tokens.append(tok)
+        if picks is not None:
+            req.expert_picks.append(picks)
         self.last_token[b] = tok
         self.stats.decoded_tokens += 1
         self.stats.replicas[self._rep(b)].decoded_tokens += 1
@@ -1333,7 +1363,7 @@ class ServingEngine:
         self.stats.replicas[self._rep(b)].prefills += 1
         # splice lane 0 of lane_cache into slot b of the engine cache
         self.cache = _splice_cache(self.cache, lane_cache, b)
-        logits = np.asarray(jax.device_get(logits)).astype(np.float32)
+        logits = self._host_logits(jax.device_get(logits))
         # the token sampled from the prompt's final logits IS the first
         # generated token: emit it (TTFT lands at prefill completion, and
         # max_new_tokens counts it)
@@ -1494,19 +1524,31 @@ class ServingEngine:
         if inf is None:
             return
         self._inflight = None
-        handles = [h for h, comps in inf["pf"] if comps]
+        # a chunk that finishes no prompt is fetched only for its picks
+        handles = [h if comps else h[1] for h, comps, _ in inf["pf"]
+                   if comps or self.counts_picks]
         step = inf["step"]
         if step is not None:
             handles.append(step[1])
         with _Phase(self.stats, "engine.wait"):
             vals = jax.device_get(handles) if handles else []
         vi = 0
-        for _, comps in inf["pf"]:
+        for _, comps, rows in inf["pf"]:
+            if not (comps or self.counts_picks):
+                continue
+            out, picks = vals[vi], None
+            vi += 1
+            if self.counts_picks:
+                out, picks = out if comps else (None, out)
+            for r, b, rid in rows if picks is not None else ():
+                adm = self.admissions[b]
+                if adm is not None and adm.req.rid == rid:
+                    self._prompt_picks[rid] = \
+                        self._prompt_picks.get(rid, 0) + int(picks[r])
             if not comps:
                 continue
             with _Phase(self.stats, "engine.sample"):
-                logits_np = np.asarray(vals[vi]).astype(np.float32)
-            vi += 1
+                logits_np = self._host_logits(out)
             for r, b, rid, L in comps:
                 adm = self.admissions[b]
                 if adm is None or adm.req.rid != rid:
@@ -1522,7 +1564,8 @@ class ServingEngine:
                 self.pos[b] = L
                 with _Phase(self.stats, "engine.sample"):
                     tok = self._sample_row(logits_np, r, req)
-                self._emit(b, req, tok, time.perf_counter())
+                self._emit(b, req, tok, time.perf_counter(),
+                           self._prompt_picks.pop(rid, None))
                 if self.admissions[b] is None:
                     continue           # retired by that token
                 if self.roles is not None and self.roles[r] == "prefill":
@@ -1539,19 +1582,21 @@ class ServingEngine:
                 if self.admissions[b] is not None
                 and self.admissions[b].req.rid == rid]
         if step[0] == "decode":
+            out, picks = vals[vi] if self.counts_picks else (vals[vi], None)
             # sample every row, then emit: an emission retires only its
             # own slot, so the order changes nothing
             with _Phase(self.stats, "engine.sample"):
-                logits = np.asarray(vals[vi]).astype(np.float32)
+                logits = self._host_logits(out)
                 toks = [self._sample_row(logits, b, req) for b, req in live]
             now = time.perf_counter()
             for (b, req), tok in zip(live, toks, strict=True):
                 self.pos[b] += 1    # the decode step wrote last_token's KV
-                self._emit(b, req, tok, now)
+                self._emit(b, req, tok, now,
+                           None if picks is None else int(picks[b]))
         else:                        # verify
             drafts = step[3]
             with _Phase(self.stats, "engine.sample"):
-                logits = np.asarray(vals[vi]).astype(np.float32)
+                logits = self._host_logits(vals[vi])
             for b, req in live:
                 d = drafts.get(b, [])
                 with _Phase(self.stats, "engine.sample"):
@@ -1692,8 +1737,8 @@ class ServingEngine:
         across replicas: compiled chunk call k covers each replica's k-th
         prefilling slot (replicas with fewer ride along as scratch-page
         no-ops), so the dp mesh prefills all replicas in parallel.
-        -> list of (logits handle, completions) per round, consumed at the
-        next collect point."""
+        -> list of (logits handle, completions, rows) per round, consumed
+        at the next collect point."""
         per_rep = [[b for b in self._rep_slots(r)
                     if self.admissions[b] is not None
                     and self.slot_state[b] == "prefill"]
@@ -1708,8 +1753,8 @@ class ServingEngine:
         """One compiled chunk call: row r advances slot ``rows[r]`` (or is
         a scratch no-op when None).  Host bookkeeping (``prefill_done``)
         advances now; -> (logits handle, [(r, b, rid, prompt_len)] for
-        rows whose prompt is now fully resident) — sampling waits for the
-        collect point."""
+        rows whose prompt is now fully resident, [(r, b, rid)] for every
+        row it advanced) — sampling waits for the collect point."""
         C = self.chunk
         toks = np.zeros((self.R, C), np.int32)
         starts = np.zeros(self.R, np.int32)
@@ -1748,7 +1793,8 @@ class ServingEngine:
             self.prefill_done[b] = int(starts[r]) + C
             if int(starts[r]) + C >= L:      # prompt fully resident
                 comps.append((r, b, req.rid, L))
-        return logits, comps
+        return logits, comps, [(r, b, req.rid)
+                               for r, (b, req, _) in prompts.items()]
 
     def _dispatch_step(self):
         """Dispatch the tick's decode-or-verify step over every

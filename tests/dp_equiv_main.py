@@ -64,7 +64,9 @@ def layouts():
     """The carried pools are indexed by (layer, replica, page): two
     replicas per data shard (dp=4 on the 2-device data mesh) and heads
     split over a 2-device model axis (tp=2) give the one-device logits for
-    every served token, for KV pages and SSM slabs.  Logits, not tokens:
+    every served token, for KV pages and SSM slabs (and, for the
+    interleaved Nemotron cut, B/C groups and held experts split over the
+    model axis, by their width or whole).  Logits, not tokens:
     a tiny random model's greedy tokens barely depend on what attention
     reads."""
     m1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
@@ -103,7 +105,8 @@ def layouts():
     # summation order into a different stored byte
     for name, kvd, ssd, tp2 in (("tinyllama-42m", "float32", "", True),
                                 ("hymba-1.5b", "int8", "int8", False),
-                                ("hymba-1.5b", "float32", "", True)):
+                                ("hymba-1.5b", "float32", "", True),
+                                ("nemotron3-nano-ep8", "float32", "", True)):
         cfg = reduced(get_config(name), dtype="float32")
         p1 = ShardingPlan(tp=1, kv_cache_dtype=kvd, ssm_cache_dtype=ssd)
         params = model.init_params(cfg, p1, seed=1)
@@ -118,6 +121,11 @@ def layouts():
         if name == "tinyllama-42m":
             same(run(cfg, p1, m2, params, 4), oracle,
                  f"{name} {kvd}: two replicas per data shard")
+        if cfg.n_experts:       # the held experts whole, one range a shard
+            pe = ShardingPlan(tp=2, moe_mode="ep", kv_cache_dtype=kvd)
+            same(run(cfg, pe, mt, model.init_params(cfg, pe, seed=1,
+                                                    mesh=mt), 1),
+                 oracle, f"{name} {kvd}: tp=2, experts by expert parallelism")
     print("paged-layouts OK: dp=4 on 2 shards and tp=2 == 1 device")
     return 0
 

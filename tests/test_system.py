@@ -157,10 +157,10 @@ def test_two_sync_contract_all_dense_archs(mesh1):
 def test_shape_support_matrix():
     from repro.configs import ASSIGNED
     cells = [(a, s) for a in ASSIGNED for s in SHAPES]
-    assert len(cells) == 40
+    assert len(cells) == 44
     skipped = [(a, s) for a, s in cells
                if not shape_supported(get_config(a), SHAPES[s])[0]]
-    assert len(skipped) == 5
+    assert len(skipped) == 6
     assert all(s == "long_500k" for _, s in skipped)
 
 

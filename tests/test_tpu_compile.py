@@ -214,3 +214,41 @@ def test_paged_kernel_compiles_for_v5e(one_chip, kernel, dtype):
         call = fn
     compiled = jax.jit(call).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_chunk"])
+def test_nemotron_cell_steps_fit_v5e(chip_mesh, kind):
+    """The nemotron3-reason deployment (128 slots of 4096 tokens: 129
+    state slabs in the nine Mamba-2 layers, 32,769 pages in the three
+    attention layers, 16 experts in each of nine expert layers) compiles
+    for one v5e, fits its 16 GB, and copies no whole pool."""
+    cfg = get_config("nemotron3-nano-ep8")
+    plan = ShardingPlan(tp=1)
+    slots, budget = 128, 4096
+    n_max = budget // PSZ
+    geo = dict(n_pages=slots * n_max + 1, page_size=PSZ, n_max_pages=n_max,
+               n_slabs=slots + 1)
+    if kind == "decode":
+        fn, t, s = steps.make_paged_decode_step(cfg, plan, chip_mesh, slots,
+                                                **geo)
+        names = ("tokens1", "pos", "block_table", "slab_ids")
+    else:
+        fn, t, s = steps.make_prefill_chunk_step(cfg, plan, chip_mesh, CHUNK,
+                                                 **geo)
+        names = ("tokens", "chunk_start", "last_idx", "block_table",
+                 "slab_ids")
+    args = [_on(chip_mesh, model.abstract_params(cfg, plan),
+                model.param_pspecs(cfg, plan)),
+            _on(chip_mesh, t["cache"], s["cache"])]
+    args += [_on(chip_mesh, t[k], s[k]) for k in names]
+    with chip_mesh:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree_util.tree_leaves(t["cache"]))
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.alias_size_in_bytes >= cache_bytes     # donated
+    assert used < 0.75 * V5E_HBM_BYTES, used
+    assert not _pool_sized(compiled.as_text(), t["cache"],
+                           0.01 * cache_bytes)

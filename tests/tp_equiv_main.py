@@ -46,9 +46,7 @@ def run_case(name, **overrides):
 
     losses, decs = [], []
     for mesh, tp in ((m1, 1), (m8, 4)):
-        # moe_capacity=64: no token drops, so capacity rounding (a per-DP-shard
-        # semantic, not a partitioning property) cannot differ between meshes.
-        plan = ShardingPlan(tp=tp, moe_capacity=64.0)
+        plan = ShardingPlan(tp=tp)
         state = steps.init_train_state(cfg, plan)
         ts, _ = steps.make_train_step(cfg, plan, mesh, shape=shape)
         with jax.set_mesh(mesh):
