@@ -76,6 +76,14 @@ def run_family(name, plan_fp, plan_i8, mesh, frames_of=None):
             eng.submit(r)
         eng.run(max_ticks=2000)
         assert all(r.done for r in reqs), name
+        # the engine is greedy and picks its tokens on the device; the
+        # recorder read every emitted token's row, and the token is its
+        # argmax, so the drift below compares every row
+        for r in reqs:
+            rows = eng.recorded.get(r.rid, [])
+            assert len(rows) == len(r.out_tokens) > 0, (name, r.rid)
+            assert r.out_tokens == [int(np.argmax(x[:cfg.vocab_size]))
+                                    for x in rows], (name, r.rid)
         return ({r.rid: tuple(r.out_tokens) for r in reqs}, eng.recorded)
 
     rng_p = np.random.RandomState(SEED + 1)

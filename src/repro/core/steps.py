@@ -961,6 +961,20 @@ def zero_slab(pool, replica, slab):
     return pool.at[:, replica, slab].set(0)
 
 
+@functools.partial(jax.jit, static_argnums=(1,))
+def greedy_ids(logits, vocab_size: int):
+    """-> ``(N,)`` int32, the argmax of each row of ``logits`` ``(N,
+    V_pad)`` over its first ``vocab_size`` columns, ties to the first
+    index (as ``np.argmax``): the greedy tokens of a step, picked on the
+    device as one compiled module named ``jit_greedy_ids``.  Padding
+    columns are masked rather than sliced off, so logits whose vocabulary
+    is sharded over ``model`` stay sharded and the compiler reduces the
+    argmax across the shards."""
+    col = jnp.arange(logits.shape[-1])
+    return jnp.argmax(jnp.where(col < vocab_size, logits, -jnp.inf),
+                      axis=-1).astype(jnp.int32)
+
+
 _STEP_SET_MEMO: dict = {}
 
 

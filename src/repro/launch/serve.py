@@ -319,6 +319,8 @@ def main(argv=None):
               f"wait={stats.collect_wait_s * per_tick:.2f}ms "
               f"sample={stats.sample_s * per_tick:.2f}ms "
               f"dispatch={stats.dispatch_s * per_tick:.2f}ms")
+        print(f"sampled: device={stats.device_sampled} "
+              f"host={stats.host_sampled}")
     if disagg is not None:
         print(f"disagg(P={disagg[0]} D={disagg[1]}): "
               f"handoffs={stats.handoffs} "

@@ -189,8 +189,8 @@ from repro.core.kvcache import (SCRATCH_PAGE, SCRATCH_SLAB, PageAllocator,
 from repro.serving.prefix_cache import (CrossKVCache, HostSpillStore,
                                         PromptLookupDraft, RadixPrefixCache)
 from repro.serving.router import Router
-from repro.serving.sampler import (SamplerConfig, sample_from_logits,
-                                   speculative_sample)
+from repro.serving.sampler import (DeviceRows, SamplerConfig,
+                                   sample_from_logits, speculative_sample)
 from repro.serving.scheduler import (Admission, FCFSScheduler, Scheduler,
                                      effective_prompt)
 
@@ -321,11 +321,20 @@ class EngineStats:
     # host seconds per phase of the tick (``PHASE_COUNTERS``)
     plan_s: float = 0.0                # plan phase: admissions, handoffs
     collect_wait_s: float = 0.0        # host time blocked at collect points
-    sample_s: float = 0.0              # host sampling of fetched logits
+    sample_s: float = 0.0              # host sampling: row sampler calls
     dispatch_s: float = 0.0            # dispatch phase: inputs, enqueue
+    # emitted tokens whose row the device reduced to its greedy id
+    # (``core.steps.greedy_ids``); the rest were sampled from logits
+    # fetched to the host (``host_sampled``)
+    device_sampled: int = 0
     tpot_s: list = field(default_factory=list)
     request_ttft: dict = field(default_factory=dict)   # rid -> seconds
     replicas: List[ReplicaStats] = field(default_factory=list)
+
+    @property
+    def host_sampled(self) -> int:
+        """Emitted tokens sampled from logits fetched to the host."""
+        return self.decoded_tokens - self.device_sampled
 
     @property
     def ttft_s(self) -> list:
@@ -1512,34 +1521,57 @@ class ServingEngine:
                     self._reset_scale_rows(r, dirty)
 
     # --------------------------------------------------------- collect phase
+    def _step_result(self, out, emits: bool = True):
+        """A paged step's output as the collect point consumes it:
+        ``(logits, picks, ids)`` handles.  ``picks`` is None where the
+        steps count no expert picks.  ``ids`` holds each row's greedy
+        token, picked on the device (``core.steps.greedy_ids``), where the
+        sampler is greedy and the step ``emits`` tokens; otherwise it is
+        None."""
+        from repro.core import steps as _steps
+        logits, picks = out if self.counts_picks else (out, None)
+        ids = _steps.greedy_ids(logits, self.cfg.vocab_size) \
+            if emits and self.sampler.temperature <= 0 else None
+        return logits, picks, ids
+
+    def _sample_input(self, res, got):
+        """-> what the row sampler reads for a step result ``res`` whose
+        fetch (``_collect_phase``) came back as ``got``: the logits as
+        float32, or the device-picked ids as ``DeviceRows``, whose logits
+        are fetched only if a sampler reads them."""
+        logits, _, ids = res
+        if ids is None:
+            return self._host_logits(got)
+        return DeviceRows(got, lambda: np.asarray(jax.device_get(logits),
+                                                  np.float32))
+
     def _collect_phase(self):
         """The tick's single barrier point: one batched ``jax.device_get``
         over every in-flight handle, then host-side consumption in
         dispatch order — prefill completions (first-token emission, state
         flip to decode or the handoff queue) before decode/verify
-        emissions.  Slots evicted or retired since dispatch are skipped by
-        (slot, rid) guard, so a cancelled request's RNG stream is never
-        advanced."""
+        emissions.  Under a greedy sampler it fetches each emitting
+        step's token ids, picked on the device, and not its logits.
+        Slots evicted or retired since dispatch are skipped by (slot, rid)
+        guard, so a cancelled request's RNG stream is never advanced."""
         inf = self._inflight
         if inf is None:
             return
         self._inflight = None
+
+        def fetched(res, emits):
+            logits, picks, ids = res
+            return (logits if ids is None else ids) if emits else None, \
+                picks
+
         # a chunk that finishes no prompt is fetched only for its picks
-        handles = [h if comps else h[1] for h, comps, _ in inf["pf"]
-                   if comps or self.counts_picks]
+        handles = [fetched(res, comps) for res, comps, _ in inf["pf"]]
         step = inf["step"]
         if step is not None:
-            handles.append(step[1])
+            handles.append(fetched(step[1], True))
         with _Phase(self.stats, "engine.wait"):
-            vals = jax.device_get(handles) if handles else []
-        vi = 0
-        for _, comps, rows in inf["pf"]:
-            if not (comps or self.counts_picks):
-                continue
-            out, picks = vals[vi], None
-            vi += 1
-            if self.counts_picks:
-                out, picks = out if comps else (None, out)
+            vals = jax.device_get(handles)
+        for (res, comps, rows), (got, picks) in zip(inf["pf"], vals):
             for r, b, rid in rows if picks is not None else ():
                 adm = self.admissions[b]
                 if adm is not None and adm.req.rid == rid:
@@ -1548,7 +1580,8 @@ class ServingEngine:
             if not comps:
                 continue
             with _Phase(self.stats, "engine.sample"):
-                logits_np = self._host_logits(out)
+                logits_np = self._sample_input(res, got)
+            on_device = res[2] is not None
             for r, b, rid, L in comps:
                 adm = self.admissions[b]
                 if adm is None or adm.req.rid != rid:
@@ -1564,6 +1597,7 @@ class ServingEngine:
                 self.pos[b] = L
                 with _Phase(self.stats, "engine.sample"):
                     tok = self._sample_row(logits_np, r, req)
+                self.stats.device_sampled += on_device
                 self._emit(b, req, tok, time.perf_counter(),
                            self._prompt_picks.pop(rid, None))
                 if self.admissions[b] is None:
@@ -1581,13 +1615,15 @@ class ServingEngine:
         live = [(b, self.admissions[b].req) for b, rid in step[2]
                 if self.admissions[b] is not None
                 and self.admissions[b].req.rid == rid]
+        got, picks = vals[-1]
         if step[0] == "decode":
-            out, picks = vals[vi] if self.counts_picks else (vals[vi], None)
             # sample every row, then emit: an emission retires only its
             # own slot, so the order changes nothing
             with _Phase(self.stats, "engine.sample"):
-                logits = self._host_logits(out)
+                logits = self._sample_input(step[1], got)
                 toks = [self._sample_row(logits, b, req) for b, req in live]
+            if step[1][2] is not None:
+                self.stats.device_sampled += len(live)
             now = time.perf_counter()
             for (b, req), tok in zip(live, toks, strict=True):
                 self.pos[b] += 1    # the decode step wrote last_token's KV
@@ -1596,7 +1632,7 @@ class ServingEngine:
         else:                        # verify
             drafts = step[3]
             with _Phase(self.stats, "engine.sample"):
-                logits = self._host_logits(vals[vi])
+                logits = self._host_logits(got)
             for b, req in live:
                 d = drafts.get(b, [])
                 with _Phase(self.stats, "engine.sample"):
@@ -1737,7 +1773,7 @@ class ServingEngine:
         across replicas: compiled chunk call k covers each replica's k-th
         prefilling slot (replicas with fewer ride along as scratch-page
         no-ops), so the dp mesh prefills all replicas in parallel.
-        -> list of (logits handle, completions, rows) per round, consumed
+        -> list of (step result, completions, rows) per round, consumed
         at the next collect point."""
         per_rep = [[b for b in self._rep_slots(r)
                     if self.admissions[b] is not None
@@ -1752,9 +1788,10 @@ class ServingEngine:
     def _prefill_chunk_round(self, rows: List[Optional[int]]):
         """One compiled chunk call: row r advances slot ``rows[r]`` (or is
         a scratch no-op when None).  Host bookkeeping (``prefill_done``)
-        advances now; -> (logits handle, [(r, b, rid, prompt_len)] for
-        rows whose prompt is now fully resident, [(r, b, rid)] for every
-        row it advanced) — sampling waits for the collect point."""
+        advances now; -> (step result (``_step_result``), [(r, b, rid,
+        prompt_len)] for rows whose prompt is now fully resident, [(r, b,
+        rid)] for every row it advanced) — sampling waits for the collect
+        point."""
         C = self.chunk
         toks = np.zeros((self.R, C), np.int32)
         starts = np.zeros(self.R, np.int32)
@@ -1786,21 +1823,21 @@ class ServingEngine:
         if self.has_cross:
             args.append(jnp.asarray(cbt))
         with self.mesh:
-            logits, self.cache = self.prefill_fn(*args)
+            out, self.cache = self.prefill_fn(*args)
         comps = []
         for r, (b, req, prompt) in prompts.items():
             L = len(prompt)
             self.prefill_done[b] = int(starts[r]) + C
             if int(starts[r]) + C >= L:      # prompt fully resident
                 comps.append((r, b, req.rid, L))
-        return logits, comps, [(r, b, req.rid)
-                               for r, (b, req, _) in prompts.items()]
+        return self._step_result(out, bool(comps)), comps, \
+            [(r, b, req.rid) for r, (b, req, _) in prompts.items()]
 
     def _dispatch_step(self):
         """Dispatch the tick's decode-or-verify step over every
-        decode-state slot.  -> ("decode", logits handle, [(b, rid)]) or
-        ("verify", logits handle, [(b, rid)], drafts) or None; emissions
-        happen at the next collect point."""
+        decode-state slot.  -> ("decode", step result (``_step_result``),
+        [(b, rid)]) or ("verify", (logits, None, None), [(b, rid)],
+        drafts) or None; emissions happen at the next collect point."""
         active = [b for b in range(self.B)
                   if self.admissions[b] is not None
                   and self.slot_state[b] == "decode"]
@@ -1834,8 +1871,8 @@ class ServingEngine:
                                     np.int32) for b in range(self.B)])
             args.append(jnp.asarray(cbt))
         with self.mesh:
-            logits, self.cache = self.decode_fn(*args)
-        return ("decode", logits,
+            out, self.cache = self.decode_fn(*args)
+        return ("decode", self._step_result(out),
                 [(b, self.admissions[b].req.rid) for b in active])
 
     # ---------------------------------------------------- speculative decode
@@ -1902,7 +1939,7 @@ class ServingEngine:
             logits, self.cache = self.verify_fn(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(pos), jnp.asarray(qlen), jnp.asarray(bt))
-        return ("verify", logits,
+        return ("verify", (logits, None, None),
                 [(b, self.admissions[b].req.rid) for b in active], drafts)
 
 

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 import numpy as np
 
@@ -13,9 +14,41 @@ class SamplerConfig:
     top_p: float = 0.0
 
 
-def sample_from_logits(logits: np.ndarray, cfg: SamplerConfig,
-                       vocab_size: int, rng: np.random.RandomState):
-    """logits: (B, V_pad) float32 -> (B,) int32."""
+class DeviceRows:
+    """Rows of logits left on the device, with their greedy tokens
+    already picked there (``core.steps.greedy_ids``): what a greedy paged
+    engine hands its row sampler in place of fetched logits.  A greedy
+    ``sample_from_logits`` takes ``ids`` and reads no logits.  A slice of
+    rows is a ``DeviceRows`` of those rows; any other read (another index,
+    ``np.asarray``) calls ``fetch`` once for the float32 logits and reads
+    them."""
+
+    def __init__(self, ids: np.ndarray, fetch):
+        self.ids = ids                  # (N,) int32
+        self._fetch = fetch
+        self._values: Optional[np.ndarray] = None
+
+    def values(self) -> np.ndarray:
+        if self._values is None:
+            self._values = self._fetch()
+        return self._values
+
+    def __getitem__(self, key):
+        if isinstance(key, slice):
+            return DeviceRows(self.ids[key], lambda: self.values()[key])
+        return self.values()[key]
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.values(), dtype)
+
+
+def sample_from_logits(logits, cfg: SamplerConfig, vocab_size: int,
+                       rng: np.random.RandomState):
+    """logits: (B, V_pad) float32, or a ``DeviceRows`` -> (B,) int32."""
+    if isinstance(logits, DeviceRows):
+        if cfg.temperature <= 0:
+            return logits.ids
+        logits = logits.values()
     lg = logits[:, :vocab_size].astype(np.float64)
     if cfg.temperature <= 0:
         return lg.argmax(axis=-1).astype(np.int32)

@@ -66,9 +66,10 @@ def layouts():
     split over a 2-device model axis (tp=2) give the one-device logits for
     every served token, for KV pages and SSM slabs (and, for the
     interleaved Nemotron cut, B/C groups and held experts split over the
-    model axis, by their width or whole).  Logits, not tokens:
+    model axis, by their width or whole).  Logits as well as tokens:
     a tiny random model's greedy tokens barely depend on what attention
-    reads."""
+    reads.  The greedy ids, picked on the device from logits whose
+    vocabulary tp=2 splits over the model axis, equal one device's."""
     m1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     m2 = make_mesh((2, 1), ("data", "model"))
     mt = make_mesh((1, 2), ("data", "model"))
@@ -93,13 +94,22 @@ def layouts():
         assert all(r.done for r in rs), [r.rid for r in rs if not r.done]
         assert all(st.routed > 0 for st in eng.stats.replicas), \
             "a replica served nothing"
-        return rows
+        # the engine is greedy: each token was picked on the device, and
+        # it is the argmax of the row the sampler read
+        toks = {r.rid: r.out_tokens for r in rs}
+        assert len(rows) == sum(map(len, toks.values())) > 0
+        assert eng.stats.device_sampled == len(rows)
+        for (rid, i), row in rows.items():
+            assert toks[rid][i] == int(np.argmax(row)), (rid, i)
+        return rows, toks
 
     def same(got, want, what):
-        assert got.keys() == want.keys(), what
-        for k in want:
-            np.testing.assert_allclose(got[k], want[k], atol=1e-4,
+        (rows, toks), (want_rows, want_toks) = got, want
+        assert rows.keys() == want_rows.keys(), what
+        for k in want_rows:
+            np.testing.assert_allclose(rows[k], want_rows[k], atol=1e-4,
                                        err_msg=f"{what} {k}")
+        assert toks == want_toks, f"{what}: greedy ids"
 
     # tp=2 is compared in float: int8 rounding can turn the psum's other
     # summation order into a different stored byte
@@ -126,7 +136,8 @@ def layouts():
             same(run(cfg, pe, mt, model.init_params(cfg, pe, seed=1,
                                                     mesh=mt), 1),
                  oracle, f"{name} {kvd}: tp=2, experts by expert parallelism")
-    print("paged-layouts OK: dp=4 on 2 shards and tp=2 == 1 device")
+    print("paged-layouts OK: dp=4 on 2 shards and tp=2 == 1 device, "
+          "logits and greedy ids")
     return 0
 
 

@@ -133,6 +133,9 @@ def test_tick_phases_and_request_stamps(mesh1, tiny):
         assert r.t_submit <= r.t_admitted <= r.t_first_token <= r.t_done
     assert sorted(set(calls)) == [0, 1]
     st = eng.stats
+    # a sampler at a temperature fetches every row to the host
+    assert st.device_sampled == 0
+    assert st.host_sampled == sum(len(r.out_tokens) for r in reqs) > 0
     phases = [getattr(st, f) for f in PHASE_COUNTERS.values()]
     assert all(p > 0 for p in phases), dict(zip(PHASE_COUNTERS, phases,
                                                 strict=True))

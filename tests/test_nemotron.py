@@ -198,6 +198,13 @@ def _serve(eng, prompts, max_new=5):
         eng.submit(r)
     eng.run()
     eng._sample_row = sample
+    # the greedy engine picks its tokens on the device and hands the row
+    # sampler rows it fetches only when read: every emitted token's row
+    # was read, and the token is its argmax
+    for r in reqs:
+        assert len(rows[r.rid]) == len(r.out_tokens) == max_new
+        assert r.out_tokens == [int(np.argmax(x[:eng.cfg.vocab_size]))
+                                for x in rows[r.rid]]
     return reqs, rows
 
 

@@ -252,3 +252,18 @@ def test_nemotron_cell_steps_fit_v5e(chip_mesh, kind):
     assert used < 0.75 * V5E_HBM_BYTES, used
     assert not _pool_sized(compiled.as_text(), t["cache"],
                            0.01 * cache_bytes)
+
+
+@pytest.mark.parametrize("rows, vocab, v_pad", [(128, 131072, 131072),
+                                                (6, 151936, 151936)])
+def test_greedy_ids_compile_for_v5e(one_chip, rows, vocab, v_pad):
+    """The greedy pick of the nemotron3-reason decode step (128 rows of
+    131,072) and of qwen3-docs (6 of 151,936) compiles for one v5e and
+    reduces the bf16 logits without a copy of them."""
+    x = jax.ShapeDtypeStruct((rows, v_pad), jnp.bfloat16, sharding=one_chip)
+    compiled = steps.greedy_ids.lower(x, vocab).compile()
+    mem = compiled.memory_analysis()
+    # int32 ids, padded at most to a tile of the output
+    assert rows * 4 <= mem.output_size_in_bytes <= max(rows * 4, 4096)
+    assert mem.temp_size_in_bytes < rows * v_pad * 2 // 4, \
+        mem.temp_size_in_bytes
