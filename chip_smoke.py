@@ -50,13 +50,13 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
-ONE_CHIP_ARGS = ["--arch", "qwen3-0.6b", "--paged", "--slots", "8",
+ONE_CHIP_ARGS = ["--arch", "qwen3-0.6b", "--slots", "8",
                  "--seq-budget", "2048", "--page-size", "16",
                  "--prefill-chunk", "128", "--kv-dtype", "fp16",
                  "--temperature", "0", "--seed", "0"]
 # the four-chip phases check the mesh paths, not capacity: fewer slots and
 # a shorter budget keep their compiles and runs short at full width
-FOUR_CHIP_ARGS = ["--arch", "qwen3-0.6b", "--paged", "--slots", "4",
+FOUR_CHIP_ARGS = ["--arch", "qwen3-0.6b", "--slots", "4",
                   "--seq-budget", "1024", "--page-size", "16",
                   "--prefill-chunk", "128", "--kv-dtype", "fp16",
                   "--temperature", "0", "--seed", "0"]

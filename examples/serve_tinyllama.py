@@ -4,9 +4,9 @@
     PYTHONPATH=src python examples/serve_tinyllama.py [--full]
 
 ``--full`` uses the real 42M config (slower on CPU); default is the reduced
-smoke model.  Demonstrates prefill->slot splice->fused batch decode, greedy
-sampling, TTFT/TPOT reporting — the autoregressive mode the paper
-accelerates 26.1x.
+smoke model.  Demonstrates chunked prefill into paged KV -> fused batch
+decode, greedy sampling, TTFT/TPOT reporting — the autoregressive mode the
+paper accelerates 26.1x.
 """
 import sys
 

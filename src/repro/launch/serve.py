@@ -3,13 +3,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-42m --smoke \
         --requests 16 --slots 4 --max-new 16
 
-Builds the prefill/decode steps for a host mesh, spins up the
-continuous-batching engine, pushes synthetic requests, and reports
-TTFT / per-token latency / throughput.
+Builds the paged continuous-batching engine for a host mesh, pushes
+synthetic requests, and reports TTFT / per-token latency / throughput.
 
 ``--arch`` accepts any registered decode-capable config
 (``repro.configs``), including hybrid/SSM archs (e.g. ``hymba-1.5b``,
-``mamba2-370m`` — paged serving gives them per-request recurrent-state
+``mamba2-370m`` — the engine gives them per-request recurrent-state
 slabs) and encoder-decoder archs (e.g. ``seamless-m4t-large-v2`` — the
 launcher synthesizes encoder frame embeddings per request;
 ``--frame-groups K`` spreads requests over K distinct frame tensors so
@@ -23,18 +22,18 @@ enc-dec is automatic instead).
 Scheduling policy is selected with ``--policy {fcfs,priority,fair}``;
 ``--policy priority --preemption`` additionally evicts low-priority slots
 when urgent requests arrive, and ``--policy fair --preemption`` enables
-preemptive DRR (paged engine only; see README §Serving).
+preemptive DRR (see README §Serving).
 ``--high-priority-every N`` marks every Nth request urgent and the report
 then splits TTFT per class; ``--clients N`` spreads requests across N
 client ids for the fair policy.
 
-``--speculative K`` (paged engine, attention-only archs) turns on
+``--speculative K`` (attention-only archs) turns on
 speculative decoding: prompt-lookup self-drafts of up to K tokens are
 verified in one fused K+1-position step per tick, outputs stay
 token-identical to the one-token path, and the report adds
 accepted-tokens/tick and the draft hit rate.
 
-``--dp N`` (paged engine only) runs N data-parallel replicas, each with
+``--dp N`` runs N data-parallel replicas, each with
 ``--slots`` slots and its own replica-local page pool / prefix cache /
 scheduler; a router assigns requests by prefix affinity then page load,
 and the report splits stats per replica.  Replicas shard over the mesh's
@@ -44,13 +43,13 @@ data axis when enough devices exist (they co-locate otherwise).
 prefill from decode: P replicas chunk-prefill fresh requests and hand
 each finished KV page run to one of D decode replicas through the
 compiled page-transfer step, so long prefills never steal decode ticks
-(README §Disaggregated serving).  The paged engine plans one tick ahead
+(README §Disaggregated serving).  The engine plans one tick ahead
 by default (``--overlap``); ``--no-overlap`` restores the serial
 plan-dispatch-collect loop for debugging — outputs are token-identical
 either way, and the report adds the device-busy fraction plus plan-ahead
 / invalidation counts.
 
-``--scale-events T:N[,T:N...]`` (paged engine, no ``--disagg``) replays
+``--scale-events T:N[,T:N...]`` (no ``--disagg``) replays
 an elastic membership schedule under the live load: at tick T the engine
 scales to N replicas (``scale_to`` — leaving replicas drain by migrating
 their in-flight KV page runs to survivors), and a ``T:kill:R`` entry
@@ -76,9 +75,9 @@ def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     help="registered config id (repro.configs): dense/MoE "
-                         "decoders, hybrid/SSM (paged: recurrent-state "
-                         "slabs), enc-dec (paged: cross-KV pages + "
-                         "shared-frame encode reuse)")
+                         "decoders, hybrid/SSM (recurrent-state slabs), "
+                         "enc-dec (cross-KV pages + shared-frame encode "
+                         "reuse)")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
@@ -88,27 +87,24 @@ def parse_args(argv=None):
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--dp", type=int, default=1,
                     help="data-parallel replicas with replica-local page "
-                         "pools and a prefix-affinity router (implies "
-                         "--paged)")
+                         "pools and a prefix-affinity router")
     ap.add_argument("--disagg", default=None, metavar="P:D",
                     help="disaggregate prefill from decode: P prefill "
                          "replicas hand finished page runs to D decode "
                          "replicas via the compiled page-transfer step "
                          "(requires --dp P+D)")
     ap.add_argument("--scale-events", default=None, metavar="T:N[,T:N...]",
-                    help="elastic membership schedule (paged engine, no "
-                         "--disagg): at tick T scale to N replicas; a "
+                    help="elastic membership schedule (no --disagg): at "
+                         "tick T scale to N replicas; a "
                          "'T:kill:R' entry injects a replica-R failure "
                          "instead (e.g. 8:1,12:kill:0,16:2)")
     ap.add_argument("--overlap", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="plan tick t+1 while tick t's steps run on device "
-                         "(paged engine; --no-overlap restores the serial "
-                         "loop, token-identical either way)")
+                         "(--no-overlap restores the serial loop, "
+                         "token-identical either way)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV-cache + chunked prefill (README §Serving)")
     ap.add_argument("--kv-dtype", choices=("fp32", "fp16", "int8"),
                     default="fp16",
                     help="paged-pool storage dtype; int8 quantizes every "
@@ -120,13 +116,12 @@ def parse_args(argv=None):
     ap.add_argument("--n-pages", type=int, default=0,
                     help="page pool size (0 = full occupancy + scratch)")
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="radix prefix sharing with copy-on-write pages "
-                         "(implies --paged)")
+                    help="radix prefix sharing with copy-on-write pages")
     ap.add_argument("--speculative", type=int, default=0, metavar="K",
                     help="speculative decoding: prompt-lookup self-drafts "
                          "of up to K tokens verified in one fused step "
-                         "(attention-only archs; implies --paged; outputs "
-                         "stay token-identical)")
+                         "(attention-only archs; outputs stay "
+                         "token-identical)")
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="prepend a common system-prompt prefix of this "
                          "many tokens to every request")
@@ -138,7 +133,7 @@ def parse_args(argv=None):
                     default="fcfs", help="admission policy (serving.policies)")
     ap.add_argument("--preemption", action="store_true",
                     help="evict low-priority slots for urgent arrivals "
-                         "(requires --policy priority and a paged engine)")
+                         "(requires --policy priority or fair)")
     ap.add_argument("--high-priority-every", type=int, default=0,
                     metavar="N", help="every Nth request gets priority 10 "
                                       "(0 = uniform priority)")
@@ -150,9 +145,6 @@ def parse_args(argv=None):
                  "--seq-budget")
     if args.preemption and args.policy not in ("priority", "fair"):
         ap.error("--preemption requires --policy priority or fair")
-    if args.preemption and not (args.paged or args.prefix_cache
-                                or args.dp > 1):
-        ap.error("--preemption requires the paged engine (--paged)")
     if args.dp < 1:
         ap.error("--dp must be >= 1")
     if args.speculative < 0:
@@ -169,9 +161,6 @@ def parse_args(argv=None):
         disagg = (p, d)
     scale_events = []
     if args.scale_events:
-        if not (args.paged or args.prefix_cache or args.dp > 1
-                or args.speculative):
-            ap.error("--scale-events requires the paged engine (--paged)")
         if disagg is not None:
             ap.error("--scale-events cannot combine with --disagg "
                      "(role sets are static)")
@@ -201,8 +190,7 @@ def build_engine(args):
     build through here."""
     import jax
     from repro.configs import get_config, reduced
-    from repro.configs.base import ShapeConfig
-    from repro.core import model, steps
+    from repro.core import model
     from repro.core.partition import ShardingPlan
     from repro.launch.mesh import host_mesh
     from repro.serving import (FairScheduler, PriorityScheduler,
@@ -232,38 +220,22 @@ def build_engine(args):
                                       preemption=args.preemption)
 
     sampler = SamplerConfig(temperature=args.temperature, top_k=40)
-    if args.paged or args.prefix_cache or args.dp > 1 or args.speculative:
-        engine = ServingEngine.build_paged(
-            cfg, plan, mesh, args.slots, args.seq_budget, params,
-            page_size=args.page_size, n_pages=args.n_pages,
-            prefill_chunk=args.prefill_chunk, sampler=sampler,
-            prefix_cache=args.prefix_cache, scheduler=scheduler,
-            rng_seed=args.seed, dp=args.dp, speculative=args.speculative,
-            overlap=args.overlap, disagg=args.disagg)
-        if args.scale_events:
-            def membership_hook(e, _pending=list(args.scale_events)):
-                while _pending and e.stats.ticks >= _pending[0][0]:
-                    _, kind, val = _pending.pop(0)
-                    if kind == "scale":
-                        e.scale_to(val)
-                    else:
-                        e.kill_replica(val)
-            engine.membership_hook = membership_hook
-    else:
-        dshape = ShapeConfig("serve", "decode", args.seq_budget, args.slots)
-        pshape = ShapeConfig("serve1", "decode", args.seq_budget, 1)
-        decode_fn, _, _ = steps.make_decode_step(cfg, plan, mesh, dshape)
-        prefill_fn, _, _ = steps.make_prefill_step(cfg, plan, mesh, pshape)
-        # donate the lane/engine cache: it is reassigned from the return at
-        # every call site (cache arg trails the prompt; enc-dec adds frames)
-        cache_arg = 3 if cfg.is_encdec else 2
-        engine = ServingEngine(cfg, plan, mesh, args.slots, args.seq_budget,
-                               params,
-                               jax.jit(prefill_fn,
-                                       donate_argnums=(cache_arg,)),
-                               jax.jit(decode_fn, donate_argnums=(1,)),
-                               sampler=sampler,
-                               scheduler=scheduler, rng_seed=args.seed)
+    engine = ServingEngine.build_paged(
+        cfg, plan, mesh, args.slots, args.seq_budget, params,
+        page_size=args.page_size, n_pages=args.n_pages,
+        prefill_chunk=args.prefill_chunk, sampler=sampler,
+        prefix_cache=args.prefix_cache, scheduler=scheduler,
+        rng_seed=args.seed, dp=args.dp, speculative=args.speculative,
+        overlap=args.overlap, disagg=args.disagg)
+    if args.scale_events:
+        def membership_hook(e, _pending=list(args.scale_events)):
+            while _pending and e.stats.ticks >= _pending[0][0]:
+                _, kind, val = _pending.pop(0)
+                if kind == "scale":
+                    e.scale_to(val)
+                else:
+                    e.kill_replica(val)
+        engine.membership_hook = membership_hook
     return engine
 
 
@@ -309,18 +281,17 @@ def main(argv=None):
               f"tpot_p50={np.median(stats.tpot_s) * 1e3:.1f}ms")
     else:
         print("no tokens emitted")
-    if engine.paged:
-        per_tick = 1e3 / max(stats.ticks, 1)
-        print(f"pipeline: overlap={'on' if engine.overlap else 'off'} "
-              f"plan_ahead_ticks={stats.plan_ahead_ticks} "
-              f"plan_invalidations={stats.plan_invalidations} "
-              f"collect_wait={stats.collect_wait_s * 1e3:.1f}ms")
-        print(f"host per tick: plan={stats.plan_s * per_tick:.2f}ms "
-              f"wait={stats.collect_wait_s * per_tick:.2f}ms "
-              f"sample={stats.sample_s * per_tick:.2f}ms "
-              f"dispatch={stats.dispatch_s * per_tick:.2f}ms")
-        print(f"sampled: device={stats.device_sampled} "
-              f"host={stats.host_sampled}")
+    per_tick = 1e3 / max(stats.ticks, 1)
+    print(f"pipeline: overlap={'on' if engine.overlap else 'off'} "
+          f"plan_ahead_ticks={stats.plan_ahead_ticks} "
+          f"plan_invalidations={stats.plan_invalidations} "
+          f"collect_wait={stats.collect_wait_s * 1e3:.1f}ms")
+    print(f"host per tick: plan={stats.plan_s * per_tick:.2f}ms "
+          f"wait={stats.collect_wait_s * per_tick:.2f}ms "
+          f"sample={stats.sample_s * per_tick:.2f}ms "
+          f"dispatch={stats.dispatch_s * per_tick:.2f}ms")
+    print(f"sampled: device={stats.device_sampled} "
+          f"host={stats.host_sampled}")
     if disagg is not None:
         print(f"disagg(P={disagg[0]} D={disagg[1]}): "
               f"handoffs={stats.handoffs} "

@@ -14,23 +14,18 @@ behind the ``Scheduler`` interface; the engine executes the scheduler's
 ``Admission`` decisions (and preemption verdicts) and reports lifecycle
 events back.
 
-Two cache disciplines, selected by the ``paged`` flag:
+One cache discipline: K/V live in fixed pools of fixed-size pages
+(``core.kvcache``).  Admission allocates the slot's block table up front
+(prompt + max_new_tokens worth — all-or-nothing, so requests queue instead
+of OOMing mid-flight), prefill advances one fixed-size chunk per tick
+interleaved with decode, and completion returns the pages to the pool.
+One compiled (chunk, decode) pair serves every prompt-length mix.  With
+``prefix_cache=True`` a radix tree maps cached prompt prefixes to
+refcounted page runs: admission starts prefill at the first uncached
+token, copying partially-shared pages copy-on-write
+(``serving.prefix_cache``).
 
-* **contiguous** (reference oracle): each slot owns an exact-length cache
-  lane; admission prefills the whole prompt in one step (recompiling per
-  prompt length) and splices the lane in.
-* **paged**: K/V live in fixed pools of fixed-size pages
-  (``core.kvcache``); admission allocates the slot's block table up front
-  (prompt + max_new_tokens worth — all-or-nothing, so requests queue
-  instead of OOMing mid-flight), prefill advances one fixed-size chunk per
-  tick interleaved with decode, and completion returns the pages to the
-  pool.  One compiled (chunk, decode) pair serves every prompt-length mix.
-  With ``prefix_cache=True`` a radix tree maps cached prompt prefixes to
-  refcounted page runs: admission starts prefill at the first uncached
-  token, copying partially-shared pages copy-on-write
-  (``serving.prefix_cache``).
-
-**Architecture coverage** (paged engine): beyond attention-only decoders,
+**Architecture coverage**: beyond attention-only decoders,
 
 * *SSM/hybrid* archs get one recurrent-state **slab** per admitted request
   (``SlabAllocator``; slab 0 is scratch, mirroring page 0): SSM layers
@@ -56,7 +51,7 @@ Admission budgets pages + slabs + cross pages JOINTLY (all-or-nothing),
 and ``drain()`` leak-freedom extends to all three: after every admission
 retires, each replica's pages are free or cache-held and its slabs free.
 
-**Data parallelism** (``dp`` — paged engine only): the engine runs ``dp``
+**Data parallelism** (``dp``): the engine runs ``dp``
 *replicas*, each with its own ``batch_slots`` slots and — crucially — its
 own replica-local ``PageAllocator``, ``RadixPrefixCache`` and
 ``Scheduler`` instance, so page refcounts, prefix pins, eviction and
@@ -70,7 +65,7 @@ replicas' slots through one compiled decode step per tick; per-replica
 counters land in ``EngineStats.replicas``.  ``dp=1`` (the default) is the
 old single-pool engine, token-for-token.
 
-**Speculative decoding** (``speculative=k`` — paged, attention-only archs):
+**Speculative decoding** (``speculative=k`` — attention-only archs):
 each tick a self-drafting source (``serving.prefix_cache.PromptLookupDraft``
 — prompt-lookup n-grams over the slot's own context and the radix cache's
 token paths; no second model) proposes up to k tokens per slot, and ONE
@@ -89,7 +84,7 @@ drafts keep getting rejected stops drafting and returns the headroom pages
 (``Scheduler.on_spec_trim`` — a refcount trim, safe against pages shared
 with the prefix cache).
 
-**Pipelined execution** (paged engine; ``overlap=True``, the default):
+**Pipelined execution** (``overlap=True``, the default):
 each tick splits into three phases — **plan** (host: preemption verdicts,
 admissions, COW/cross/handoff planning), **collect** (the tick's single
 barrier: one batched ``jax.device_get`` over every in-flight handle, then
@@ -136,7 +131,7 @@ Machine-checked clauses (scripts/check_static.py):
 
 Invariant: one compiled (chunk, decode, verify) step set serves every
     request mix — request lengths flow in as data, never as traced
-    shapes, so the paged hot loop triggers zero recompiles after tick 1.
+    shapes, so the hot loop triggers zero recompiles after tick 1.
 Enforced-by: analysis:jit-stability, analysis:traced-shape
 
 Invariant: the per-tick path reads device values only through the single
@@ -185,7 +180,7 @@ import numpy as np
 from repro.core.kvcache import (SCRATCH_PAGE, SCRATCH_SLAB, PageAllocator,
                                 SlabAllocator, cache_profile,
                                 kv_pool_is_quantized, page_rows,
-                                pages_needed)
+                                paged_cache_supported, pages_needed)
 from repro.serving.prefix_cache import (CrossKVCache, HostSpillStore,
                                         PromptLookupDraft, RadixPrefixCache)
 from repro.serving.router import Router
@@ -365,59 +360,53 @@ class EngineStats:
 
 
 class ServingEngine:
+    """The paged serving engine (module docstring).  ``build_paged`` is the
+    public constructor; both take the same arguments."""
+
     def __init__(self, cfg, plan, mesh, batch_slots: int, seq_budget: int,
-                 params, prefill_fn, decode_fn, eos_id: int = 1,
-                 sampler: Optional[SamplerConfig] = None, *,
-                 paged: bool = False, page_size: int = 16,
-                 n_pages: int = 0, prefill_chunk: int = 0,
+                 params, *, page_size: int = 16, n_pages: int = 0,
+                 prefill_chunk: int = 16, eos_id: int = 1,
+                 sampler: Optional[SamplerConfig] = None,
                  prefix_cache: bool = False, scheduler=None,
                  rng_seed: int = 0, dp: int = 1, n_slabs: int = 0,
-                 speculative: int = 0, verify_fn=None,
-                 overlap: bool = True, disagg=None, transfer_fn=None,
-                 spill=None):
+                 speculative: int = 0, overlap: bool = True,
+                 disagg=None, spill=None):
         from repro.core import steps as _steps
         self.cfg, self.plan, self.mesh = cfg, plan, mesh
         assert dp >= 1, dp
-        assert paged or dp == 1, "dp>1 serving requires the paged engine"
+        ok, why = paged_cache_supported(cfg)
+        if not ok:
+            raise ValueError(
+                f"paged serving unsupported for arch '{cfg.name}': {why}")
         self.R = dp                    # data-parallel replicas
         self.Bp = batch_slots          # slots per replica
         self.B = batch_slots * dp      # global slots (the decode batch)
         self.S = seq_budget
         self.params = params
-        self.prefill_fn = prefill_fn   # jitted: batch=1 lane / paged chunks
-        self.decode_fn = decode_fn     # jitted, batch=R*Bp
         self.eos = eos_id
         self.sampler = sampler or SamplerConfig()
         self.admissions: List[Optional[Admission]] = [None] * self.B
         self.pos = np.zeros(self.B, np.int32)
         self.last_token = np.zeros(self.B, np.int32)
-        self.paged = paged
         self.stats = EngineStats(replicas=[ReplicaStats()
                                            for _ in range(self.R)])
-        self.allocators: List[PageAllocator] = []
-        self.prefix_caches: List[Optional[RadixPrefixCache]] = []
-        self.slab_allocators: List[SlabAllocator] = []
-        self.cross_caches: List[Optional[CrossKVCache]] = []
-        self.router: Optional[Router] = None
         prof = cache_profile(cfg)
-        self.has_ssm = paged and "ssm" in prof
-        self.has_cross = paged and "cross_kv" in prof
+        self.has_ssm = "ssm" in prof
+        self.has_cross = "cross_kv" in prof
+        self.kv_pages = "kv" in prof
         # int8 page pools carry per-(page, slot) scale tensors whose rows
         # must be invalidated when a page is recycled (see _plan_admissions)
-        self.quant_pools = paged and kv_pool_is_quantized(plan) and \
+        self.quant_pools = kv_pool_is_quantized(plan) and \
             ("kv" in prof or "cross_kv" in prof)
         from repro.core.model import counts_expert_picks
-        self.counts_picks = paged and counts_expert_picks(cfg)
+        self.counts_picks = counts_expert_picks(cfg)
         self._logits_host = {}          # shape -> float32 buffer, reused
         self._prompt_picks: dict = {}  # rid -> picks of its prompt chunks
-        self.overlap = bool(overlap) and paged
+        self.overlap = bool(overlap)
         self.disagg = None
         self.roles: Optional[List[str]] = None
         if disagg is not None:
             p_reps, d_reps = int(disagg[0]), int(disagg[1])
-            if not paged:
-                raise ValueError(
-                    "disaggregated serving requires the paged engine")
             if p_reps < 1 or d_reps < 1 or p_reps + d_reps != dp:
                 raise ValueError(
                     f"disagg {p_reps}:{d_reps} must cover every replica "
@@ -432,66 +421,55 @@ class ServingEngine:
                     f"not hand off")
             self.disagg = (p_reps, d_reps)
             self.roles = ["prefill"] * p_reps + ["decode"] * d_reps
-        if paged:
-            from repro.core.kvcache import paged_cache_supported
-            ok, why = paged_cache_supported(cfg)
-            if not ok:
-                raise ValueError(
-                    f"paged serving unsupported for arch '{cfg.name}': {why}")
-            if prefix_cache and self.has_ssm:
-                raise ValueError(
-                    f"prefix_cache=True is unsupported for arch "
-                    f"'{cfg.name}': its SSM layers hold recurrent state "
-                    f"that a token-id prefix cannot address (cache kinds "
-                    f"{sorted(prof)}); run it paged without the prefix "
-                    f"cache")
-            if prefix_cache and self.has_cross:
-                raise ValueError(
-                    f"prefix_cache=True is unsupported for arch "
-                    f"'{cfg.name}': decoder self-KV depends on the "
-                    f"request's encoder frames through cross-attention, "
-                    f"so equal token prefixes do not imply equal KV; "
-                    f"cross-KV sharing is keyed by frames digest instead "
-                    f"(automatic)")
-            assert seq_budget % page_size == 0, (seq_budget, page_size)
-            assert prefill_chunk > 0 and seq_budget % prefill_chunk == 0, \
-                (seq_budget, prefill_chunk)
-            self.page_size = page_size
-            self.chunk = prefill_chunk
-            self.n_max_pages = seq_budget // page_size
-            self.n_slabs = n_slabs or batch_slots + 1
-            self.n_pool_pages = n_pages        # per-replica pool size
-            self._prefix_cache_enabled = bool(prefix_cache)
-            self.n_cross_pages = pages_needed(cfg.enc_seq_len, page_size) \
-                if self.has_cross else 0
-            # replica-local pools: refcounts never cross a replica boundary
-            self.allocators = [PageAllocator(n_pages) for _ in range(dp)]
-            self.prefix_caches = [
-                RadixPrefixCache(a, page_size) if prefix_cache else None
-                for a in self.allocators]
-            self.slab_allocators = [SlabAllocator(self.n_slabs)
-                                    for _ in range(dp)] if self.has_ssm \
-                else []
-            self.cross_caches = [CrossKVCache(a) for a in self.allocators] \
-                if self.has_cross else []
-            self.slot_state: List[Optional[str]] = [None] * self.B
-            self.prefill_done = np.zeros(self.B, np.int32)
-            self._stash: dict = {}     # rid -> preempted SSM checkpoint
-            self.cache = _steps.zero_paged_cache_for(
-                cfg, plan, mesh, n_pages, page_size, n_replicas=dp,
-                n_slabs=self.n_slabs if self.has_ssm else 0)
-        else:
-            assert not prefix_cache, "prefix cache requires the paged engine"
-            self.cache = _steps.zero_cache_for(cfg, plan, mesh, batch_slots,
-                                               seq_budget)
+        if prefix_cache and self.has_ssm:
+            raise ValueError(
+                f"prefix_cache=True is unsupported for arch "
+                f"'{cfg.name}': its SSM layers hold recurrent state "
+                f"that a token-id prefix cannot address (cache kinds "
+                f"{sorted(prof)}); run it paged without the prefix "
+                f"cache")
+        if prefix_cache and self.has_cross:
+            raise ValueError(
+                f"prefix_cache=True is unsupported for arch "
+                f"'{cfg.name}': decoder self-KV depends on the "
+                f"request's encoder frames through cross-attention, "
+                f"so equal token prefixes do not imply equal KV; "
+                f"cross-KV sharing is keyed by frames digest instead "
+                f"(automatic)")
+        assert seq_budget % page_size == 0, (seq_budget, page_size)
+        assert prefill_chunk > 0 and seq_budget % prefill_chunk == 0, \
+            (seq_budget, prefill_chunk)
+        self.page_size = page_size
+        self.chunk = prefill_chunk
+        self.n_max_pages = seq_budget // page_size
+        self.n_cross_pages = pages_needed(cfg.enc_seq_len, page_size) \
+            if self.has_cross else 0
+        # per-replica pool: by default every slot at budget (plus its
+        # cross-KV pages) and the scratch page
+        n_pages = n_pages or \
+            batch_slots * (self.n_max_pages + self.n_cross_pages) + 1
+        self.n_slabs = n_slabs or batch_slots + 1
+        self.n_pool_pages = n_pages
+        self._prefix_cache_enabled = bool(prefix_cache)
+        # replica-local pools: refcounts never cross a replica boundary
+        self.allocators = [PageAllocator(n_pages) for _ in range(dp)]
+        self.prefix_caches: List[Optional[RadixPrefixCache]] = [
+            RadixPrefixCache(a, page_size) if prefix_cache else None
+            for a in self.allocators]
+        self.slab_allocators = [SlabAllocator(self.n_slabs)
+                                for _ in range(dp)] if self.has_ssm else []
+        self.cross_caches = [CrossKVCache(a) for a in self.allocators] \
+            if self.has_cross else []
+        self.slot_state: List[Optional[str]] = [None] * self.B
+        self.prefill_done = np.zeros(self.B, np.int32)
+        self._stash: dict = {}         # rid -> preempted SSM checkpoint
+        self.cache = _steps.zero_paged_cache_for(
+            cfg, plan, mesh, n_pages, page_size, n_replicas=dp,
+            n_slabs=self.n_slabs if self.has_ssm else 0)
         self.speculative = int(speculative)
-        self.verify_fn = verify_fn
         self.draft_sources: List[PromptLookupDraft] = []
         self.spec_miss = np.zeros(self.B, np.int32)
         if self.speculative > 0:
-            if not paged:
-                raise ValueError(
-                    "speculative decoding requires the paged engine")
             if prof != {"kv"}:
                 raise ValueError(
                     f"speculative decoding is unsupported for arch "
@@ -501,13 +479,10 @@ class ServingEngine:
                     f"enc-dec verify is not implemented")
             self.draft_sources = [PromptLookupDraft(self.prefix_caches[r])
                                   for r in range(dp)]
-        if paged:
-            # compiled steps come from the memoized per-shape step set
-            # (steps.paged_step_set): repeated engine builds and elastic
-            # membership changes reuse XLA executables instead of
-            # recompiling.  Explicitly passed functions win.
-            self._wire_steps(prefill_fn=prefill_fn, decode_fn=decode_fn,
-                             verify_fn=verify_fn, transfer_fn=transfer_fn)
+        # compiled steps come from the memoized per-shape step set
+        # (steps.paged_step_set): repeated engine builds and elastic
+        # membership changes reuse XLA executables instead of recompiling
+        self._wire_steps()
         # ``scheduler`` is either a ready instance (dp=1 only) or a factory
         # (a Scheduler subclass / functools.partial): factories receive the
         # engine-owned shared state, so callers can pass e.g.
@@ -521,24 +496,12 @@ class ServingEngine:
             assert self.disagg is None, \
                 "disaggregation needs a scheduler factory, not an instance"
             self.scheds = [sched]
+            self._sched_factory = None
         else:
-            self.scheds = [
-                sched(seq_budget=seq_budget,
-                      allocator=self.allocators[r] if paged else None,
-                      page_size=page_size if paged else 0,
-                      prefix_cache=self.prefix_caches[r] if paged else None,
-                      slab_allocator=(self.slab_allocators[r]
-                                      if self.has_ssm else None),
-                      cross_cache=(self.cross_caches[r]
-                                   if self.has_cross else None),
-                      cross_pages_per_req=(self.n_cross_pages
-                                           if self.has_cross else 0),
-                      kv_pages=not paged or "kv" in prof,
-                      spec_tokens=self.speculative if paged else 0,
-                      stats=self.stats,
-                      **({"role": self.roles[r]}
-                         if self.roles is not None else {}))
-                for r in range(dp)]
+            # elastic membership: scale_to/kill_replica need the factory
+            # to build schedulers for joined replicas
+            self._sched_factory = sched
+            self.scheds = [self._new_sched(r) for r in range(dp)]
         for r, s in enumerate(self.scheds):
             # per-replica counters update at the scheduler's single
             # counting site, alongside the global stats
@@ -547,11 +510,10 @@ class ServingEngine:
         if self.roles is not None:
             for r, ro in enumerate(self.roles):
                 self.stats.replicas[r].role = ro
-        if paged:
-            self.router = Router(self.scheds, self.allocators,
-                                 self.prefix_caches, page_size,
-                                 cross_caches=self.cross_caches or None,
-                                 roles=self.roles)
+        self.router = Router(self.scheds, self.allocators,
+                             self.prefix_caches, page_size,
+                             cross_caches=self.cross_caches or None,
+                             roles=self.roles)
         self._rids: set = set()
         self.rng_seed = rng_seed
         # pipelined execution state: results of the previous dispatch phase
@@ -559,13 +521,11 @@ class ServingEngine:
         # prefill-role slots whose finished page runs await a decode home
         self._inflight: Optional[dict] = None
         self._pending_handoffs: List[int] = []
-        # elastic membership: scale_to/kill_replica need the factory to
-        # build schedulers for joined replicas; a hook installed here fires
-        # at the top of every paged tick (fault injection, ops triggers)
-        self._sched_factory = None if isinstance(sched, Scheduler) else sched
+        # a hook installed here fires at the top of every tick (fault
+        # injection, ops triggers)
         self.membership_hook = None
         self.spill = spill
-        if paged and spill is not None:
+        if spill is not None:
             self._restore_from_spill(spill)
 
     @classmethod
@@ -577,10 +537,10 @@ class ServingEngine:
                     rng_seed: int = 0, dp: int = 1, n_slabs: int = 0,
                     speculative: int = 0, overlap: bool = True,
                     disagg=None, spill=None):
-        """Construct a paged engine; its compiled (chunk, decode) pair
-        (plus the cross-KV write step for enc-dec archs, the k+1-token
-        verify step when ``speculative=k`` > 0, and the page-transfer step
-        for dp>1 attention-only configs) comes from the memoized per-shape
+        """Construct an engine; its compiled (chunk, decode) pair (plus
+        the cross-KV write step for enc-dec archs, the k+1-token verify
+        step when ``speculative=k`` > 0, and the page-transfer step for
+        dp>1 attention-only configs) comes from the memoized per-shape
         step set, so repeated builds reuse XLA executables.
 
         ``n_pages`` is the PER-REPLICA pool size and defaults to full
@@ -592,25 +552,13 @@ class ServingEngine:
         slots and their own pool, driven together by one compiled step
         pair.  ``spill`` (a ``HostSpillStore``) warm-starts the prefix /
         cross caches from a previous engine's spilled page payloads."""
-        from repro.core import steps as _steps
-        from repro.core.kvcache import paged_cache_supported
-        ok, why = paged_cache_supported(cfg)
-        if not ok:
-            raise ValueError(
-                f"paged serving unsupported for arch '{cfg.name}': {why}")
-        has_ssm, has_cross = _steps.paged_extra_inputs(cfg)
-        n_max = seq_budget // page_size
-        n_cross = pages_needed(cfg.enc_seq_len, page_size) if has_cross else 0
-        n_pages = n_pages or batch_slots * (n_max + n_cross) + 1
-        n_slabs = n_slabs or batch_slots + 1
         return cls(cfg, plan, mesh, batch_slots, seq_budget, params,
-                   None, None, eos_id=eos_id,
-                   sampler=sampler, paged=True, page_size=page_size,
-                   n_pages=n_pages, prefill_chunk=prefill_chunk,
-                   prefix_cache=prefix_cache, scheduler=scheduler,
-                   rng_seed=rng_seed, dp=dp, n_slabs=n_slabs,
-                   speculative=speculative, overlap=overlap, disagg=disagg,
-                   spill=spill)
+                   page_size=page_size, n_pages=n_pages,
+                   prefill_chunk=prefill_chunk, eos_id=eos_id,
+                   sampler=sampler, prefix_cache=prefix_cache,
+                   scheduler=scheduler, rng_seed=rng_seed, dp=dp,
+                   n_slabs=n_slabs, speculative=speculative,
+                   overlap=overlap, disagg=disagg, spill=spill)
 
     # ------------------------------------------------------------------ API
     @property
@@ -622,16 +570,12 @@ class ServingEngine:
     @property
     def allocator(self):
         """The single allocator (dp=1 compatibility accessor)."""
-        if not self.paged:
-            return None
         assert self.R == 1, "dp>1: use engine.allocators[r]"
         return self.allocators[0]
 
     @property
     def prefix_cache(self):
         """The single prefix cache (dp=1 compatibility accessor)."""
-        if not self.paged:
-            return None
         assert self.R == 1, "dp>1: use engine.prefix_caches[r]"
         return self.prefix_caches[0]
 
@@ -648,12 +592,10 @@ class ServingEngine:
         """Replica-local slot index -> global slot index."""
         return r * self.Bp + local
 
-    def _wire_steps(self, prefill_fn=None, decode_fn=None, verify_fn=None,
-                    transfer_fn=None):
-        """(Re)wire the paged engine's compiled steps from the memoized
-        per-shape step set for the CURRENT replica count ``self.R`` —
-        called at construction and again after every membership change.
-        Explicitly passed functions win over the set's entries."""
+    def _wire_steps(self):
+        """(Re)wire the compiled steps from the memoized per-shape step set
+        for the CURRENT replica count ``self.R`` — called at construction
+        and again after every membership change."""
         from repro.core import steps as _steps
         sset = _steps.paged_step_set(
             self.cfg, self.plan, self.mesh, self.Bp, self.n_pool_pages,
@@ -661,13 +603,26 @@ class ServingEngine:
             n_replicas=self.R,
             n_slabs=self.n_slabs if self.has_ssm else 0,
             speculative=self.speculative)
-        self.prefill_fn = prefill_fn or sset["prefill"]
-        self.decode_fn = decode_fn or sset["decode"]
+        self.prefill_fn = sset["prefill"]
+        self.decode_fn = sset["decode"]
         self.copy_fn = sset["copy"]    # COW only exists with self-KV pools
-        if self.has_cross:
-            self.cross_write_fn = sset["cross_write"]
-        self.verify_fn = verify_fn or sset["verify"]
-        self.transfer_fn = transfer_fn or sset["transfer"]
+        self.cross_write_fn = sset["cross_write"]
+        self.verify_fn = sset["verify"]
+        self.transfer_fn = sset["transfer"]
+
+    def _new_sched(self, r: int):
+        """Replica ``r``'s scheduler, built by the factory over the
+        replica's own allocator, caches and slab pool."""
+        return self._sched_factory(
+            seq_budget=self.S, allocator=self.allocators[r],
+            page_size=self.page_size, prefix_cache=self.prefix_caches[r],
+            slab_allocator=(self.slab_allocators[r]
+                            if self.has_ssm else None),
+            cross_cache=self.cross_caches[r] if self.has_cross else None,
+            cross_pages_per_req=self.n_cross_pages,
+            kv_pages=self.kv_pages, spec_tokens=self.speculative,
+            stats=self.stats,
+            **({"role": self.roles[r]} if self.roles is not None else {}))
 
     # ------------------------------------------------- cache-tree plumbing
     def _kind_leaves(self, kind: str):
@@ -795,10 +750,9 @@ class ServingEngine:
                     f"request {req.rid} needs {need} pages to decode but "
                     f"the largest decode-replica pool has only {usable} "
                     f"usable pages — it could prefill but never hand off")
-        r = self.router.route(req) if self.router is not None else 0
+        r = self.router.route(req)
         self.scheds[r].submit(req)    # raises on infeasible requests
-        if self.router is not None:
-            self.router.commit(req, r)
+        self.router.commit(req, r)
         req.replica = r
         self.stats.replicas[r].routed += 1
         self._rids.add(req.rid)
@@ -848,8 +802,7 @@ class ServingEngine:
             self.scheds[self._rep(b)].on_finish(adm)
             self._clear_slot(b)
             n += 1
-        if self.paged:
-            self._stash.clear()
+        self._stash.clear()
         return n
 
     def preempt(self, b: int):
@@ -862,7 +815,6 @@ class ServingEngine:
         ``sched.on_preempt`` — resume finds them as a prefix hit on the
         same replica (routing is sticky) and the victim's KV is reused,
         not recomputed (only the partial tail page is re-prefilled)."""
-        assert self.paged, "preemption requires the paged engine"
         assert self.admissions[b] is not None, f"slot {b} is idle"
         self._barrier()               # external preempt: settle first
         if self.admissions[b] is None:
@@ -894,11 +846,10 @@ class ServingEngine:
         self.pos[b] = 0
         self.last_token[b] = 0
         self.spec_miss[b] = 0
-        if self.paged:
-            self.slot_state[b] = None
-            self.prefill_done[b] = 0
-            if b in self._pending_handoffs:
-                self._pending_handoffs.remove(b)
+        self.slot_state[b] = None
+        self.prefill_done[b] = 0
+        if b in self._pending_handoffs:
+            self._pending_handoffs.remove(b)
 
     # --------------------------------------------------- elastic membership
     def scale_to(self, dp_new: int):
@@ -916,8 +867,6 @@ class ServingEngine:
         (page ids and refcounts stay valid), joined replicas start fresh,
         and the compiled steps rewire from the memoized step set."""
         from repro.core import steps as _steps
-        if not self.paged:
-            raise ValueError("elastic membership requires the paged engine")
         if self.disagg is not None:
             raise ValueError(
                 "scale_to under disaggregation is unsupported: a disagg "
@@ -957,8 +906,6 @@ class ServingEngine:
         ``RecoveryReport``."""
         from repro.core import steps as _steps
         from repro.runtime.ft import plan_recovery
-        if not self.paged:
-            raise ValueError("elastic membership requires the paged engine")
         if self.disagg is not None:
             raise ValueError(
                 "kill_replica under disaggregation is unsupported: a "
@@ -1128,23 +1075,8 @@ class ServingEngine:
         if self.has_cross:
             self.cross_caches = [self.cross_caches[r] for r in keep] + \
                 [CrossKVCache(a) for a in self.allocators[n_keep:]]
-        self.scheds = [self.scheds[r] for r in keep]
-        prof = cache_profile(self.cfg)
-        for j in range(n_keep, dp_new):
-            self.scheds.append(self._sched_factory(
-                seq_budget=self.S,
-                allocator=self.allocators[j],
-                page_size=self.page_size,
-                prefix_cache=self.prefix_caches[j],
-                slab_allocator=(self.slab_allocators[j]
-                                if self.has_ssm else None),
-                cross_cache=(self.cross_caches[j]
-                             if self.has_cross else None),
-                cross_pages_per_req=(self.n_cross_pages
-                                     if self.has_cross else 0),
-                kv_pages="kv" in prof,
-                spec_tokens=self.speculative,
-                stats=self.stats))
+        self.scheds = [self.scheds[r] for r in keep] + \
+            [self._new_sched(j) for j in range(n_keep, dp_new)]
         self.stats.replicas = [self.stats.replicas[r] for r in keep] + \
             [ReplicaStats() for _ in range(dp_new - n_keep)]
         for j, s in enumerate(self.scheds):
@@ -1200,7 +1132,6 @@ class ServingEngine:
         digest.  The pool itself is untouched (spilling takes no refs);
         restore re-allocates fresh pages wherever the store is next
         attached.  -> the store."""
-        assert self.paged, "spill requires the paged engine"
         store = store if store is not None else HostSpillStore()
         for r in (replicas if replicas is not None else range(self.R)):
             pc = self.prefix_caches[r]
@@ -1225,8 +1156,6 @@ class ServingEngine:
         resets never touch them), and register with the replica's cache.
         Entries already resident, or not fitting the pool right now, are
         skipped — the spill store is a warm-start, not a ledger."""
-        if store is None or not self.paged:
-            return
         cand = [r for r in range(self.R)
                 if self.prefix_caches[r] is not None]
         if cand:
@@ -1268,25 +1197,24 @@ class ServingEngine:
 
     # ----------------------------------------------------------------- tick
     def tick(self):
-        if self.paged:
-            return self._tick_paged()
-        self._admit()
-        if not any(self.slots):
-            return
-        with self.mesh:
-            logits, self.cache = self.decode_fn(
-                self.params, self.cache,
-                jnp.asarray(self.last_token[:, None]),
-                jnp.asarray(self.pos))
-        logits = self._host_logits(jax.device_get(logits))
-        live = [(b, req) for b, req in enumerate(self.slots)
-                if req is not None]
-        with _Phase(self.stats, "engine.sample"):
-            toks = [self._sample_row(logits, b, req) for b, req in live]
-        now = time.perf_counter()
-        for (b, req), tok in zip(live, toks, strict=True):
-            self.pos[b] += 1        # the decode step wrote last_token's KV
-            self._emit(b, req, tok, now)
+        """One pipelined tick: plan (host, overlaps in-flight device work),
+        collect (the tick's single barrier — consume the PREVIOUS tick's
+        dispatched results), apply deferred preemption verdicts, dispatch
+        this tick's compiled steps.  ``overlap=False`` collects the fresh
+        dispatch immediately — the serial oracle.  A membership hook (set
+        by fault-injection harnesses or ops triggers) fires first, before
+        any planning — scale_to/kill_replica barrier internally, so the
+        hook sees (and leaves) a fully synchronous engine."""
+        if self.membership_hook is not None:
+            self.membership_hook(self)
+        with _Phase(self.stats, "engine.plan"):
+            tick_plan = self._plan_phase()
+        self._collect_phase()
+        self._run_deferred_preempts(tick_plan)
+        with _Phase(self.stats, "engine.dispatch"):
+            self._dispatch_phase(tick_plan)
+        if not self.overlap:
+            self._collect_phase()
         self.stats.ticks += 1
 
     def _host_logits(self, out) -> np.ndarray:
@@ -1338,70 +1266,6 @@ class ServingEngine:
                 max(len(req.out_tokens) - 1, 1))
             self.scheds[self._rep(b)].on_finish(self.admissions[b])
             self._clear_slot(b)
-
-    def _admit(self):
-        free = [b for b in range(self.B) if self.admissions[b] is None]
-        for adm in self.scheds[0].plan(free):
-            self.admissions[adm.slot] = adm
-            _stamp_admitted(adm.req)
-            self._prefill_into(adm.slot, adm.req)
-
-    def _prefill_into(self, b: int, req: Request):
-        """Prefill a single request and splice its cache into lane b.
-        Enc-dec archs additionally run the encoder over the request's
-        frames here; prefill writes the cross-KV lane the decode step
-        reads."""
-        from repro.core import steps as _steps
-        S = len(req.prompt)
-        assert S < self.S
-        prompt = np.zeros((1, self.S), np.int32)
-        prompt[0, :S] = req.prompt
-        lane_cache = _steps.zero_cache_for(self.cfg, self.plan, self.mesh, 1,
-                                           self.S)
-        with self.mesh:
-            if self.cfg.is_encdec:
-                logits, lane_cache = self.prefill_fn(
-                    self.params,
-                    jnp.asarray(np.asarray(req.frames, np.float32)[None],
-                                jnp.dtype(self.cfg.dtype)),
-                    jnp.asarray(prompt[:, :S]), lane_cache)
-            else:
-                logits, lane_cache = self.prefill_fn(
-                    self.params, jnp.asarray(prompt[:, :S]), lane_cache)
-        self.stats.prefills += 1
-        self.stats.replicas[self._rep(b)].prefills += 1
-        # splice lane 0 of lane_cache into slot b of the engine cache
-        self.cache = _splice_cache(self.cache, lane_cache, b)
-        logits = self._host_logits(jax.device_get(logits))
-        # the token sampled from the prompt's final logits IS the first
-        # generated token: emit it (TTFT lands at prefill completion, and
-        # max_new_tokens counts it)
-        self.pos[b] = S
-        with _Phase(self.stats, "engine.sample"):
-            tok = self._sample_row(logits, 0, req)
-        self._emit(b, req, tok, time.perf_counter())
-
-    # ------------------------------------------------------------ paged tick
-    def _tick_paged(self):
-        """One pipelined tick: plan (host, overlaps in-flight device work),
-        collect (the tick's single barrier — consume the PREVIOUS tick's
-        dispatched results), apply deferred preemption verdicts, dispatch
-        this tick's compiled steps.  ``overlap=False`` collects the fresh
-        dispatch immediately — the serial oracle.  A membership hook (set
-        by fault-injection harnesses or ops triggers) fires first, before
-        any planning — scale_to/kill_replica barrier internally, so the
-        hook sees (and leaves) a fully synchronous engine."""
-        if self.membership_hook is not None:
-            self.membership_hook(self)
-        with _Phase(self.stats, "engine.plan"):
-            tick_plan = self._plan_phase()
-        self._collect_phase()
-        self._run_deferred_preempts(tick_plan)
-        with _Phase(self.stats, "engine.dispatch"):
-            self._dispatch_phase(tick_plan)
-        if not self.overlap:
-            self._collect_phase()
-        self.stats.ticks += 1
 
     def _rep_slots(self, r: int):
         return range(r * self.Bp, (r + 1) * self.Bp)
@@ -1752,7 +1616,7 @@ class ServingEngine:
     def _bt_row(self, b: int) -> np.ndarray:
         row = np.full(self.n_max_pages, SCRATCH_PAGE, np.int32)
         adm = self.admissions[b]
-        if adm is not None and adm.pages is not None:
+        if adm is not None:
             row[:len(adm.pages)] = adm.pages
         return row
 
@@ -1948,10 +1812,3 @@ def _stamp_admitted(req: Request):
     re-placement keeps it."""
     if req.t_admitted is None:
         req.t_admitted = time.perf_counter()
-
-
-def _splice_cache(big, lane, b):
-    def leaf(big_l, lane_l):
-        return big_l.at[:, b:b + 1].set(lane_l[:, 0:1]) \
-            if big_l.ndim >= 2 else big_l
-    return jax.tree_util.tree_map(leaf, big, lane)

@@ -16,7 +16,7 @@ class SamplerConfig:
 
 class DeviceRows:
     """Rows of logits left on the device, with their greedy tokens
-    already picked there (``core.steps.greedy_ids``): what a greedy paged
+    already picked there (``core.steps.greedy_ids``): what a greedy
     engine hands its row sampler in place of fetched logits.  A greedy
     ``sample_from_logits`` takes ``ids`` and reads no logits.  A slice of
     rows is a ``DeviceRows`` of those rows; any other read (another index,

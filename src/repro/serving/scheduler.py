@@ -112,9 +112,9 @@ def remaining_new_tokens(req) -> int:
 class Admission:
     """One scheduler decision: place ``req`` into engine slot ``slot``.
 
-    pages: the slot's full block-table page run (None for the contiguous
-    engine).  cached_len: prompt tokens already resident via prefix sharing
-    — chunked prefill starts at this offset.  cow: (src, dst) page pair the
+    pages: the slot's full block-table page run.  cached_len: prompt
+    tokens already resident via prefix sharing — chunked prefill starts
+    at this offset.  cow: (src, dst) page pair the
     engine must copy before the slot's first write (divergence out of a
     shared partial page).  seq: global admission order stamp (preemptive
     policies use it to pick the victim with the least sunk work).
@@ -128,7 +128,7 @@ class Admission:
     admission (pool pressure) and the slot decodes one token per tick."""
     slot: int
     req: object
-    pages: Optional[List[int]] = None
+    pages: List[int]
     cached_len: int = 0
     cow: Optional[Tuple[int, int]] = None
     seq: int = 0
@@ -140,7 +140,7 @@ class Admission:
 
 class Scheduler:
     """Policy interface the engine drives.  Implementations own the wait
-    queue and (for paged engines) all allocator / prefix-cache traffic."""
+    queue and all allocator / prefix-cache traffic."""
 
     def submit(self, req) -> None:
         raise NotImplementedError
@@ -182,8 +182,7 @@ class Scheduler:
 
     def on_spec_trim(self, adm: Admission, keep: int) -> None:
         """The engine stopped speculating on adm's slot — return the draft
-        headroom pages past block-table index ``keep``; default: no-op
-        (the contiguous engine holds no pages)."""
+        headroom pages past block-table index ``keep``; default: no-op."""
 
     def plan_handoff(self, slot: int, req,
                      resident_len: int) -> Optional[Admission]:
@@ -200,16 +199,15 @@ class Scheduler:
 
 
 class FCFSScheduler(Scheduler):
-    """First-come-first-served admission (the seed engine's policy).
-
-    Paged mode adds all-or-nothing page budgeting — the head request either
-    gets its full budget (prompt + max_new_tokens) or the whole queue waits
+    """First-come-first-served admission (the seed engine's policy) with
+    all-or-nothing page budgeting — the head request either gets its full
+    budget (prompt + max_new_tokens) or the whole queue waits
     (no mid-flight OOM, no starvation-by-overtaking) — plus optional radix
     prefix sharing: admission maps the longest cached prefix into the block
     table, duplicating a partially-shared page copy-on-write, and evicts
     LRU cache runs when the pool can't cover the remainder."""
 
-    def __init__(self, *, seq_budget: int, allocator=None, page_size: int = 0,
+    def __init__(self, *, seq_budget: int, allocator, page_size: int,
                  prefix_cache=None, stats=None, slab_allocator=None,
                  cross_cache=None, cross_pages_per_req: int = 0,
                  kv_pages: bool = True, spec_tokens: int = 0,
@@ -251,10 +249,6 @@ class FCFSScheduler(Scheduler):
         # rates cannot drift
         self.replica_stats = None
 
-    @property
-    def paged(self) -> bool:
-        return self.allocator is not None
-
     # ------------------------------------------------- queue discipline hooks
     def _enqueue(self, req) -> None:
         self.queue.append(req)
@@ -276,25 +270,19 @@ class FCFSScheduler(Scheduler):
     def submit(self, req) -> None:
         if len(req.prompt) == 0:
             raise RuntimeError(f"request {req.rid} has an empty prompt")
-        if self.paged:
-            if len(req.prompt) + req.max_new_tokens > self.seq_budget:
-                raise RuntimeError(
-                    f"request {req.rid} needs {len(req.prompt)} prompt + "
-                    f"{req.max_new_tokens} new tokens; the sequence budget "
-                    f"is {self.seq_budget}")
-            need = self._req_pages(req)
-            usable = self.allocator.n_pages - self.allocator.n_reserved
-            if need > usable:       # reject now, not mid-run at admission
-                raise RuntimeError(
-                    f"request {req.rid} needs {need} pages"
-                    + (f" (incl. {self.cross_pages_per_req} cross-KV)"
-                       if self.cross_pages_per_req else "")
-                    + f"; the pool only has {usable} usable")
-        elif len(req.prompt) >= self.seq_budget:
-            # the contiguous lane needs room past the prompt for decode
+        if len(req.prompt) + req.max_new_tokens > self.seq_budget:
             raise RuntimeError(
-                f"request {req.rid} prompt ({len(req.prompt)} tokens) "
-                f"exceeds the sequence budget {self.seq_budget}")
+                f"request {req.rid} needs {len(req.prompt)} prompt + "
+                f"{req.max_new_tokens} new tokens; the sequence budget "
+                f"is {self.seq_budget}")
+        need = self._req_pages(req)
+        usable = self.allocator.n_pages - self.allocator.n_reserved
+        if need > usable:           # reject now, not mid-run at admission
+            raise RuntimeError(
+                f"request {req.rid} needs {need} pages"
+                + (f" (incl. {self.cross_pages_per_req} cross-KV)"
+                   if self.cross_pages_per_req else "")
+                + f"; the pool only has {usable} usable")
         self._enqueue(req)
         self.backlog_pages += self._req_pages(req)
 
@@ -309,8 +297,6 @@ class FCFSScheduler(Scheduler):
         Constant while it waits (out_tokens only grow while admitted), so
         the backlog counter's add/subtract stay symmetric across put-backs
         and requeues."""
-        if not self.paged:
-            return 0
         n = len(effective_prompt(req))
         if self.role != "prefill":      # prefill slots leave at first token
             n += remaining_new_tokens(req)
@@ -340,8 +326,6 @@ class FCFSScheduler(Scheduler):
         pool pages and state slabs included.  A free slot whose pool is
         exhausted must not suppress preemption: evicting a victim is what
         frees the pages (and its slab)."""
-        if not self.paged:
-            return True
         if self.slab_allocator is not None and \
                 self.slab_allocator.n_free == 0:
             return False
@@ -357,14 +341,11 @@ class FCFSScheduler(Scheduler):
             if req is None:
                 break
             self.backlog_pages -= self._req_pages(req)
-            if self.paged:
-                adm = self._plan_paged(slot, req)
-                if adm is None:     # blocked: wait for reclamation
-                    self._put_back(req)
-                    self.backlog_pages += self._req_pages(req)
-                    break
-            else:
-                adm = Admission(slot=slot, req=req)
+            adm = self._plan_paged(slot, req)
+            if adm is None:         # blocked: wait for reclamation
+                self._put_back(req)
+                self.backlog_pages += self._req_pages(req)
+                break
             adm.seq = self._adm_seq
             self._adm_seq += 1
             out.append(adm)
@@ -527,8 +508,7 @@ class FCFSScheduler(Scheduler):
             self.allocator.decref(adm.cross_pages)
 
     def on_finish(self, adm: Admission) -> None:
-        if self.paged:
-            self._release(adm)
+        self._release(adm)
 
     def on_spec_trim(self, adm: Admission, keep: int) -> None:
         """The engine stopped speculating on adm's slot (persistent draft
@@ -551,14 +531,12 @@ class FCFSScheduler(Scheduler):
         cross-KV cache), and re-queue the request.  The partial tail page
         is slot-private KV and is simply freed; resume re-prefills those
         few tokens."""
-        if self.paged:
-            if self.prefix_cache is not None:
-                n_full = len(resident_tokens) // self.psz
-                if n_full:
-                    self.prefix_cache.insert(
-                        resident_tokens[:n_full * self.psz],
-                        adm.pages[:n_full])
-            self._release(adm)
+        if self.prefix_cache is not None:
+            n_full = len(resident_tokens) // self.psz
+            if n_full:
+                self.prefix_cache.insert(
+                    resident_tokens[:n_full * self.psz], adm.pages[:n_full])
+        self._release(adm)
         self._requeue_preempted(adm.req)
         self.backlog_pages += self._req_pages(adm.req)
 

@@ -5,13 +5,12 @@ page+slab+cross leak-freedom across policies and dp, and the precise
 errors for unsupported combinations."""
 import functools
 
-import jax
 import numpy as np
 import pytest
+from greedy_ref import greedy_reference
 
 from repro.configs import get_config, reduced
-from repro.configs.base import ShapeConfig
-from repro.core import model, steps
+from repro.core import model
 from repro.core.partition import ShardingPlan
 from repro.serving import (FairScheduler, PriorityScheduler, Request,
                            ServingEngine)
@@ -37,21 +36,6 @@ def _mk_requests(base):
             for i, (p, m, f) in enumerate(base)]
 
 
-def _run_contiguous_oracle(cfg, params, mesh, base, SB=32, NSLOT=2):
-    dec, _, _ = steps.make_decode_step(cfg, PLAN, mesh,
-                                       ShapeConfig("s", "decode", SB, NSLOT))
-    pre, _, _ = steps.make_prefill_step(cfg, PLAN, mesh,
-                                        ShapeConfig("p", "decode", SB, 1))
-    eng = ServingEngine(cfg, PLAN, mesh, NSLOT, SB, params, jax.jit(pre),
-                        jax.jit(dec))
-    reqs = _mk_requests(base)
-    for r in reqs:
-        eng.submit(r)
-    eng.run(max_ticks=2000)
-    assert all(r.done for r in reqs)
-    return {r.rid: tuple(r.out_tokens) for r in reqs}
-
-
 def _assert_leak_free(eng):
     """Every page free or cache-held, every slab free, per replica."""
     for rr in range(eng.R):
@@ -67,7 +51,7 @@ def _assert_leak_free(eng):
 
 
 # ---------------------------------------------------------------------------
-# paged vs contiguous oracle (greedy token identity)
+# paged engine vs the contiguous steps' greedy reference (token identity)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
@@ -75,13 +59,13 @@ def _assert_leak_free(eng):
 def test_paged_ssm_archs_match_contiguous(name, mesh1):
     """Hybrid (attn KV pages + SSM slabs) and pure-SSM (slabs only) paged
     engines produce greedy outputs token-identical to the contiguous
-    oracle, and release every page and slab."""
+    steps' greedy reference, and release every page and slab."""
     cfg = reduced(get_config(name), dtype="float32")
     params = model.init_params(cfg, PLAN)
     rng = np.random.RandomState(0)
     base = [(rng.randint(2, cfg.vocab_size, L).astype(np.int32), m, None)
             for L, m in zip([5, 9, 17, 12], [6, 4, 5, 3], strict=True)]
-    ref = _run_contiguous_oracle(cfg, params, mesh1, base)
+    ref = greedy_reference(cfg, PLAN, mesh1, params, _mk_requests(base), 32)
 
     eng = ServingEngine.build_paged(cfg, PLAN, mesh1, 2, 32, params,
                                     page_size=8, prefill_chunk=8)
@@ -106,7 +90,7 @@ def test_paged_encdec_matches_contiguous_with_shared_frames(mesh1):
     base = [(rng.randint(2, cfg.vocab_size, L).astype(np.int32), m,
              frames[i % 2])
             for i, (L, m) in enumerate(zip([5, 9, 12, 7], [5, 4, 3, 6], strict=True))]
-    ref = _run_contiguous_oracle(cfg, params, mesh1, base)
+    ref = greedy_reference(cfg, PLAN, mesh1, params, _mk_requests(base), 32)
 
     eng = ServingEngine.build_paged(cfg, PLAN, mesh1, 2, 32, params,
                                     page_size=8, prefill_chunk=8)

@@ -303,20 +303,19 @@ def test_dp2_drain_releases_both_replicas(mesh1):
         assert a.n_free + c.n_cached_pages == a.n_pages - a.n_reserved, rr
 
 
-def test_dp_requires_paged_and_factory(mesh1):
-    import jax
-    from repro.configs.base import ShapeConfig
-    from repro.core import steps
-    from repro.serving import ServingEngine
+def test_dp_requires_scheduler_factory(mesh1):
+    """dp>1 builds one scheduler per replica, so a pre-built instance is
+    refused."""
+    from repro.core.kvcache import PageAllocator
+    from repro.serving import FCFSScheduler, ServingEngine
     cfg = reduced(get_config("tinyllama-42m"), dtype="float32")
     params = model.init_params(cfg, PLAN)
-    dshape = ShapeConfig("dp_d", "decode", 32, 2)
-    pshape = ShapeConfig("dp_p", "decode", 32, 1)
-    dec, _, _ = steps.make_decode_step(cfg, PLAN, mesh1, dshape)
-    pre, _, _ = steps.make_prefill_step(cfg, PLAN, mesh1, pshape)
-    with pytest.raises(AssertionError, match="paged"):
-        ServingEngine(cfg, PLAN, mesh1, 2, 32, params, jax.jit(pre),
-                      jax.jit(dec), dp=2)
+    sched = FCFSScheduler(seq_budget=32, allocator=PageAllocator(9),
+                          page_size=8)
+    with pytest.raises(AssertionError, match="scheduler factory"):
+        ServingEngine.build_paged(cfg, PLAN, mesh1, 2, 32, params,
+                                  page_size=8, prefill_chunk=8, dp=2,
+                                  scheduler=sched)
 
 
 @pytest.mark.slow

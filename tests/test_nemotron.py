@@ -250,7 +250,7 @@ def test_models_without_experts_return_nothing_new():
 
 def test_serve_entry_point_builds_the_engine_and_refuses_prefix_cache():
     from repro.launch import serve
-    base = ["--arch", "nemotron3-nano-ep8", "--smoke", "--paged",
+    base = ["--arch", "nemotron3-nano-ep8", "--smoke",
             "--slots", "2", "--seq-budget", "64", "--page-size", "16",
             "--prefill-chunk", "16"]
     eng = serve.build_engine(serve.parse_args(base))

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from greedy_ref import greedy_reference
 
 from repro.configs import get_config, reduced
 from repro.configs.base import ShapeConfig
@@ -364,29 +365,21 @@ def test_paged_steps_write_only_addressed_rows(name, mesh1):
 
 @pytest.mark.slow
 def test_paged_engine_matches_contiguous_greedy(mesh1):
-    """Greedy outputs are token-identical across the two cache disciplines."""
+    """Greedy outputs are token-identical to the contiguous steps' greedy
+    reference (``greedy_ref``)."""
     from repro.serving import Request, ServingEngine
     cfg = reduced(get_config("tinyllama-42m"), dtype="float32")
     params = model.init_params(cfg, PLAN)
     rng = np.random.RandomState(0)
     SB, NSLOT = 64, 4
-    # few distinct lengths so the contiguous oracle's per-length recompiles
-    # stay bounded
+    # few distinct lengths so the reference's per-length prefill
+    # recompiles stay bounded
     reqs = [(rid, rng.randint(2, cfg.vocab_size,
                               int(rng.choice([4, 9, 17]))).astype(np.int32),
              int(rng.randint(2, 8))) for rid in range(8)]
-
-    dec, _, _ = steps.make_decode_step(cfg, PLAN, mesh1,
-                                       ShapeConfig("s", "decode", SB, NSLOT))
-    pre, _, _ = steps.make_prefill_step(cfg, PLAN, mesh1,
-                                        ShapeConfig("p", "decode", SB, 1))
-    eng = ServingEngine(cfg, PLAN, mesh1, NSLOT, SB, params, jax.jit(pre),
-                        jax.jit(dec))
-    rs = [Request(rid=r, prompt=p, max_new_tokens=m) for r, p, m in reqs]
-    for r in rs:
-        eng.submit(r)
-    eng.run(max_ticks=500)
-    ref = {r.rid: tuple(r.out_tokens) for r in rs}
+    ref = greedy_reference(cfg, PLAN, mesh1, params,
+                           [Request(rid=r, prompt=p, max_new_tokens=m)
+                            for r, p, m in reqs], SB)
 
     peng = ServingEngine.build_paged(cfg, PLAN, mesh1, NSLOT, SB, params,
                                      page_size=8, prefill_chunk=16,

@@ -233,11 +233,12 @@ def test_scheduler_degrades_to_cold_prefill_instead_of_livelock():
     assert a.n_free == 7
 
 
-def test_contiguous_scheduler_rejects_over_budget_prompt():
-    sched = FCFSScheduler(seq_budget=16)          # contiguous: no allocator
+def test_scheduler_rejects_over_budget_request():
+    sched = FCFSScheduler(seq_budget=16, allocator=PageAllocator(8),
+                          page_size=PSZ)
     with pytest.raises(RuntimeError, match="budget"):
-        sched.submit(_Req(0, toks(*range(16)), 4))
-    sched.submit(_Req(1, toks(*range(15)), 4))    # strictly inside: fine
+        sched.submit(_Req(0, toks(*range(16)), 4))    # 16 + 4 > 16
+    sched.submit(_Req(1, toks(*range(12)), 4))        # exactly 16: fine
 
 
 def test_scheduler_rejects_empty_prompt():

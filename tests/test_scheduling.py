@@ -6,9 +6,10 @@ and invariant to forced preemption points; sampled outputs are invariant
 to admission order via per-request RNG streams."""
 import numpy as np
 import pytest
+from greedy_ref import greedy_reference
 
 from repro.configs import get_config, reduced
-from repro.core import model, steps
+from repro.core import model
 from repro.core.kvcache import PageAllocator
 from repro.core.partition import ShardingPlan
 from repro.serving.policies import FairScheduler, PriorityScheduler
@@ -30,12 +31,18 @@ def toks(*ids):
     return np.asarray(ids, np.int32)
 
 
+def _pool():
+    """Scheduler pool keywords for the policy tests: room for four slots
+    at the 64-token budget, so no admission here blocks on pages."""
+    return {"allocator": PageAllocator(4 * 64 // PSZ + 1), "page_size": PSZ}
+
+
 # ---------------------------------------------------------------------------
 # priority: ordering + aging
 # ---------------------------------------------------------------------------
 
 def test_priority_admission_order():
-    sched = PriorityScheduler(seq_budget=64)
+    sched = PriorityScheduler(seq_budget=64, **_pool())
     for rid, p in enumerate([0, 5, 1, 5]):
         sched.submit(_Req(rid, toks(1, 2, 3), priority=p))
     order = [a.req.rid for a in sched.plan([0, 1, 2, 3])]
@@ -46,7 +53,7 @@ def test_priority_admission_order():
 def test_priority_aging_prevents_starvation():
     """A continuous high-priority stream must not starve a low-priority
     request: its aged effective priority eventually wins the round."""
-    sched = PriorityScheduler(seq_budget=64, aging_rate=0.25)
+    sched = PriorityScheduler(seq_budget=64, aging_rate=0.25, **_pool())
     low = _Req(0, toks(1, 2, 3), priority=0)
     sched.submit(low)
     admitted_round = None
@@ -60,7 +67,7 @@ def test_priority_aging_prevents_starvation():
     assert admitted_round is not None and admitted_round <= 30
 
     # and with aging off, it starves forever
-    sched0 = PriorityScheduler(seq_budget=64, aging_rate=0.0)
+    sched0 = PriorityScheduler(seq_budget=64, aging_rate=0.0, **_pool())
     low0 = _Req(0, toks(1, 2, 3), priority=0)
     sched0.submit(low0)
     for r in range(1, 100):
@@ -76,7 +83,7 @@ def test_priority_aging_prevents_starvation():
 
 def test_fair_drr_interleaves_clients():
     """A flooding client shares the slot evenly with a light client."""
-    sched = FairScheduler(seq_budget=64, quantum=16)
+    sched = FairScheduler(seq_budget=64, quantum=16, **_pool())
     for rid in range(6):                           # client 0 floods
         sched.submit(_Req(rid, toks(*range(8)), max_new=4, client_id=0))
     for rid in (10, 11):                           # client 1: two requests
@@ -92,7 +99,7 @@ def test_fair_drr_interleaves_clients():
 
 def test_fair_drr_charges_by_cost():
     """A client with 3x-heavier requests gets ~1/3 the admission rate."""
-    sched = FairScheduler(seq_budget=64, quantum=12)
+    sched = FairScheduler(seq_budget=64, quantum=12, **_pool())
     for rid in range(4):                           # heavy: cost 36
         sched.submit(_Req(rid, toks(*range(30)), max_new=6, client_id=0))
     for rid in range(10, 22):                      # light: cost 12
@@ -118,7 +125,7 @@ def test_fair_drr_preempts_long_running_client():
     starvation bug)."""
     for preemption in (True, False):
         sched = FairScheduler(seq_budget=64, quantum=16, preemption=preemption,
-                              preempt_after=3)
+                              preempt_after=3, **_pool())
         for rid in range(4):               # client 0 floods both slots
             sched.submit(_Req(rid, toks(*range(8)), max_new=8, client_id=0))
         adms = sched.plan([0, 1])
@@ -149,7 +156,7 @@ def test_fair_drr_preempts_long_running_client():
 def test_fair_drr_preemption_respects_free_slots():
     """A usable free slot serves the waiting client without eviction."""
     sched = FairScheduler(seq_budget=64, quantum=16, preemption=True,
-                          preempt_after=1)
+                          preempt_after=1, **_pool())
     sched.submit(_Req(0, toks(*range(8)), max_new=8, client_id=0))
     (adm,) = sched.plan([0, 1])
     sched.submit(_Req(1, toks(*range(8)), max_new=8, client_id=1))
@@ -162,7 +169,7 @@ def test_fair_drr_preemption_respects_free_slots():
 # ---------------------------------------------------------------------------
 
 def test_preemption_victim_choice_and_no_ping_pong():
-    sched = PriorityScheduler(seq_budget=64, preemption=True)
+    sched = PriorityScheduler(seq_budget=64, preemption=True, **_pool())
     lo_a = _Req(0, toks(1, 2, 3), priority=1)
     lo_b = _Req(1, toks(4, 5, 6), priority=0)
     sched.submit(lo_a)
@@ -188,7 +195,8 @@ def test_preemption_victim_choice_and_no_ping_pong():
 def test_preemption_resets_victim_aging_no_ping_pong():
     """An aged-up victim must not out-rank the urgent request that
     displaced it: preemption resets its aging credit."""
-    sched = PriorityScheduler(seq_budget=64, preemption=True, aging_rate=1.0)
+    sched = PriorityScheduler(seq_budget=64, preemption=True, aging_rate=1.0,
+                              **_pool())
     low = _Req(0, toks(1, 2, 3), priority=0)
     sched.submit(low)
     for _ in range(20):                  # age low well past priority 10
@@ -208,7 +216,8 @@ def test_preemption_resets_victim_aging_no_ping_pong():
 def test_preemption_scans_past_aged_low_priority_head():
     """A fresh high-priority request behind an aged low-priority one in
     the pending order must still trigger preemption."""
-    sched = PriorityScheduler(seq_budget=64, preemption=True, aging_rate=1.0)
+    sched = PriorityScheduler(seq_budget=64, preemption=True, aging_rate=1.0,
+                              **_pool())
     running = _Req(0, toks(1,), priority=0)
     sched.submit(running)
     (adm,) = sched.plan([0])
@@ -340,6 +349,33 @@ def test_policies_conserve_requests_and_pages_randomized(name, mk):
         assert alloc.n_free + cache.n_cached_pages == 32, (name, seed)
         cache.evict(10 ** 6)
         assert alloc.n_free == 32, (name, seed)
+
+
+@pytest.mark.parametrize("name,mk", _policies(),
+                         ids=[p[0] for p in _policies()])
+def test_blocked_head_is_not_overtaken(name, mk):
+    """The policy's next pick B needs more pages than are free: a plan()
+    with two free slots admits neither B nor the smaller C behind it, and
+    rolls back whatever it took.  Once A returns its pages, B goes first."""
+    alloc = PageAllocator(9)                       # 8 usable
+    sched = mk(seq_budget=32, allocator=alloc, page_size=PSZ,
+               prefix_cache=None, stats=None)
+    a = _Req(0, toks(*range(16)), max_new=8, priority=9)      # 6 pages
+    sched.submit(a)
+    (adm_a,) = sched.plan([0])
+    assert alloc.n_free == 2
+    b = _Req(1, toks(*range(8)), max_new=8, priority=5)       # 4 pages
+    c = _Req(2, toks(1, 2), max_new=2, priority=0)            # 1 page
+    sched.submit(b)
+    sched.submit(c)
+    assert sched.plan([1, 2]) == []
+    assert alloc.n_free == 2 and sched.has_pending()
+    sched.on_finish(adm_a)
+    adms = sched.plan([1, 2])
+    assert adms[0].req is b
+    for x in adms:
+        sched.on_finish(x)
+    assert alloc.n_free == 8
 
 
 # ---------------------------------------------------------------------------
@@ -484,25 +520,17 @@ def test_sampled_outputs_schedule_invariant(mesh1):
 def test_first_token_from_prefill_logits_and_exact_budget(mesh1):
     """The token sampled from the prompt's final logits is the first output
     token (not silently dropped), and max_new_tokens is exact."""
-    import jax
-    import jax.numpy as jnp
-    from repro.configs.base import ShapeConfig
     from repro.serving import Request, ServingEngine
     cfg = reduced(get_config("qwen3-0.6b"), dtype="float32")
     params = model.init_params(cfg, PLAN)
     SB = 32
-    dec, _, _ = steps.make_decode_step(cfg, PLAN, mesh1,
-                                       ShapeConfig("ft_d", "decode", SB, 1))
-    pre, _, _ = steps.make_prefill_step(cfg, PLAN, mesh1,
-                                        ShapeConfig("ft_p", "decode", SB, 1))
-    pre = jax.jit(pre)
     prompt = np.arange(2, 11, dtype=np.int32)
-    lane = steps.zero_cache_for(cfg, PLAN, mesh1, 1, SB)
-    with mesh1:
-        logits, _ = pre(params, jnp.asarray(prompt[None]), lane)
-    t0 = int(np.argmax(np.asarray(logits[0])[:cfg.vocab_size]))
+    (t0,) = greedy_reference(
+        cfg, PLAN, mesh1, params,
+        [Request(rid=0, prompt=prompt, max_new_tokens=1)], SB)[0]
 
-    eng = ServingEngine(cfg, PLAN, mesh1, 1, SB, params, pre, jax.jit(dec))
+    eng = ServingEngine.build_paged(cfg, PLAN, mesh1, 1, SB, params,
+                                    page_size=8, prefill_chunk=8)
     req = Request(rid=0, prompt=prompt, max_new_tokens=3)
     eng.submit(req)
     stats = eng.run(max_ticks=50)

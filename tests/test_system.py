@@ -60,12 +60,8 @@ def test_serving_engine_end_to_end(mesh1):
     plan = ShardingPlan(tp=1)
     params = model.init_params(cfg, plan)
     SB = 64
-    dshape = ShapeConfig("s", "decode", SB, 2)
-    pshape = ShapeConfig("p", "decode", SB, 1)
-    dec, _, _ = steps.make_decode_step(cfg, plan, mesh1, dshape)
-    pre, _, _ = steps.make_prefill_step(cfg, plan, mesh1, pshape)
-    eng = ServingEngine(cfg, plan, mesh1, 2, SB, params, jax.jit(pre),
-                        jax.jit(dec), sampler=SamplerConfig())
+    eng = ServingEngine.build_paged(cfg, plan, mesh1, 2, SB, params,
+                                    sampler=SamplerConfig())
     rng = np.random.RandomState(0)
     for rid in range(4):
         eng.submit(Request(rid=rid,
@@ -86,20 +82,35 @@ def test_greedy_decode_deterministic(mesh1):
     plan = ShardingPlan(tp=1)
     params = model.init_params(cfg, plan)
     SB = 32
-    dec, _, _ = steps.make_decode_step(cfg, plan, mesh1,
-                                       ShapeConfig("s", "decode", SB, 1))
-    pre, _, _ = steps.make_prefill_step(cfg, plan, mesh1,
-                                        ShapeConfig("p", "decode", SB, 1))
     outs = []
     for _ in range(2):
-        eng = ServingEngine(cfg, plan, mesh1, 1, SB, params, jax.jit(pre),
-                            jax.jit(dec), sampler=SamplerConfig())
+        eng = ServingEngine.build_paged(cfg, plan, mesh1, 1, SB, params,
+                                        sampler=SamplerConfig())
         req = Request(rid=0, prompt=np.arange(2, 10, dtype=np.int32),
                       max_new_tokens=5)
         eng.submit(req)
         eng.run(max_ticks=50)
         outs.append(tuple(req.out_tokens))
     assert outs[0] == outs[1]
+
+
+def test_serve_cli_default_builds_paged_engine(monkeypatch, capsys):
+    """``launch/serve.py`` with no engine flags serves through the paged
+    engine: every request completes and every page returns to the pool."""
+    from repro.launch import compile_cache, serve
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    built = []
+    build = serve.build_engine
+    monkeypatch.setattr(serve, "build_engine",
+                        lambda args: built.append(build(args)) or built[-1])
+    assert serve.main(["--arch", "tinyllama-42m", "--smoke",
+                       "--requests", "3"]) == 0
+    (eng,) = built
+    assert eng.allocators
+    for a in eng.allocators:
+        assert a.n_free == a.n_pages - a.n_reserved
+    out = capsys.readouterr().out
+    assert "requests=3 " in out and "prefills=3 " in out
 
 
 # ---------------------------------------------------------------------------
